@@ -5,14 +5,6 @@ reduction that matters at 1000-node DP scale), and (b) convergence parity
 on a regression task — run in a subprocess with 4 host devices."""
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
 _CODE = """
 import json
 import numpy as np, jax, jax.numpy as jnp
@@ -53,15 +45,8 @@ print(json.dumps(out))
 
 
 def run(scale: str = "small"):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = SRC
-    env["JAX_PLATFORMS"] = "cpu"
-    res = subprocess.run([sys.executable, "-c", textwrap.dedent(_CODE)],
-                         capture_output=True, text=True, env=env, timeout=560)
-    if res.returncode != 0:
-        return [{"bench": "grad_compression", "error": res.stderr[-400:]}]
-    data = json.loads(res.stdout.strip().splitlines()[-1])
+    from benchmarks.common import run_cpu_child
+    data = run_cpu_child("grad_compression", _CODE, devices=4)
     rows = []
     for variant, d in data.items():
         rows.append({

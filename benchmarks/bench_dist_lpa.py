@@ -11,14 +11,6 @@ through the same static ``FoldRequest`` as the single-host mover
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
 _CODE = """
 import json, time
 import numpy as np, jax
@@ -80,18 +72,10 @@ print(json.dumps(out))
 
 
 def run(scale: str = "small", sketches: str | None = None):
-    from benchmarks.common import sketch_list
+    from benchmarks.common import run_cpu_child, sketch_list
     chosen = sketch_list(sketches) if sketches else ("mg",)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = SRC
-    env["JAX_PLATFORMS"] = "cpu"
-    code = textwrap.dedent(_CODE).format(sketches=tuple(chosen))
-    res = subprocess.run([sys.executable, "-c", code],
-                         capture_output=True, text=True, env=env, timeout=560)
-    if res.returncode != 0:
-        return [{"bench": "dist_lpa_scaling", "error": res.stderr[-400:]}]
-    rows = json.loads(res.stdout.strip().splitlines()[-1])
+    code = _CODE.format(sketches=tuple(chosen))
+    rows = run_cpu_child("dist_lpa_scaling", code, devices=8)
     for r in rows:
         r["bench"] = "dist_lpa_scaling"
     return rows

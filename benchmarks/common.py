@@ -2,6 +2,11 @@
 the working-set model used for the Fig. 7(d) memory comparison."""
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
+import textwrap
 import time
 from typing import Callable, Dict
 
@@ -10,6 +15,38 @@ import jax
 
 from repro.core.lpa import LPAConfig, build_workspace
 from repro.graphs.csr import CSRGraph, plan_padded_entries
+
+
+_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+
+
+def run_cpu_child(bench: str, code: str, devices: int,
+                  timeout: int = 560):
+    """Run ``code`` in a child process on ``devices`` virtual CPU devices
+    and return the JSON its last stdout line carries.
+
+    Benchmarks that simulate shards on host devices need a fresh process
+    (the device count is fixed at JAX start-up). On a TPU host that child
+    could only run pinned to the CPU — the parent already holds the chip —
+    so there it refuses instead, and a failed child raises: ``run.py``
+    turns either into an error row and a non-zero exit.
+    """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{bench} runs its shards as virtual CPU devices in a child "
+            "process, which cannot share this host's TPU with the parent; "
+            "run it on a CPU host, and the four-chip path with "
+            "`python chip_smoke.py --four-chips`")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = _SRC
+    env["JAX_PLATFORMS"] = "cpu"
+    res = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env,
+                         timeout=timeout)
+    if res.returncode != 0:
+        raise RuntimeError(f"{bench} child failed: {res.stderr[-400:]}")
+    return json.loads(res.stdout.strip().splitlines()[-1])
 
 
 def time_fn(fn: Callable, *args, repeats: int = 3, warmup: int = 1) -> float:

@@ -65,6 +65,8 @@ def main() -> int:
                          "layout)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     all_rows = []
     failed = 0
@@ -91,7 +93,7 @@ def main() -> int:
             import traceback
             traceback.print_exc()
             rows = [{"bench": name, "error": f"{type(e).__name__}: {e}"}]
-            failed += 1
+        failed += any("error" in r for r in rows)
         dt = time.time() - t0
         print(f"\n== {name} ({dt:.0f}s) " + "=" * max(0, 50 - len(name)))
         print(_csv(rows))

@@ -28,7 +28,7 @@ from typing import Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import sketch as sketch_lib
 from repro.core.fold_program import FoldRequest
@@ -36,7 +36,6 @@ from repro.core.plan_bundle import (PlanSpec, ShardSlice, build_plan_bundle,
                                     stack_aligned_windows,
                                     stack_shard_bundles,
                                     uniform_round_count)
-from repro.compat import shard_map
 
 PAD = -1
 INT_MAX = jnp.iinfo(jnp.int32).max
@@ -449,7 +448,6 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
         init = sketch_lib.bm_init_rows(rv0_l, labels)
         if stream_meta is not None:
             from repro.graphs.csr import StreamedRound
-            from repro.kernels.mg_sketch.fused import _interpret_default
             from repro.kernels.mg_sketch.streaming import bm_fold_round_stream
             g, rs, rc, dm = stream_meta[0]
             el0, ew0 = entry_labels, entry_weights
@@ -460,20 +458,16 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
                                 step_dmax=dm[0], n_entries_in=0,
                                 window_entries=g.shape[-1],
                                 aligned=stream_apos is not None)
-            ck, wk = bm_fold_round_stream(rnd, el0, ew0,
-                                          init, chunk=chunk,
-                                          interpret=_interpret_default())
+            ck, wk = bm_fold_round_stream(rnd, el0, ew0, init, chunk=chunk)
         elif fused_meta is not None:
             from repro.graphs.csr import FusedRound
-            from repro.kernels.mg_sketch.fused import (_interpret_default,
-                                                       bm_fold_round_fused)
+            from repro.kernels.mg_sketch.fused import bm_fold_round_fused
             rs, rc, dm = fused_meta[0]
             rnd = FusedRound(row_start=rs[0], row_count=rc[0],
                              step_dmax=dm[0],
                              n_entries_in=fused_entries[0])
             ck, wk = bm_fold_round_fused(rnd, entry_labels, entry_weights,
-                                         init, chunk=chunk,
-                                         interpret=_interpret_default())
+                                         init, chunk=chunk)
         else:
             gl, gw = sketch_lib._gather_entries(round_gathers[0],
                                                 entry_labels, entry_weights)
@@ -487,9 +481,7 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
         # resident per grid step (the shard-local analogue of
         # kernels.mg_sketch.streaming.run_mg_plan_stream)
         from repro.graphs.csr import StreamedRound
-        from repro.kernels.mg_sketch.fused import _interpret_default
         from repro.kernels.mg_sketch.streaming import stream_fold_round
-        interpret = _interpret_default()
         for r, (g, rs, rc, dm) in enumerate(stream_meta):
             el, ew = entry_labels, entry_weights
             is_aligned = r == 0 and stream_apos is not None
@@ -500,25 +492,20 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
                                 step_dmax=dm[0], n_entries_in=0,
                                 window_entries=g.shape[-1],
                                 aligned=is_aligned)
-            s_k, s_v = stream_fold_round(rnd, el, ew,
-                                         k=k, chunk=chunk,
-                                         interpret=interpret)
+            s_k, s_v = stream_fold_round(rnd, el, ew, k=k, chunk=chunk)
             entry_labels, entry_weights = s_k.reshape(-1), s_v.reshape(-1)
         # window-slot row order: scatter below via the streaming slot map
         final_row_vertex = stream_frv[0]
     elif fused_meta is not None:
         # fused engine: one dispatch per round, gather inside the kernel
         from repro.graphs.csr import FusedRound
-        from repro.kernels.mg_sketch.fused import (_interpret_default,
-                                                   fused_fold_round)
-        interpret = _interpret_default()
+        from repro.kernels.mg_sketch.fused import fused_fold_round
         for r, (rs, rc, dm) in enumerate(fused_meta):
             rnd = FusedRound(row_start=rs[0], row_count=rc[0],
                              step_dmax=dm[0],
                              n_entries_in=fused_entries[r])
             s_k, s_v = fused_fold_round(rnd, entry_labels, entry_weights,
-                                        k=k, chunk=chunk,
-                                        interpret=interpret)
+                                        k=k, chunk=chunk)
             entry_labels, entry_weights = s_k.reshape(-1), s_v.reshape(-1)
         s_k = s_k[:final_row_vertex.shape[0]]  # drop tile-padding rows
         s_v = s_v[:final_row_vertex.shape[0]]
@@ -550,7 +537,6 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
         cand_rows = cand_ext[jnp.where(rv0_l >= 0, rv0_l, v_pad)]
         if stream_meta is not None:
             from repro.graphs.csr import StreamedRound
-            from repro.kernels.mg_sketch.fused import _interpret_default
             from repro.kernels.mg_sketch.streaming import rescan_round_stream
             g, rs, rc, dm = stream_meta[0]
             el0, ew0 = entry_labels0, entry_weights0
@@ -563,19 +549,16 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
                                  window_entries=g.shape[-1],
                                  aligned=is_aligned)
             parts = rescan_round_stream(rnd0, el0, ew0, cand_rows, k=k,
-                                        chunk=chunk,
-                                        interpret=_interpret_default())
+                                        chunk=chunk)
         elif fused_meta is not None:
             from repro.graphs.csr import FusedRound
-            from repro.kernels.mg_sketch.fused import (_interpret_default,
-                                                       rescan_round_fused)
+            from repro.kernels.mg_sketch.fused import rescan_round_fused
             rs, rc, dm = fused_meta[0]
             rnd0 = FusedRound(row_start=rs[0], row_count=rc[0],
                               step_dmax=dm[0],
                               n_entries_in=fused_entries[0])
             parts = rescan_round_fused(rnd0, entry_labels0, entry_weights0,
-                                       cand_rows, k=k, chunk=chunk,
-                                       interpret=_interpret_default())
+                                       cand_rows, k=k, chunk=chunk)
         else:
             gl0, gw0 = sketch_lib._gather_entries(round_gathers[0],
                                                   entry_labels0,
@@ -615,8 +598,11 @@ def dist_lpa_step(mesh, ws: DistLPAWorkspace, *, axis_names=None,
                   frontier_gate: bool = False):
     """Build the shard_map'd single-iteration function for ``mesh``.
 
-    Returns step(ws_arrays..., labels [P, V_pad], pick_less, seed) ->
-    (labels, delta_n). The caller jits it (dryrun lowers it).
+    Returns step(nbr_pos, weights, round_gathers, final_row_vertex,
+    labels [P, V_pad], pick_less, seed, *, ws_arrays=None) -> (labels,
+    delta_n). The caller jits it (dryrun lowers it). ``ws_arrays`` is the
+    workspace to read every other array from (default: the closed-over
+    ``ws``); a jitted caller passes it so the arrays stay arguments.
 
     ``engine`` selects the fold backend uniformly with the single-host
     driver ("jnp" | "pallas" | "pallas_fused" | "pallas_stream" — see
@@ -671,8 +657,38 @@ def dist_lpa_step(mesh, ws: DistLPAWorkspace, *, axis_names=None,
     n_rounds = len(ws.round_gathers)
     halo = ws.send_idx is not None
 
+    def engine_operands(w):
+        """(``_shard_move`` keyword, stacked array, in_spec) of every
+        engine-specific workspace array the step reads from ``w``."""
+        ops = []
+        if fused:
+            ops.append(("fused_meta",
+                        tuple(zip(w.fused_starts, w.fused_counts,
+                                  w.fused_dmax)),
+                        tuple([(spec, spec, spec)] * n_rounds)))
+        if stream:
+            ops.append(("stream_meta",
+                        tuple(zip(w.stream_gathers, w.stream_starts,
+                                  w.stream_counts, w.stream_dmax)),
+                        tuple([(spec, spec, spec, spec)] * n_rounds)))
+            ops.append(("stream_frv", w.stream_final_rv, spec))
+            if w.stream_aligned_pos is not None:
+                ops += [("stream_apos", w.stream_aligned_pos, spec),
+                        ("stream_aw", w.stream_aligned_w, spec)]
+        if method == "bm" or rescan:
+            ops.append(("rv0", w.stream_rv0 if stream
+                        else w.fused_rv0 if fused else w.row_vertex0, spec))
+        if rescan:
+            ops.append(("rank0", w.stream_rank0 if stream
+                        else w.fused_rank0 if fused else w.bucket_rank0,
+                        spec))
+        if frontier_gate:
+            ops.append(("entry_vertex", w.entry_vertex, spec))
+        return ops
+
     def step(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
-             pick_less, seed, send_idx=None, hub_idx=None, frontier=None):
+             pick_less, seed, send_idx=None, hub_idx=None, frontier=None,
+             ws_arrays=None):
         in_specs = [spec, spec, tuple([spec] * n_rounds), spec, spec,
                     P(), P()]
         args = [nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
@@ -683,51 +699,32 @@ def dist_lpa_step(mesh, ws: DistLPAWorkspace, *, axis_names=None,
             kw.update(fused_entries=ws.fused_entries, chunk=ws.chunk)
         if stream:
             kw.update(chunk=ws.chunk)
+        if rescan:
+            kw["max_rows0"] = ws.max_rows0
         extra_names = []
         if send_idx is not None:
             in_specs += [spec, spec]
             args += [send_idx, hub_idx]
             extra_names += ["send_idx", "hub_idx"]
-        if fused:
-            meta = tuple(zip(ws.fused_starts, ws.fused_counts,
-                             ws.fused_dmax))
-            in_specs += [tuple([(spec, spec, spec)] * n_rounds)]
-            args += [meta]
-            extra_names += ["fused_meta"]
-        if stream:
-            meta = tuple(zip(ws.stream_gathers, ws.stream_starts,
-                             ws.stream_counts, ws.stream_dmax))
-            in_specs += [tuple([(spec, spec, spec, spec)] * n_rounds), spec]
-            args += [meta, ws.stream_final_rv]
-            extra_names += ["stream_meta", "stream_frv"]
-            if ws.stream_aligned_pos is not None:
-                in_specs += [spec, spec]
-                args += [ws.stream_aligned_pos, ws.stream_aligned_w]
-                extra_names += ["stream_apos", "stream_aw"]
-        if method == "bm" or rescan:
-            rv0 = (ws.stream_rv0 if stream
-                   else ws.fused_rv0 if fused else ws.row_vertex0)
-            in_specs += [spec]
-            args += [rv0]
-            extra_names += ["rv0"]
-        if rescan:
-            rk0 = (ws.stream_rank0 if stream
-                   else ws.fused_rank0 if fused else ws.bucket_rank0)
-            in_specs += [spec]
-            args += [rk0]
-            extra_names += ["rank0"]
-            kw["max_rows0"] = ws.max_rows0
         if frontier_gate:
-            in_specs += [spec, spec]
-            args += [frontier, ws.entry_vertex]
-            extra_names += ["frontier", "entry_vertex"]
+            in_specs.append(spec)
+            args.append(frontier)
+            extra_names.append("frontier")
+        # the engine's arrays come from ``ws_arrays`` when the caller hands
+        # the workspace in as a jit argument, else from the closed-over
+        # ``ws`` (jit would then embed them in the program as constants)
+        for name, value, sp in engine_operands(
+                ws if ws_arrays is None else ws_arrays):
+            in_specs.append(sp)
+            args.append(value)
+            extra_names.append(name)
 
         def body(*a):
             return _shard_move(*a[:7], **dict(zip(extra_names, a[7:])),
                                **kw)
 
         out_specs = (spec, P(), spec) if frontier_gate else (spec, P())
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=out_specs,
@@ -735,11 +732,12 @@ def dist_lpa_step(mesh, ws: DistLPAWorkspace, *, axis_names=None,
         )(*args)
 
     if halo:
-        def halo_step(*a, frontier=None):
+        def halo_step(*a, frontier=None, ws_arrays=None):
+            w = ws if ws_arrays is None else ws_arrays
             return step(*a[:7],
-                        send_idx=a[7] if len(a) > 7 else ws.send_idx,
-                        hub_idx=a[8] if len(a) > 8 else ws.hub_idx,
-                        frontier=frontier)
+                        send_idx=a[7] if len(a) > 7 else w.send_idx,
+                        hub_idx=a[8] if len(a) > 8 else w.hub_idx,
+                        frontier=frontier, ws_arrays=ws_arrays)
         return halo_step
     return step
 
@@ -761,9 +759,15 @@ def dist_lpa(mesh, ws: DistLPAWorkspace, rho: int = 8, tau: float = 0.05,
     step = jax.jit(dist_lpa_step(mesh, ws, engine=engine, method=method,
                                  rescan=rescan,
                                  frontier_gate=frontier_gate))
+    # place the stacked workspace once — shard p of every [P, ...] array on
+    # mesh device p — and hand it to every step call as an argument, so no
+    # call reshards it and no array is compiled into the program
+    shards = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    ws = jax.device_put(ws, shards)
     labels = ws.init_labels
     n = ws.n_nodes
-    frontier = jnp.ones(labels.shape, dtype=jnp.bool_)
+    frontier = jax.device_put(jnp.ones(labels.shape, dtype=jnp.bool_),
+                              shards)
     it = 0
     for it in range(max_iters):
         pl_on = (it % rho) == 0
@@ -771,12 +775,13 @@ def dist_lpa(mesh, ws: DistLPAWorkspace, rho: int = 8, tau: float = 0.05,
             labels, delta, marked = step(
                 ws.nbr_pos, ws.weights, ws.round_gathers,
                 ws.final_row_vertex, labels, jnp.asarray(pl_on),
-                jnp.int32(it + 1), frontier=frontier)
+                jnp.int32(it + 1), frontier=frontier, ws_arrays=ws)
             frontier = (frontier | marked) if pl_on else marked
         else:
             labels, delta = step(ws.nbr_pos, ws.weights, ws.round_gathers,
                                  ws.final_row_vertex, labels,
-                                 jnp.asarray(pl_on), jnp.int32(it + 1))
+                                 jnp.asarray(pl_on), jnp.int32(it + 1),
+                                 ws_arrays=ws)
         if not pl_on and int(delta) / max(n, 1) < tau:
             break
     flat = np.asarray(labels).reshape(-1)

@@ -35,7 +35,7 @@ compute the executors:
                         fold and ONE for the rescan second pass
                         (kernels.mg_sketch.fused). Keeps the flat entry
                         arrays VMEM-resident, so a single core is bounded
-                        by the VMEM budget (round 0 = |E| entries at ~8
+                        by the VMEM budget (round 0 = |E| entries at 8
                         bytes each).
   * ``pallas_stream`` — the fused dataflow with every round streamed
                         through fixed-size double-buffered HBM->VMEM entry
@@ -76,9 +76,12 @@ from repro.graphs.csr import (FoldPlan, fused_dispatches, plan_dispatches,
 
 #: Default VMEM budget (bytes) the ``auto`` policy allows the fused engine's
 #: resident round-0 entry arrays (labels int32 + weights float32 = 8
-#: bytes/entry). 8 MiB ~= the "|E| ~ 1M entries per core" cap of
-#: kernels.mg_sketch.fused, leaving headroom inside a ~16 MiB v5e core for
-#: the gathered tile, sketches and double-buffered blocks.
+#: bytes/entry; one whole-array VMEM copy each, not double-buffered).
+#: 8 MiB = 1M entries. The v5e compiler accepts the fused kernels with up
+#: to 96 MiB of entries and refuses 128 MiB, the core's whole VMEM, so
+#: the budget is a speed policy, not a capacity limit: raise it once a chip
+#: measurement shows the fused engine winning past 1M entries
+#: (tests/test_tpu_compile.py compiles the fused kernels at the budget).
 DEFAULT_VMEM_BUDGET_BYTES = 8 * 2**20
 
 #: HBM bytes per round-0 entry held resident by the fused engine
@@ -266,7 +269,9 @@ class JnpEngine(FoldEngine):
 
 class PallasEngine(FoldEngine):
     """Per-bucket tile kernels (the pre-fusion Pallas baseline; for
-    bounded-VMEM large graphs use ``pallas_stream`` instead)."""
+    bounded-VMEM large graphs use ``pallas_stream`` instead). Runs in
+    interpret mode only: its kernels slice columns out of in-register
+    values, which the TPU compiler refuses (``dynamic_slice``)."""
 
     name = "pallas"
 

@@ -2,31 +2,29 @@
 from __future__ import annotations
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from repro.graphs.csr import CSRGraph
 
 
 def modularity(graph: CSRGraph, labels: jnp.ndarray,
-               edge_src: jnp.ndarray | None = None) -> jnp.ndarray:
+               edge_src: jnp.ndarray | None = None) -> float:
     """Q = sum_c [ sigma_c / 2m - (Sigma_c / 2m)^2 ]  (paper Eq. 1).
 
     sigma_c counts both directions of every intra-community edge, matching
     2*sigma_c of the undirected formulation — our CSR stores both directions.
+    Accumulated on the host in float64: float32 sums of a giant community
+    stall once they pass 2^24 (a graph500-22 run read Q = -0.78 that way).
     """
     n = graph.n_nodes
-    if edge_src is None:
-        edge_src = graph.sources()
-    two_m = jnp.sum(graph.weights)  # = 2m (both directions stored)
-    same = labels[edge_src] == labels[graph.indices]
-    # per-community internal weight (counted with both directions = 2*sigma_c)
-    intra2 = jax.ops.segment_sum(jnp.where(same, graph.weights, 0.0), labels[edge_src],
-                                 num_segments=n)
-    k_i = jax.ops.segment_sum(graph.weights, edge_src, num_segments=n)  # weighted degree
-    sigma_tot = jax.ops.segment_sum(k_i, labels, num_segments=n)        # Sigma_c
-    q = jnp.sum(intra2 / two_m) - jnp.sum((sigma_tot / two_m) ** 2)
-    return q
+    src = np.asarray(graph.sources() if edge_src is None else edge_src)
+    lab = np.asarray(labels)
+    w = np.asarray(graph.weights, dtype=np.float64)
+    two_m = w.sum()  # = 2m (both directions stored)
+    intra2 = w[lab[src] == lab[np.asarray(graph.indices)]].sum()
+    k_i = np.bincount(src, weights=w, minlength=n)          # weighted degree
+    sigma_tot = np.bincount(lab, weights=k_i, minlength=n)  # Sigma_c
+    return float(intra2 / two_m - np.sum((sigma_tot / two_m) ** 2))
 
 
 def nmi(labels_a: np.ndarray, labels_b: np.ndarray) -> float:

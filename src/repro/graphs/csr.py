@@ -425,13 +425,13 @@ def build_fused_fold_plan(degrees: np.ndarray, k: int = 8, chunk: int = 128,
 # ---------------------------------------------------------------------------
 #
 # The fused plan above keeps each round's flat entry arrays VMEM-resident
-# (round 0 = |E| entries), which caps a single core at |E| ~ 1M entries.
+# (round 0 = |E| entries), which caps a single core at the VMEM budget.
 # The streamed plan re-lays every round's entries into fixed-size windows
 # of at most ``window_entries`` slots such that **no row straddles a window
 # boundary**: each window owns at most ``tile_r`` rows whose entries are
 # packed contiguously at window-relative offsets, with the invariant
-# ``rel_start + chunk <= window_entries`` so the kernel's full-``chunk``
-# dynamic slice of any row stays inside the window. One grid step then
+# ``rel_start + chunk <= window_entries`` so the kernel's gather of any
+# row stays inside the window. One grid step then
 # consumes exactly one window: the Pallas pipeline streams each window's
 # entry block HBM -> VMEM (double-buffered across grid steps) while the
 # previous window folds, so per-step residency is O(window_entries), not

@@ -13,11 +13,12 @@ kernels here exploit the structure of the fold plan (every gather is a
 masked contiguous range, see ``repro.graphs.csr.build_fused_fold_plan``):
 
   * the *entry gather happens inside the kernel* — the flat entry arrays
-    are passed whole, and each grid step dynamic-slices the ``chunk``-wide
-    window of each of its ``tile_r`` rows straight into VMEM. The padded
-    [tile_r, chunk] tile never exists in HBM; pad lanes are masked
-    in-register from (start, count) scalars (8 bytes/row of metadata
-    instead of ``4*width`` bytes of gather indices);
+    are passed whole, viewed as ``[rows, 128]`` lane rows, and each grid
+    step copies the ``chunk``-wide range of each of its ``tile_r`` rows
+    into a VMEM tile (:func:`_gather_tile`). The padded [tile_r, chunk]
+    tile never exists in HBM; pad lanes are masked in-register from
+    (start, count) scalars (8 bytes/row of metadata instead of
+    ``4*width`` bytes of gather indices);
   * all width buckets of a round share ONE grid — per-step ``step_dmax``
     bounds the fold loop, so a step of deg-2 road rows runs 2 accumulate
     iterations, not 128, with no per-width dispatch;
@@ -28,20 +29,30 @@ masked contiguous range, see ``repro.graphs.csr.build_fused_fold_plan``):
     dispatches total and the [N, k] candidate scatter shrinks to an [N]
     label scatter.
 
-VMEM budget per grid step (defaults tile_r=128, chunk=128, k=8): the
-gathered tile is 128*128*8 = 128 KiB + 8 KiB sketches — far inside a v5e
-core's ~16 MiB. The flat entry arrays are kept resident (round 0 size =
-|E| entries; ~8 bytes each), which caps a single-core fused round 0 at
-|E| ~ 1M entries — past that budget use the HBM-streaming engine
-(``kernels.mg_sketch.streaming`` / ``fold_backend="pallas_stream"``, or
-``"auto"`` which picks per graph), or shard the graph
-(repro.core.distributed). Single-lane dynamic slices at unaligned starts
-are the price of the in-kernel gather; they are contiguous 128-wide
-loads, the pattern Mosaic handles without layout churn.
+Addressing (what Mosaic compiles): per-step scalars — the fold-loop bound
+``step_dmax``, the row starts and the selection seed — live in SMEM, so
+loop bounds and load addresses are true scalars. A row's range starts at
+an arbitrary entry offset, so the gather loads the ``G + 1`` lane rows
+that cover it (a dynamic sublane slice) and lines the lanes up with a
+dynamic lane rotation (``pltpu.roll``). The gathered rows are then
+transposed once to a ``[chunk, tile_r]`` tile: entry ``i`` of every row
+is sublane row ``i``, which the fold loops read from the scratch ref. The
+sketches live the same way round, ``[k, tile_r]`` (slot on sublanes, row
+on lanes), and are transposed back to the plan's ``[rows, k]`` order on
+the way out.
 
-Validated bit-identically against ``repro.core.sketch`` in interpret mode
-(tests/test_fused_engine.py); this container is CPU-only, TPU is the
-lowering target.
+VMEM per grid step (tile_r=128, chunk=128, k=8): two 64 KiB gather tiles
+and their transposes, plus the [k, tile_r] sketches. The flat entry
+arrays are resident for the whole dispatch (round 0 = |E| entries at 8
+bytes each), which caps a single-core fused round 0 at the
+``fold_engine.DEFAULT_VMEM_BUDGET_BYTES`` budget — past it use the
+HBM-streaming engine (``kernels.mg_sketch.streaming`` /
+``fold_backend="pallas_stream"``, or ``"auto"`` which picks per graph),
+or shard the graph (repro.core.distributed).
+
+Bit-identical to ``repro.core.sketch`` (tests/test_fused_engine.py runs
+the kernels in interpret mode; tests/test_tpu_compile.py compiles them
+for a v5e chip).
 """
 from __future__ import annotations
 
@@ -52,92 +63,137 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.graphs.csr import FusedFoldPlan, FusedRound, compact_active_rows
 
 INT_MAX = jnp.iinfo(jnp.int32).max
-UINT_MAX = np.uint32(0xFFFFFFFF)  # np scalar: inlines as a kernel literal
+LANES = 128  # lane width of a TPU vector register: entries load in rows of it
+# hash_mix's uint32 constants as wrapped int32 (np scalars inline as kernel
+# literals), and the sign bit that turns a hash into an int32 order key
+_HASH_MUL = np.int32(2654435761 - 2**32)
+_HASH_SEED_MUL = np.int32(0x9E3779B9 - 2**32)
+_HASH_FIN_MUL = np.int32(0x85EBCA77 - 2**32)
+_SIGN_BIT = np.int32(-2**31)
 
 
 def _interpret_default() -> bool:
+    """Run the Pallas kernels in interpret mode exactly when no TPU backs
+    JAX (the CPU test suite); on a TPU they always compile."""
     return jax.default_backend() != "tpu"
 
 
-def _gather_tile(start_ref, count_ref, elab_ref, ewgt_ref, chunk: int):
-    """Phase 1: in-kernel gather of [tile_r, chunk] (label, weight) tiles.
+def _lane_groups(chunk: int) -> int:
+    """128-lane groups one row's ``chunk`` entries span."""
+    return -(-chunk // LANES)
 
-    One contiguous ``chunk``-wide dynamic slice per row from the flat entry
-    arrays (VMEM-resident), then pad lanes beyond ``count`` are masked
-    in-register. The entry arrays carry ``chunk`` slack entries so the
-    full-width slice of a short final row never reads out of bounds.
+
+# ---------------------------------------------------------------------------
+# Kernel phases, shared by the fused and the streamed (``streaming.py``)
+# kernels. Tiles are column-major: [entry, row] and [slot, row].
+# ---------------------------------------------------------------------------
+
+
+def _gather_tile(start_ref, src_l_ref, src_w_ref, rows_l_ref, rows_w_ref,
+                 tile_l_ref, tile_w_ref):
+    """Phase 1: in-kernel gather of the step's [chunk, tile_r] entry tiles.
+
+    ``start_ref`` (SMEM [1, tile_r]) holds each row's entry offset into the
+    source ``src_*_ref`` ([n_src, 128] lane rows of the flat label/weight
+    entries). Row ``r`` lands in row ``r`` of the ``rows_*_ref`` scratch
+    ([tile_r, G*128]); one transpose then writes the column-major
+    ``tile_*_ref`` ([G*128, tile_r]). Lanes past a row's count hold
+    neighbouring entries; :func:`_column` masks them.
+
+    The source holds at least ``G + 1`` lane rows. A row that ends in the
+    last lane row of the source (the end of a stream window) loads from
+    one row earlier (``late``); its entries then sit one lane row further
+    down the loaded block.
     """
-    starts = start_ref[0, :]  # [tile_r]
-    counts = count_ref[0, :]
-    tile_r = starts.shape[0]
+    tile_r, width = rows_l_ref.shape
+    groups = width // LANES
+    last = src_l_ref.shape[0] - groups - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
-    def load_row(r, acc):
-        lab, wgt = acc
-        s = jax.lax.dynamic_slice(starts, (r,), (1,))[0]
-        row_l = elab_ref[pl.ds(s, chunk)]
-        row_w = ewgt_ref[pl.ds(s, chunk)]
-        lab = jax.lax.dynamic_update_slice(lab, row_l[None, :], (r, 0))
-        wgt = jax.lax.dynamic_update_slice(wgt, row_w[None, :], (r, 0))
-        return lab, wgt
+    def load_row(r, carry):
+        s = start_ref[0, r]
+        q = s // LANES
+        base = jnp.minimum(q, last)
+        late = q > base
+        off = s - q * LANES
+        low = lane + off < LANES       # lane l reads lane row g, else g + 1
+        shift = (LANES - off) % LANES  # rotate lane `off` down to lane 0
+        for src_ref, dst_ref in ((src_l_ref, rows_l_ref),
+                                 (src_w_ref, rows_w_ref)):
+            blk = src_ref[pl.ds(base, groups + 1), :]
+            for g in range(groups):
+                nxt = min(g + 2, groups)
+                lo = jnp.where(late, blk[g + 1:g + 2], blk[g:g + 1])
+                hi = jnp.where(late, blk[nxt:nxt + 1], blk[g + 1:g + 2])
+                dst_ref[pl.ds(r, 1), g * LANES:(g + 1) * LANES] = jnp.where(
+                    low, pltpu.roll(lo, shift, 1), pltpu.roll(hi, shift, 1))
+        return carry
 
-    init = (jnp.full((tile_r, chunk), -1, jnp.int32),
-            jnp.zeros((tile_r, chunk), jnp.float32))
-    lab, wgt = jax.lax.fori_loop(0, tile_r, load_row, init)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (tile_r, chunk), 1)
-    valid = lane < counts[:, None]
-    return jnp.where(valid, lab, -1), jnp.where(valid, wgt, 0.0)
+    jax.lax.fori_loop(0, tile_r, load_row, 0)
+    tile_l_ref[...] = rows_l_ref[...].T
+    tile_w_ref[...] = rows_w_ref[...].T
 
 
-def _mg_fold(labels, weights, k: int, dmax):
-    """Phase 2: lane-per-row weighted MG fold, loop bound = step's max
-    width (``dmax`` is traced — a deg-2 step runs 2 iterations, not 128).
-    Identical accumulate semantics to ``repro.core.sketch.mg_fold_tile``.
+def _column(tile_l_ref, tile_w_ref, counts, i):
+    """Entry ``i`` of every row of the tile ([1, tile_r] label, weight);
+    rows with fewer than ``i + 1`` entries read the no-op (-1, 0.0)."""
+    live = i < counts
+    return (jnp.where(live, tile_l_ref[pl.ds(i, 1), :], -1),
+            jnp.where(live, tile_w_ref[pl.ds(i, 1), :], 0.0))
+
+
+def _any(mask):
+    """Per-row (lane) OR over the sketch slots (sublanes)."""
+    return jnp.max(mask.astype(jnp.int32), axis=0, keepdims=True) > 0
+
+
+def _mg_fold(tile_l_ref, tile_w_ref, counts, k: int, dmax):
+    """Phase 2: lane-per-row weighted MG fold into [k, tile_r] sketches,
+    loop bound = step's max width (``dmax`` — a deg-2 step runs 2
+    iterations, not 128). Identical accumulate semantics to
+    ``repro.core.sketch.mg_fold_tile``.
     """
-    tile_r, _ = labels.shape
-    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_r, k), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (k, counts.shape[1]), 0)
 
     def body(i, carry):
         s_k, s_v = carry
-        c = jax.lax.dynamic_slice(labels, (0, i), (tile_r, 1))
-        w = jax.lax.dynamic_slice(weights, (0, i), (tile_r, 1))
+        c, w = _column(tile_l_ref, tile_w_ref, counts, i)
         valid = (w > 0) & (c >= 0)
         occupied = s_v > 0
         match = occupied & (s_k == c) & valid
-        any_match = match.any(axis=1, keepdims=True)
+        any_match = _any(match)
         s_v = s_v + jnp.where(match, w, 0.0)
         free = ~occupied
-        has_free = free.any(axis=1, keepdims=True)
-        first_free = jnp.min(jnp.where(free, slot_iota, k), axis=1,
+        has_free = _any(free)
+        first_free = jnp.min(jnp.where(free, slot, k), axis=0,
                              keepdims=True)
-        claim = (valid & ~any_match & has_free) & (slot_iota == first_free)
+        claim = (valid & ~any_match & has_free) & (slot == first_free)
         s_k = jnp.where(claim, c, s_k)
         s_v = jnp.where(claim, w, s_v)
         dec = valid & ~any_match & ~has_free
         s_v = jnp.maximum(s_v - jnp.where(dec, w, 0.0), 0.0)
         return s_k, s_v
 
-    init = (jnp.full((tile_r, k), -1, jnp.int32),
-            jnp.zeros((tile_r, k), jnp.float32))
+    init = (jnp.full(slot.shape, -1, jnp.int32),
+            jnp.zeros(slot.shape, jnp.float32))
     return jax.lax.fori_loop(0, dmax, body, init)
 
 
-def _bm_fold(labels, weights, init, dmax):
+def _bm_fold(tile_l_ref, tile_w_ref, counts, init, dmax):
     """Phase 2 (BM): lane-per-row weighted Boyer-Moore scan, loop bound =
-    the step's max width. ``init`` [tile_r, 1] carries each row's incumbent
+    the step's max width. ``init`` [1, tile_r] carries each row's incumbent
     label (paper Alg. 3 l. 13). Identical accumulate semantics to
-    ``repro.core.sketch.bm_fold_tile`` (pad columns are exact no-ops).
-    Returns ([tile_r, 1] candidate, [tile_r, 1] vote weight).
+    ``repro.core.sketch.bm_fold_tile`` (pad entries are exact no-ops).
+    Returns ([1, tile_r] candidate, [1, tile_r] vote weight).
     """
-    tile_r, _ = labels.shape
-
     def body(i, carry):
         ck, wk = carry
-        c = jax.lax.dynamic_slice(labels, (0, i), (tile_r, 1))
-        w = jax.lax.dynamic_slice(weights, (0, i), (tile_r, 1))
+        c, w = _column(tile_l_ref, tile_w_ref, counts, i)
         valid = (w > 0) & (c >= 0)
         same = valid & (c == ck)
         bigger = valid & ~same & (wk > w)
@@ -147,180 +203,230 @@ def _bm_fold(labels, weights, init, dmax):
         wk = jnp.where(replace, w, wk)
         return ck, wk
 
-    return jax.lax.fori_loop(
-        0, dmax, body, (init, jnp.zeros((tile_r, 1), jnp.float32)))
+    return jax.lax.fori_loop(0, dmax, body,
+                             (init, jnp.zeros(init.shape, jnp.float32)))
 
 
-def _rescan_acc(labels, weights, cand, dmax):
+def _rescan_acc(tile_l_ref, tile_w_ref, counts, cand, dmax):
     """Phase 2 (rescan): exact per-candidate linking weights of a gathered
     tile. Accumulates sequentially over the entry axis — the same order as
     ``repro.core.sketch.rescan_row_partials``, so partials are
-    bit-identical to the reference (pad columns add exact 0.0 no-ops).
-    ``cand`` [tile_r, k] holds each row's candidate labels (-1 empties).
+    bit-identical to the reference (pad entries add exact 0.0 no-ops).
+    ``cand`` [k, tile_r] holds each row's candidate labels (-1 empties).
     """
-    tile_r, k = cand.shape
-
     def body(i, acc):
-        c = jax.lax.dynamic_slice(labels, (0, i), (tile_r, 1))
-        w = jax.lax.dynamic_slice(weights, (0, i), (tile_r, 1))
+        c, w = _column(tile_l_ref, tile_w_ref, counts, i)
         hit = (cand == c) & (cand >= 0)
         return acc + jnp.where(hit, w, 0.0)
 
     return jax.lax.fori_loop(0, dmax, body,
-                             jnp.zeros((tile_r, k), jnp.float32))
+                             jnp.zeros(cand.shape, jnp.float32))
 
 
-def _fused_fold_kernel(dmax_ref, start_ref, count_ref, elab_ref, ewgt_ref,
-                       out_k_ref, out_v_ref, *, k: int, chunk: int):
-    lab, wgt = _gather_tile(start_ref, count_ref, elab_ref, ewgt_ref, chunk)
-    s_k, s_v = _mg_fold(lab, wgt, k, dmax_ref[0, 0])
-    out_k_ref[...] = s_k
-    out_v_ref[...] = s_v
+def _hash_key(x, seed):
+    """In-kernel clone of repro.core.sketch.hash_mix as an int32 order key.
 
-
-def _bm_fold_kernel(dmax_ref, start_ref, count_ref, init_ref, elab_ref,
-                    ewgt_ref, out_c_ref, out_w_ref, *, chunk: int):
-    """One BM step: gather the tile and run the majority-vote scan."""
-    lab, wgt = _gather_tile(start_ref, count_ref, elab_ref, ewgt_ref, chunk)
-    init = init_ref[0, :][:, None]         # [tile_r, 1] incumbent labels
-    ck, wk = _bm_fold(lab, wgt, init, dmax_ref[0, 0])
-    out_c_ref[...] = ck[:, 0][None, :]
-    out_w_ref[...] = wk[:, 0][None, :]
-
-
-def _rescan_fold_kernel(dmax_ref, start_ref, count_ref, cand_ref, elab_ref,
-                        ewgt_ref, out_ref, *, k: int, chunk: int):
-    """One rescan step: gather the tile and score the row candidates."""
-    lab, wgt = _gather_tile(start_ref, count_ref, elab_ref, ewgt_ref, chunk)
-    out_ref[...] = _rescan_acc(lab, wgt, cand_ref[...], dmax_ref[0, 0])
-
-
-def _hash_mix(x, seed):
-    """In-kernel clone of repro.core.sketch.hash_mix (bit-identical)."""
-    h = x.astype(jnp.uint32) * jnp.uint32(2654435761)
-    h = h ^ (seed.astype(jnp.uint32) * jnp.uint32(0x9E3779B9))
-    h = h ^ (h >> 15)
-    h = h * jnp.uint32(0x85EBCA77)
-    return h ^ (h >> 13)
+    The hash runs in int32 (wrapping products and logical shifts give the
+    same bits as the reference's uint32 arithmetic); flipping the sign bit
+    then makes signed order equal the hash's unsigned order, because
+    Mosaic reduces no unsigned integers. ``UINT_MAX`` maps to ``INT_MAX``.
+    """
+    srl = jax.lax.shift_right_logical
+    h = x * _HASH_MUL
+    h = h ^ (seed * _HASH_SEED_MUL)
+    h = h ^ srl(h, jnp.int32(15))
+    h = h * _HASH_FIN_MUL
+    h = h ^ srl(h, jnp.int32(13))
+    return h ^ _SIGN_BIT
 
 
 def _select_rows(s_k, s_v, inc, seed):
-    """In-kernel move selection over a folded [tile_r, k] sketch tile.
+    """In-kernel move selection over folded [k, tile_r] sketches.
 
     Replays ``select_best``'s candidate preprocessing and
     ``choose_from_candidates`` bit-for-bit over the sketch + the incumbent
-    ``inc`` [tile_r, 1]: max weight wins, ties resolved by the
+    ``inc`` [1, tile_r]: max weight wins, ties resolved by the
     per-iteration hash, then the smaller label; no candidate -> keep the
-    incumbent. Returns the chosen label per row [tile_r]. Shared by the
-    fused and streaming (``streaming.py``) select kernels.
+    incumbent. The k slots and the incumbent reduce separately and then
+    combine — max/min are exact, so this equals one (k+1)-wide reduction.
+    Returns the chosen label per row [1, tile_r].
     """
-    cand_c = jnp.where(s_v > 0, s_k, -1)  # select_best's preprocessing
-    cur_w = jnp.max(jnp.where((cand_c == inc) & (s_v > 0), s_v, 0.0),
-                    axis=1, keepdims=True)
-    c_all = jnp.concatenate([cand_c, inc], axis=1)     # [tile_r, k+1]
-    w_all = jnp.concatenate([s_v, cur_w], axis=1)
-    valid = c_all >= 0
-    w = jnp.where(valid, w_all, -1.0)
-    w_best = jnp.max(w, axis=1, keepdims=True)
-    tied = valid & (w >= w_best)
-    h = _hash_mix(c_all, seed)
-    h = jnp.where(tied, h, UINT_MAX)
-    h_best = jnp.min(h, axis=1, keepdims=True)
-    in_hash = tied & (h <= h_best)
-    c_best = jnp.min(jnp.where(in_hash, c_all, INT_MAX), axis=1)
-    return jnp.where(c_best == INT_MAX, inc[:, 0], c_best)
+    cand = jnp.where(s_v > 0, s_k, -1)  # select_best's preprocessing
+    cur_w = jnp.max(jnp.where((cand == inc) & (s_v > 0), s_v, 0.0),
+                    axis=0, keepdims=True)
+    w_s = jnp.where(cand >= 0, s_v, -1.0)
+    w_i = jnp.where(inc >= 0, cur_w, -1.0)
+    w_best = jnp.maximum(jnp.max(w_s, axis=0, keepdims=True), w_i)
+    tied_s = (cand >= 0) & (w_s >= w_best)
+    tied_i = (inc >= 0) & (w_i >= w_best)
+    h_s = jnp.where(tied_s, _hash_key(cand, seed), INT_MAX)
+    h_i = jnp.where(tied_i, _hash_key(inc, seed), INT_MAX)
+    h_best = jnp.minimum(jnp.min(h_s, axis=0, keepdims=True), h_i)
+    c_s = jnp.min(jnp.where(tied_s & (h_s <= h_best), cand, INT_MAX),
+                  axis=0, keepdims=True)
+    c_best = jnp.minimum(c_s, jnp.where(tied_i & (h_i <= h_best), inc,
+                                        INT_MAX))
+    return jnp.where(c_best == INT_MAX, inc, c_best)
 
 
-def _fused_select_kernel(dmax_ref, start_ref, count_ref, inc_ref, seed_ref,
-                         elab_ref, ewgt_ref, out_c_ref, *, k: int,
-                         chunk: int):
-    """Final-round fold + move selection in one dispatch.
+# ---------------------------------------------------------------------------
+# Kernels: (dmax, starts, counts, [extras...], labels, weights) -> outputs,
+# then the four gather scratch tiles. Shared by fused and streamed rounds.
+# ---------------------------------------------------------------------------
 
-    Folds the tile like ``_fused_fold_kernel``, then applies
-    :func:`_select_rows`. The final round has at most one row per vertex,
-    so the row's choice IS the vertex's choice.
+
+def _fold_kernel(dmax_ref, start_ref, count_ref, lab_ref, wgt_ref,
+                 out_k_ref, out_v_ref, *scratch, k: int):
+    """One MG step: gather the tile and fold it into [k, tile_r] sketches."""
+    _gather_tile(start_ref, lab_ref, wgt_ref, *scratch)
+    out_k_ref[...], out_v_ref[...] = _mg_fold(scratch[2], scratch[3],
+                                              count_ref[...], k,
+                                              dmax_ref[0, 0])
+
+
+def _select_kernel(dmax_ref, start_ref, count_ref, inc_ref, seed_ref,
+                   lab_ref, wgt_ref, out_c_ref, *scratch, k: int):
+    """Final-round fold + move selection in one step. The final round has
+    at most one row per vertex, so the row's choice IS the vertex's."""
+    _gather_tile(start_ref, lab_ref, wgt_ref, *scratch)
+    s_k, s_v = _mg_fold(scratch[2], scratch[3], count_ref[...], k,
+                        dmax_ref[0, 0])
+    out_c_ref[...] = _select_rows(s_k, s_v, inc_ref[...], seed_ref[0, 0])
+
+
+def _bm_kernel(dmax_ref, start_ref, count_ref, init_ref, lab_ref, wgt_ref,
+               out_c_ref, out_w_ref, *scratch):
+    """One BM step: gather the tile and run the majority-vote scan."""
+    _gather_tile(start_ref, lab_ref, wgt_ref, *scratch)
+    ck, wk = _bm_fold(scratch[2], scratch[3], count_ref[...],
+                      init_ref[...], dmax_ref[0, 0])
+    out_c_ref[...] = ck
+    out_w_ref[...] = wk
+
+
+def _rescan_kernel(dmax_ref, start_ref, count_ref, cand_ref, lab_ref,
+                   wgt_ref, out_ref, *scratch):
+    """One rescan step: gather the tile and score the row candidates."""
+    _gather_tile(start_ref, lab_ref, wgt_ref, *scratch)
+    out_ref[...] = _rescan_acc(scratch[2], scratch[3], count_ref[...],
+                               cand_ref[...], dmax_ref[0, 0])
+
+
+def slot_major(rows_k: jnp.ndarray, tile_r: int) -> jnp.ndarray:
+    """[n_steps * tile_r, k] per-row slots -> the kernels' lane-dense
+    [n_steps, k, tile_r] step blocks (slot on sublanes, row on lanes)."""
+    k = rows_k.shape[1]
+    return rows_k.reshape(-1, tile_r, k).transpose(0, 2, 1)
+
+
+def row_major(steps: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of :func:`slot_major`: [n_steps, k, tile_r] -> the plan's
+    [n_steps * tile_r, k] row order, which later rounds read flattened."""
+    return steps.transpose(0, 2, 1).reshape(-1, steps.shape[1])
+
+
+def _step_spec(*block):
+    """Grid step ``i``'s block of a per-step [n_steps, *block] operand."""
+    return pl.BlockSpec((None,) + block, lambda i: (i,) + (0,) * len(block))
+
+
+def _round_call(kernel, rnd, extras, entries, entries_spec, outs, *,
+                chunk: int, interpret: bool | None):
+    """Launch one round's kernel over its ``n_steps`` grid.
+
+    ``rnd`` is a ``FusedRound`` or ``StreamedRound`` (the per-step
+    ``step_dmax``/``row_start``/``row_count`` metadata); ``extras`` are
+    (operand, per-step block) pairs of [n_steps, *block] operands between
+    the metadata and the entries, or (operand, None) for a whole-array
+    SMEM scalar; ``entries`` are the (labels, weights) lane-row sources
+    under ``entries_spec``; ``outs`` are (dtype, per-step block) pairs.
     """
-    lab, wgt = _gather_tile(start_ref, count_ref, elab_ref, ewgt_ref, chunk)
-    s_k, s_v = _mg_fold(lab, wgt, k, dmax_ref[0, 0])
-    inc = inc_ref[0, :][:, None]          # [tile_r, 1] incumbent labels
-    out_c_ref[...] = _select_rows(s_k, s_v, inc, seed_ref[0, 0])[None, :]
+    n_steps, tile_r = rnd.row_start.shape
+    width = _lane_groups(chunk) * LANES
+    smem = pltpu.SMEM
+    in_specs = [
+        pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0), memory_space=smem),
+        pl.BlockSpec((None, 1, tile_r), lambda i: (i, 0, 0),
+                     memory_space=smem),
+        _step_spec(1, tile_r),
+    ]
+    args = [rnd.step_dmax.reshape(n_steps, 1, 1),
+            rnd.row_start.reshape(n_steps, 1, tile_r),
+            rnd.row_count.reshape(n_steps, 1, tile_r)]
+    for x, block in extras:
+        in_specs.append(pl.BlockSpec(memory_space=smem) if block is None
+                        else _step_spec(*block))
+        args.append(x if block is None else x.reshape((n_steps,) + block))
+    in_specs += [entries_spec, entries_spec]
+    args += list(entries)
+    return pl.pallas_call(
+        kernel,
+        grid=(n_steps,),
+        in_specs=in_specs,
+        out_specs=[_step_spec(*block) for _, block in outs],
+        out_shape=[jax.ShapeDtypeStruct((n_steps,) + block, dtype)
+                   for dtype, block in outs],
+        scratch_shapes=[pltpu.VMEM((tile_r, width), jnp.int32),
+                        pltpu.VMEM((tile_r, width), jnp.float32),
+                        pltpu.VMEM((width, tile_r), jnp.int32),
+                        pltpu.VMEM((width, tile_r), jnp.float32)],
+        interpret=_interpret_default() if interpret is None else interpret,
+    )(*args)
 
 
-def _pad_entries(x: jnp.ndarray, length: int, chunk: int, fill):
-    """Pad the flat entry array to ``length + chunk`` (slack for the
-    full-width in-kernel slice of short rows near the array end)."""
-    need = length + chunk - x.shape[0]
-    if need <= 0:
-        return x
-    return jnp.concatenate([x, jnp.full((need,), fill, dtype=x.dtype)])
+def _pad_entry_rows(x: jnp.ndarray, length: int, chunk: int, fill
+                    ) -> jnp.ndarray:
+    """Flat entry array -> [rows, 128] lane rows, padded so that every
+    row range of the ``length``-entry round (start <= length) finds the
+    ``G + 1`` lane rows :func:`_gather_tile` loads from its start."""
+    rows = -(-max(length, x.shape[0]) // LANES) + _lane_groups(chunk) + 1
+    x = jnp.concatenate(
+        [x, jnp.full((rows * LANES - x.shape[0],), fill, x.dtype)])
+    return x.reshape(rows, LANES)
+
+
+def _fused_round(kernel, rnd: FusedRound, extras, entry_labels,
+                 entry_weights, outs, *, chunk: int, interpret):
+    """One fused dispatch: the round's whole entry arrays stay resident in
+    VMEM (a whole-array block, copied in once) for every grid step."""
+    el = _pad_entry_rows(entry_labels.astype(jnp.int32), rnd.n_entries_in,
+                         chunk, -1)
+    ew = _pad_entry_rows(entry_weights.astype(jnp.float32),
+                         rnd.n_entries_in, chunk, 0.0)
+    return _round_call(kernel, rnd, extras, (el, ew),
+                       pl.BlockSpec(memory_space=pltpu.VMEM), outs,
+                       chunk=chunk, interpret=interpret)
 
 
 def fused_fold_round(rnd: FusedRound, entry_labels: jnp.ndarray,
                      entry_weights: jnp.ndarray, *, k: int, chunk: int,
-                     interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     interpret: bool | None = None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One dispatch covering every width bucket of the round.
 
     Returns padded ([n_steps*tile_r, k], [n_steps*tile_r, k]) sketches in
     fused row order (pad rows fold to empty sketches).
     """
-    n_steps, tile_r = rnd.row_start.shape
-    el = _pad_entries(entry_labels.astype(jnp.int32), rnd.n_entries_in,
-                      chunk, -1)
-    ew = _pad_entries(entry_weights.astype(jnp.float32), rnd.n_entries_in,
-                      chunk, 0.0)
-    e = el.shape[0]
-    rows = n_steps * tile_r
-    return pl.pallas_call(
-        functools.partial(_fused_fold_kernel, k=k, chunk=chunk),
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry labels
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry weights
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, k), jnp.int32),
-            jax.ShapeDtypeStruct((rows, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count, el, ew)
+    block = (k, rnd.tile_r)
+    s_k, s_v = _fused_round(
+        functools.partial(_fold_kernel, k=k), rnd, (), entry_labels,
+        entry_weights, [(jnp.int32, block), (jnp.float32, block)],
+        chunk=chunk, interpret=interpret)
+    return row_major(s_k), row_major(s_v)
 
 
 def fused_select_round(rnd: FusedRound, entry_labels: jnp.ndarray,
                        entry_weights: jnp.ndarray, incumbents: jnp.ndarray,
                        seed: jnp.ndarray, *, k: int, chunk: int,
-                       interpret: bool) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """Final-round dispatch: fold + per-row winning label [n_steps*tile_r]."""
-    n_steps, tile_r = rnd.row_start.shape
-    el = _pad_entries(entry_labels.astype(jnp.int32), rnd.n_entries_in,
-                      chunk, -1)
-    ew = _pad_entries(entry_weights.astype(jnp.float32), rnd.n_entries_in,
-                      chunk, 0.0)
-    e = el.shape[0]
-    out = pl.pallas_call(
-        functools.partial(_fused_select_kernel, k=k, chunk=chunk),
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # incumbents
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),        # seed
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry labels
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry weights
-        ],
-        out_specs=pl.BlockSpec((1, tile_r), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_steps, tile_r), jnp.int32),
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count,
-      incumbents.reshape(n_steps, tile_r),
-      seed.astype(jnp.int32).reshape(1, 1), el, ew)
+    tile_r = rnd.tile_r
+    (out,) = _fused_round(
+        functools.partial(_select_kernel, k=k), rnd,
+        [(incumbents, (1, tile_r)),
+         (seed.astype(jnp.int32).reshape(1, 1), None)],
+        entry_labels, entry_weights, [(jnp.int32, (1, tile_r))],
+        chunk=chunk, interpret=interpret)
     return out.reshape(-1)
 
 
@@ -336,8 +442,6 @@ def run_mg_plan_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
     row order (inactive rows hold empty sketches), so the output layout is
     selection-invariant.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     labels, weights = entry_labels, entry_weights
     if selection is None:
         for rnd in plan.rounds:
@@ -373,8 +477,6 @@ def select_best_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
     — the caller must have checked ``selection.cap_rows`` fits the
     frontier (``csr.fused_active_rows``).
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if plan.n_nodes == 0:
         return labels
     el, ew = entry_labels, entry_weights
@@ -424,7 +526,8 @@ def select_best_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
 def bm_fold_round_fused(rnd: FusedRound, entry_labels: jnp.ndarray,
                         entry_weights: jnp.ndarray,
                         init_labels: jnp.ndarray, *, chunk: int,
-                        interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                        interpret: bool | None = None
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One dispatch covering the whole BM fold (only round 0 is ever
     folded — BM partials merge by max-reduce, not by re-folding).
 
@@ -432,40 +535,17 @@ def bm_fold_round_fused(rnd: FusedRound, entry_labels: jnp.ndarray,
     label (-1 on pad rows). Returns per-row ([rows] candidate label,
     [rows] vote weight) partial states in fused row order.
     """
-    n_steps, tile_r = rnd.row_start.shape
-    el = _pad_entries(entry_labels.astype(jnp.int32), rnd.n_entries_in,
-                      chunk, -1)
-    ew = _pad_entries(entry_weights.astype(jnp.float32), rnd.n_entries_in,
-                      chunk, 0.0)
-    e = el.shape[0]
-    ck, wk = pl.pallas_call(
-        functools.partial(_bm_fold_kernel, chunk=chunk),
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # init labels
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry labels
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry weights
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_steps, tile_r), jnp.int32),
-            jax.ShapeDtypeStruct((n_steps, tile_r), jnp.float32),
-        ],
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count,
-      init_labels.reshape(n_steps, tile_r), el, ew)
+    row = (1, rnd.tile_r)
+    ck, wk = _fused_round(_bm_kernel, rnd, [(init_labels, row)],
+                          entry_labels, entry_weights,
+                          [(jnp.int32, row), (jnp.float32, row)],
+                          chunk=chunk, interpret=interpret)
     return ck.reshape(-1), wk.reshape(-1)
 
 
 def run_bm_plan_generic(plan, entry_labels: jnp.ndarray,
                         entry_weights: jnp.ndarray, cur_labels: jnp.ndarray,
-                        fold_round_fn, interpret: bool
+                        fold_round_fn, interpret: bool | None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Shared νBM driver for the fused and streamed engines.
 
@@ -506,8 +586,6 @@ def run_bm_plan_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
     bit-identical partial set; vertices with no compacted rows come back
     (-1, 0) — the gate masks them, like dense off-frontier moves.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if selection is None:
         return run_bm_plan_generic(plan, entry_labels, entry_weights,
                                    cur_labels, bm_fold_round_fused,
@@ -531,7 +609,7 @@ def run_bm_plan_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
 
 def rescan_round_fused(rnd: FusedRound, entry_labels: jnp.ndarray,
                        entry_weights: jnp.ndarray, cand_rows: jnp.ndarray,
-                       *, k: int, chunk: int, interpret: bool
+                       *, k: int, chunk: int, interpret: bool | None = None
                        ) -> jnp.ndarray:
     """One dispatch re-reading round 0 to score each row's candidates.
 
@@ -539,34 +617,19 @@ def rescan_round_fused(rnd: FusedRound, entry_labels: jnp.ndarray,
     vertex's) consolidated candidate labels. Returns [n_steps * tile_r, k]
     float32 partial linking weights in fused row order.
     """
-    n_steps, tile_r = rnd.row_start.shape
-    el = _pad_entries(entry_labels.astype(jnp.int32), rnd.n_entries_in,
-                      chunk, -1)
-    ew = _pad_entries(entry_weights.astype(jnp.float32), rnd.n_entries_in,
-                      chunk, 0.0)
-    e = el.shape[0]
-    out = pl.pallas_call(
-        functools.partial(_rescan_fold_kernel, k=k, chunk=chunk),
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((tile_r, k), lambda i: (i, 0)),   # candidates
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry labels
-            pl.BlockSpec((e,), lambda i: (0,)),            # entry weights
-        ],
-        out_specs=pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_steps * tile_r, k), jnp.float32),
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count, cand_rows, el, ew)
-    return out
+    block = (k, rnd.tile_r)
+    (out,) = _fused_round(_rescan_kernel, rnd,
+                          [(slot_major(cand_rows, rnd.tile_r), block)],
+                          entry_labels, entry_weights,
+                          [(jnp.float32, block)], chunk=chunk,
+                          interpret=interpret)
+    return row_major(out)
 
 
 def rescan_select_generic(plan, entry_labels: jnp.ndarray,
                           entry_weights: jnp.ndarray, labels: jnp.ndarray,
                           seed: jnp.ndarray, run_plan_fn, rescan_round_fn,
-                          interpret: bool) -> jnp.ndarray:
+                          interpret: bool | None) -> jnp.ndarray:
     """Shared double-scan driver for the fused and streamed engines.
 
     Runs the engine's MG fold (``run_plan_fn``), scatters the final
@@ -613,8 +676,6 @@ def rescan_select_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
     ``choose_from_candidates`` keeps their label — bit-identical on the
     frontier to the dense run.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if selection is None:
         return rescan_select_generic(plan, entry_labels, entry_weights,
                                      labels, seed, run_mg_plan_fused,

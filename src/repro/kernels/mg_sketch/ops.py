@@ -4,24 +4,20 @@ Pads row counts to the tile size, dispatches to the kernel, and slices the
 padding back off. Signatures match ``repro.core.sketch.{mg,bm}_fold_tile``
 so either backend plugs into ``run_mg_plan`` / ``run_bm_plan`` unchanged.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels target TPU and are validated in interpret mode per the brief).
+``interpret`` defaults to True exactly when no TPU backs JAX
+(``fused._interpret_default``).
 """
 from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.mg_sketch.fused import _interpret_default
 from repro.kernels.mg_sketch.mg_sketch import (bm_fold_pallas_call,
                                                mg_fold_pallas_call)
 
 DEFAULT_TILE_R = 512
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_rows(x: jnp.ndarray, tile_r: int, fill) -> jnp.ndarray:

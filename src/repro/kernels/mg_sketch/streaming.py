@@ -5,28 +5,26 @@ pass (one round-0 dispatch), all with O(window) residency (DESIGN.md
 
 The fused engine (``fused.py``) passes each round's flat entry arrays whole,
 so they are VMEM-resident for the duration of the dispatch — round 0 is |E|
-entries, capping a single core at |E| ~ 1M entries. The streaming kernels
-here remove that cap by processing each round in fixed-size **entry
-windows** (``repro.graphs.csr.build_streamed_fold_plan``):
+entries, capped by ``fold_engine.DEFAULT_VMEM_BUDGET_BYTES``. The streaming
+kernels here remove that cap by processing each round in fixed-size
+**entry windows** (``repro.graphs.csr.build_streamed_fold_plan``):
 
   * the round's entries are re-laid into ``[n_windows * W]`` windowed
     arrays (one XLA gather per round; W = ``window_entries``). The plan
     window-aligns every row — rows pack contiguously inside a window with
-    ``rel_start + chunk <= W`` — so no row's full-``chunk`` slice ever
-    crosses a window edge;
+    ``rel_start + chunk <= W`` — so no row's range ever crosses a window
+    edge;
   * the kernel grid runs one step per window. Each step's BlockSpec selects
-    only its own W-entry window, so the Pallas pipeline streams window
-    ``i+1`` HBM -> VMEM (the emitter's double-buffered block copies) while
-    window ``i`` folds: per-step entry residency is ``2 * W * 8`` bytes
-    (two label+weight window buffers), independent of |E|;
-  * within a step the dataflow is the fused kernel's, reused verbatim:
-    in-register gather of the [tile_r, chunk] row tile from the window
-    (``fused._gather_tile``), lane-per-row MG fold bounded by the window's
-    ``step_dmax`` (``fused._mg_fold``), and — on the final round — fused
-    move selection (``fused._select_rows``). Partial [tile_r, k] sketches
-    are carried across window steps through the padded per-window output
-    blocks; later rounds merge a vertex's partials via the plan's
-    position-table gather.
+    only its own window, viewed as ``[W / 128, 128]`` lane rows, so the
+    Pallas pipeline streams window ``i+1`` HBM -> VMEM (double-buffered
+    block copies) while window ``i`` folds: per-step entry residency is
+    ``2 * W * 8`` bytes (two label+weight window buffers), independent of
+    |E|;
+  * within a step the kernels ARE the fused ones (``fused._fold_kernel``
+    and friends): the in-kernel gather of the row tile from the window,
+    the lane-per-row fold bounded by the window's ``step_dmax``, and — on
+    the final round — fused move selection. Later rounds merge a vertex's
+    partial sketches via the plan's position-table gather.
 
 Cost vs the fused engine: same dispatch count (``n_rounds`` per MG
 iteration, the last fused with selection) and the same real entries read,
@@ -37,26 +35,25 @@ DESIGN.md §13) round 0 — the O(|E|) share of that cost — is
 pre-materialized at build time: aligned rounds (``StreamedRound.aligned``)
 arrive with their entries already windowed and every round driver below
 skips ``windowed_entries`` for them, so the per-iteration re-layout
-traffic shrinks to the small later-round merges. Validated bit-identical
-to ``repro.core.sketch`` in interpret mode (tests/test_stream_engine.py);
-this container is CPU-only, TPU is the lowering target.
+traffic shrinks to the small later-round merges. Bit-identical to
+``repro.core.sketch`` (tests/test_stream_engine.py in interpret mode;
+tests/test_tpu_compile.py compiles the kernels for a v5e chip).
 """
 from __future__ import annotations
 
 import functools
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.graphs.csr import (StreamedFoldPlan, StreamedRound,
                               compact_active_rows)
-from repro.kernels.mg_sketch.fused import (_bm_fold, _gather_tile,
-                                           _interpret_default, _mg_fold,
-                                           _rescan_acc, _select_rows,
-                                           rescan_select_generic,
-                                           run_bm_plan_generic)
+from repro.kernels.mg_sketch.fused import (LANES, _bm_kernel, _fold_kernel,
+                                           _lane_groups, _rescan_kernel,
+                                           _round_call, _select_kernel,
+                                           rescan_select_generic, row_major,
+                                           run_bm_plan_generic, slot_major)
 
 
 def windowed_entries(gather: jnp.ndarray, entry_labels: jnp.ndarray,
@@ -90,31 +87,34 @@ def _aligned_window_entries(entry_labels: jnp.ndarray,
     return entry_labels.astype(jnp.int32), entry_weights.astype(jnp.float32)
 
 
-def _stream_fold_kernel(dmax_ref, start_ref, count_ref, wlab_ref, wwgt_ref,
-                        out_k_ref, out_v_ref, *, k: int, chunk: int):
-    """One window step: gather the row tile from the resident window and
-    fold it. ``start_ref`` holds window-relative offsets, so the fused
-    gather phase works unchanged against the [W]-entry window block."""
-    lab, wgt = _gather_tile(start_ref, count_ref, wlab_ref, wwgt_ref, chunk)
-    s_k, s_v = _mg_fold(lab, wgt, k, dmax_ref[0, 0])
-    out_k_ref[...] = s_k
-    out_v_ref[...] = s_v
-
-
-def _stream_select_kernel(dmax_ref, start_ref, count_ref, inc_ref, seed_ref,
-                          wlab_ref, wwgt_ref, out_c_ref, *, k: int,
-                          chunk: int):
-    """Final-round window step: fold + fused move selection (the streaming
-    analogue of ``fused._fused_select_kernel``)."""
-    lab, wgt = _gather_tile(start_ref, count_ref, wlab_ref, wwgt_ref, chunk)
-    s_k, s_v = _mg_fold(lab, wgt, k, dmax_ref[0, 0])
-    inc = inc_ref[0, :][:, None]          # [tile_r, 1] incumbent labels
-    out_c_ref[...] = _select_rows(s_k, s_v, inc, seed_ref[0, 0])[None, :]
+def _stream_round(kernel, rnd: StreamedRound, extras, entry_labels,
+                  entry_weights, outs, *, chunk: int, interpret):
+    """One streamed dispatch: grid over windows, one W-entry window of
+    [W/128, 128] lane rows resident per step (the kernels are the fused
+    ones; ``row_start`` holds window-relative offsets)."""
+    if rnd.aligned:  # entries pre-materialized window-aligned at build time
+        wl, ww = _aligned_window_entries(entry_labels, entry_weights)
+    else:
+        wl, ww = windowed_entries(rnd.entry_gather, entry_labels,
+                                  entry_weights)
+    rows = rnd.window_entries // LANES
+    wl = wl.reshape(rnd.n_windows, rows, LANES)
+    ww = ww.reshape(rnd.n_windows, rows, LANES)
+    need = _lane_groups(chunk) + 1
+    if rows < need:  # a window narrower than one row gather's load
+        pad = ((0, 0), (0, need - rows), (0, 0))
+        wl = jnp.pad(wl, pad, constant_values=-1)
+        ww = jnp.pad(ww, pad, constant_values=0.0)
+        rows = need
+    window = pl.BlockSpec((None, rows, LANES), lambda i: (i, 0, 0))
+    return _round_call(kernel, rnd, extras, (wl, ww), window, outs,
+                       chunk=chunk, interpret=interpret)
 
 
 def stream_fold_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
                       entry_weights: jnp.ndarray, *, k: int, chunk: int,
-                      interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                      interpret: bool | None = None
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One streamed dispatch: grid over windows, one W-entry window resident
     per step.
 
@@ -126,71 +126,30 @@ def stream_fold_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
     Returns padded ([n_windows * tile_r, k] int32, [..., k] float32)
     sketches in window-slot order (pad rows fold to empty sketches).
     """
-    n_windows, tile_r = rnd.row_start.shape
-    w = rnd.window_entries
-    if rnd.aligned:  # entries pre-materialized window-aligned at build time
-        wl, ww = _aligned_window_entries(entry_labels, entry_weights)
-    else:
-        wl, ww = windowed_entries(rnd.entry_gather, entry_labels,
-                                  entry_weights)
-    rows = n_windows * tile_r
-    return pl.pallas_call(
-        functools.partial(_stream_fold_kernel, k=k, chunk=chunk),
-        grid=(n_windows,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start (rel)
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((w,), lambda i: (i,)),            # label window
-            pl.BlockSpec((w,), lambda i: (i,)),            # weight window
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, k), jnp.int32),
-            jax.ShapeDtypeStruct((rows, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count, wl, ww)
+    block = (k, rnd.tile_r)
+    s_k, s_v = _stream_round(
+        functools.partial(_fold_kernel, k=k), rnd, (), entry_labels,
+        entry_weights, [(jnp.int32, block), (jnp.float32, block)],
+        chunk=chunk, interpret=interpret)
+    return row_major(s_k), row_major(s_v)
 
 
 def stream_select_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
                         entry_weights: jnp.ndarray, incumbents: jnp.ndarray,
                         seed: jnp.ndarray, *, k: int, chunk: int,
-                        interpret: bool) -> jnp.ndarray:
+                        interpret: bool | None = None) -> jnp.ndarray:
     """Final-round streamed dispatch: fold + per-row winning label.
 
     ``incumbents`` [n_windows * tile_r] int32 carries each row slot's
     current vertex label (-1 on pad slots). Returns the chosen label per
     row slot [n_windows * tile_r] int32.
     """
-    n_windows, tile_r = rnd.row_start.shape
-    w = rnd.window_entries
-    if rnd.aligned:  # entries pre-materialized window-aligned at build time
-        wl, ww = _aligned_window_entries(entry_labels, entry_weights)
-    else:
-        wl, ww = windowed_entries(rnd.entry_gather, entry_labels,
-                                  entry_weights)
-    out = pl.pallas_call(
-        functools.partial(_stream_select_kernel, k=k, chunk=chunk),
-        grid=(n_windows,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start (rel)
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # incumbents
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),        # seed
-            pl.BlockSpec((w,), lambda i: (i,)),            # label window
-            pl.BlockSpec((w,), lambda i: (i,)),            # weight window
-        ],
-        out_specs=pl.BlockSpec((1, tile_r), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_windows, tile_r), jnp.int32),
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count,
-      incumbents.reshape(n_windows, tile_r),
-      seed.astype(jnp.int32).reshape(1, 1), wl, ww)
+    row = (1, rnd.tile_r)
+    (out,) = _stream_round(
+        functools.partial(_select_kernel, k=k), rnd,
+        [(incumbents, row), (seed.astype(jnp.int32).reshape(1, 1), None)],
+        entry_labels, entry_weights, [(jnp.int32, row)], chunk=chunk,
+        interpret=interpret)
     return out.reshape(-1)
 
 
@@ -212,8 +171,6 @@ def run_mg_plan_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
     frontier-compacted active windows and scatters its sketches back to
     dense window-slot order, so the output layout is selection-invariant.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     labels, weights = entry_labels, entry_weights
     if selection is None:
         for rnd in plan.rounds:
@@ -253,8 +210,6 @@ def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
     compute, others carry through) — the frontier gate masks both, exactly
     as it masks the dense mover's off-frontier moves.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if plan.n_nodes == 0:
         return labels
     el, ew = entry_labels, entry_weights
@@ -301,31 +256,10 @@ def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _stream_bm_kernel(dmax_ref, start_ref, count_ref, init_ref, wlab_ref,
-                      wwgt_ref, out_c_ref, out_w_ref, *, chunk: int):
-    """One BM window step: gather the row tile from the resident window
-    and run the majority-vote scan (the streaming analogue of
-    ``fused._bm_fold_kernel``)."""
-    lab, wgt = _gather_tile(start_ref, count_ref, wlab_ref, wwgt_ref, chunk)
-    init = init_ref[0, :][:, None]         # [tile_r, 1] incumbent labels
-    ck, wk = _bm_fold(lab, wgt, init, dmax_ref[0, 0])
-    out_c_ref[...] = ck[:, 0][None, :]
-    out_w_ref[...] = wk[:, 0][None, :]
-
-
-def _stream_rescan_kernel(dmax_ref, start_ref, count_ref, cand_ref,
-                          wlab_ref, wwgt_ref, out_ref, *, k: int,
-                          chunk: int):
-    """One rescan window step: gather the row tile from the resident
-    window and score the row candidates."""
-    lab, wgt = _gather_tile(start_ref, count_ref, wlab_ref, wwgt_ref, chunk)
-    out_ref[...] = _rescan_acc(lab, wgt, cand_ref[...], dmax_ref[0, 0])
-
-
 def bm_fold_round_stream(rnd: StreamedRound, entry_labels: jnp.ndarray,
                          entry_weights: jnp.ndarray,
                          init_labels: jnp.ndarray, *, chunk: int,
-                         interpret: bool
+                         interpret: bool | None = None
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One streamed dispatch covering the whole BM fold: grid over round-0
     windows, one W-entry window resident per step.
@@ -334,35 +268,11 @@ def bm_fold_round_stream(rnd: StreamedRound, entry_labels: jnp.ndarray,
     incumbent label (-1 on pad slots). Returns per-slot ([rows] candidate
     label, [rows] vote weight) partial states in window-slot order.
     """
-    n_windows, tile_r = rnd.row_start.shape
-    w = rnd.window_entries
-    if rnd.aligned:  # entries pre-materialized window-aligned at build time
-        wl, ww = _aligned_window_entries(entry_labels, entry_weights)
-    else:
-        wl, ww = windowed_entries(rnd.entry_gather, entry_labels,
-                                  entry_weights)
-    ck, wk = pl.pallas_call(
-        functools.partial(_stream_bm_kernel, chunk=chunk),
-        grid=(n_windows,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start (rel)
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # init labels
-            pl.BlockSpec((w,), lambda i: (i,)),            # label window
-            pl.BlockSpec((w,), lambda i: (i,)),            # weight window
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_windows, tile_r), jnp.int32),
-            jax.ShapeDtypeStruct((n_windows, tile_r), jnp.float32),
-        ],
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count,
-      init_labels.reshape(n_windows, tile_r), wl, ww)
+    row = (1, rnd.tile_r)
+    ck, wk = _stream_round(_bm_kernel, rnd, [(init_labels, row)],
+                           entry_labels, entry_weights,
+                           [(jnp.int32, row), (jnp.float32, row)],
+                           chunk=chunk, interpret=interpret)
     return ck.reshape(-1), wk.reshape(-1)
 
 
@@ -383,8 +293,6 @@ def run_bm_plan_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
     partially covered by active windows produce gate-masked off-frontier
     values.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if selection is None:
         return run_bm_plan_generic(plan, entry_labels, entry_weights,
                                    cur_labels, bm_fold_round_stream,
@@ -403,37 +311,20 @@ def run_bm_plan_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
 
 def rescan_round_stream(rnd: StreamedRound, entry_labels: jnp.ndarray,
                         entry_weights: jnp.ndarray, cand_rows: jnp.ndarray,
-                        *, k: int, chunk: int, interpret: bool
+                        *, k: int, chunk: int, interpret: bool | None = None
                         ) -> jnp.ndarray:
     """One streamed dispatch re-reading round 0 to score each row slot's
     candidates through the windowed layout. ``cand_rows``
     [n_windows * tile_r, k] int32. Returns [n_windows * tile_r, k] float32
     partial linking weights in window-slot order.
     """
-    n_windows, tile_r = rnd.row_start.shape
-    w = rnd.window_entries
-    if rnd.aligned:  # entries pre-materialized window-aligned at build time
-        wl, ww = _aligned_window_entries(entry_labels, entry_weights)
-    else:
-        wl, ww = windowed_entries(rnd.entry_gather, entry_labels,
-                                  entry_weights)
-    out = pl.pallas_call(
-        functools.partial(_stream_rescan_kernel, k=k, chunk=chunk),
-        grid=(n_windows,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),        # step_dmax
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_start (rel)
-            pl.BlockSpec((1, tile_r), lambda i: (i, 0)),   # row_count
-            pl.BlockSpec((tile_r, k), lambda i: (i, 0)),   # candidates
-            pl.BlockSpec((w,), lambda i: (i,)),            # label window
-            pl.BlockSpec((w,), lambda i: (i,)),            # weight window
-        ],
-        out_specs=pl.BlockSpec((tile_r, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_windows * tile_r, k),
-                                       jnp.float32),
-        interpret=interpret,
-    )(rnd.step_dmax, rnd.row_start, rnd.row_count, cand_rows, wl, ww)
-    return out
+    block = (k, rnd.tile_r)
+    (out,) = _stream_round(_rescan_kernel, rnd,
+                           [(slot_major(cand_rows, rnd.tile_r), block)],
+                           entry_labels, entry_weights,
+                           [(jnp.float32, block)], chunk=chunk,
+                           interpret=interpret)
+    return row_major(out)
 
 
 def rescan_select_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
@@ -449,8 +340,6 @@ def rescan_select_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
     only over compacted active round-0 windows; off-frontier vertices keep
     an all-empty candidate set and their label.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if selection is None:
         return rescan_select_generic(plan, entry_labels, entry_weights,
                                      labels, seed, run_mg_plan_stream,

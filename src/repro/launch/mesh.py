@@ -6,16 +6,8 @@ import jax
 
 
 def make_mesh(shape, axes, *, axis_types=None):
-    """Version-adaptive ``jax.make_mesh``.
-
-    ``jax.sharding.AxisType`` (and the matching ``axis_types`` kwarg) only
-    exist on newer JAX releases; on older installs (e.g. 0.4.x) every axis
-    is implicitly Auto, which is the only type we ever request. All mesh
-    construction in this repo goes through here so tests / benchmarks /
-    examples run on both.
-    """
-    if not hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` unless told otherwise —
+    the only axis type the repo's shard_map steps are written for."""
     if axis_types is None:
         axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
     return jax.make_mesh(shape, axes, axis_types=axis_types)

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import dense_init
-from repro.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,7 +163,7 @@ def moe_ffn(params, x: jnp.ndarray, cfg: MoEConfig) -> jnp.ndarray:
 
         espec = P(ep, None, dfs_ if len(dfs_) > 1 else dfs_[0])
         dspec = P(ep, dfs_ if len(dfs_) > 1 else dfs_[0], None)
-        y = shard_map(
+        y = jax.shard_map(
             body, mesh=mesh_,
             in_specs=(P(ba if len(ba) != 1 else ba[0], ep, None, None, None),
                       espec, espec, dspec),

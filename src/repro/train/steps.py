@@ -8,7 +8,6 @@ import jax.numpy as jnp
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.optim.compression import compressed_psum
 from repro.optim.schedule import cosine_schedule
-from repro.compat import shard_map
 
 
 def make_train_step(loss_fn, peak_lr=3e-4, warmup=100, total=10000,
@@ -64,7 +63,7 @@ def make_dp_train_step(loss_fn, mesh, axis_name="data", peak_lr=3e-4,
 
     rep = P()
     dat = P(axis_name)
-    step = shard_map(
+    step = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(rep, rep, rep, dat), out_specs=(rep, rep, rep, rep),
         check_vma=False)

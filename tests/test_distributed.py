@@ -139,8 +139,7 @@ def test_compressed_vs_plain_allreduce_agree():
             plain = jax.lax.pmean(g, "d")
             return mean["g"], new_e["g"], plain
 
-        from repro.compat import shard_map
-        f = jax.jit(shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                     in_specs=(P("d"), P("d")), out_specs=(P("d"), P("d"),
                     P("d")), check_vma=False))
         rng = np.random.default_rng(0)

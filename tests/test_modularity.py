@@ -27,6 +27,18 @@ def test_modularity_single_community_is_zero():
     np.testing.assert_allclose(q, 0.0, atol=1e-6)
 
 
+def test_modularity_single_community_is_zero_past_float32_sums():
+    """One community whose weight sums pass 2^24: float32 accumulation
+    drops the unit edges next to the heavy one, and Q drifts off 0."""
+    leaves = np.arange(2, 1002)
+    edges = np.concatenate([[[0, 1]], np.stack([np.zeros_like(leaves),
+                                                leaves], axis=1)])
+    weights = np.concatenate([[2.0 ** 24], np.ones(len(leaves))])
+    g = build_csr(edges, 1002, weights=weights.astype(np.float32))
+    q = modularity(g, jnp.zeros(1002, jnp.int32))
+    assert q == 0.0
+
+
 def test_modularity_bounds():
     g, truth = ring_of_cliques(8, 6)
     for labels in (jnp.asarray(truth, jnp.int32),

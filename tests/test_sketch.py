@@ -22,7 +22,10 @@ from repro.graphs.csr import build_fold_plan
 
 
 # ---------------------------------------------------------------------------
-# python oracle: the paper's Alg. 2 semantics, one row at a time
+# python oracle: the paper's Alg. 2 semantics, one row at a time. Callers
+# pass float32 weights, so the oracle runs the folds' own float32
+# arithmetic: a float64 oracle can see a tie (wk == w) that float32
+# rounding does not, and keep a different candidate.
 # ---------------------------------------------------------------------------
 
 def mg_oracle(labels, weights, k):
@@ -75,7 +78,7 @@ def test_mg_fold_matches_oracle(row, k):
     labels = np.array([c for c, _ in row], dtype=np.int32)[None]
     weights = np.array([w for _, w in row], dtype=np.float32)[None]
     s_k, s_v = mg_fold_tile(jnp.asarray(labels), jnp.asarray(weights), k)
-    ok, ov = mg_oracle(labels[0], weights[0].astype(np.float64), k)
+    ok, ov = mg_oracle(labels[0], weights[0], k)
     got = {int(c): float(v) for c, v in zip(np.asarray(s_k)[0],
                                             np.asarray(s_v)[0]) if v > 0}
     want = {int(c): float(v) for c, v in zip(ok, ov) if v > 0}
@@ -90,9 +93,20 @@ def test_bm_fold_matches_oracle(row):
     labels = np.array([c for c, _ in row], dtype=np.int32)[None]
     weights = np.array([w for _, w in row], dtype=np.float32)[None]
     ck, wk = bm_fold_tile(jnp.asarray(labels), jnp.asarray(weights))
-    oc, ow = bm_oracle(labels[0], weights[0].astype(np.float64))
+    oc, ow = bm_oracle(labels[0], weights[0])
     assert int(ck[0]) == oc
     assert float(wk[0]) == pytest.approx(ow, rel=1e-5, abs=1e-4)
+
+
+def test_bm_fold_matches_oracle_at_a_float32_near_tie():
+    """8.2825066 - 1 - 1 is one float32 ulp above 6.2825066, so the
+    incumbent survives; in float64 the two tie and the new label wins."""
+    labels = np.array([[1, 1, 0, 0, 0]], dtype=np.int32)
+    weights = np.array([[1.0, 7.282506619268444, 1.0, 1.0,
+                         6.282506619268444]], dtype=np.float32)
+    ck, wk = bm_fold_tile(jnp.asarray(labels), jnp.asarray(weights))
+    assert bm_oracle(labels[0], weights[0]) == (1, np.float32(wk[0]))
+    assert int(ck[0]) == 1
 
 
 # ---------------------------------------------------------------------------

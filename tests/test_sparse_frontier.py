@@ -71,7 +71,7 @@ def _assert_parity(g, backend, method, rescan, cap):
 # property parity: every engine x sketch x rescan, random caps and graphs
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10**6),
        backend=st.sampled_from(BACKENDS),
        combo=st.sampled_from([("mg", False), ("mg", True), ("bm", False)]),
@@ -167,7 +167,7 @@ def test_sparse_folds_fewer_rows_than_dense():
 # compaction unit + Pick-Less wrinkle + mark_frontier edge cases
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(rows=st.integers(min_value=0, max_value=50),
        cap=st.integers(min_value=1, max_value=60),
        seed=st.integers(min_value=0, max_value=10**6))

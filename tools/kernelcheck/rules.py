@@ -416,9 +416,9 @@ def check_r2(index: RepoIndex) -> List[Finding]:
                         f"`{qual}` is not derived from a pad/window "
                         "producer — its full-chunk in-kernel slice is not "
                         "provably in bounds",
-                        "route the operand through `_pad_entries` (chunk "
-                        "slack) or `windowed_entries` (slice-safe window "
-                        "re-layout) before the dispatch"))
+                        "route the operand through `_pad_entry_rows` "
+                        "(lane-row slack) or `windowed_entries` (slice-safe "
+                        "window re-layout) before the dispatch"))
     return findings
 
 
