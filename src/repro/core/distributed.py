@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import obs
 from repro.core import sketch as sketch_lib
 from repro.core.fold_program import FoldRequest
 from repro.core.plan_bundle import (PlanSpec, ShardSlice, build_plan_bundle,
@@ -390,6 +391,7 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
     final_row_vertex = final_row_vertex[0]
     labels = labels[0]            # [V_pad]
 
+    @jax.named_scope("lpa.exchange")
     def exchange(vec, fill):
         """Local [V_pad] vector -> table the nbr_pos positions index."""
         if send_idx is None:
@@ -409,8 +411,9 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
 
     label_table = exchange(labels, -1)
     safe = jnp.maximum(nbr_pos, 0)
-    entry_labels = jnp.where(nbr_pos >= 0, label_table[safe], -1)
-    entry_weights = jnp.where(nbr_pos >= 0, edge_w, 0.0)
+    with jax.named_scope("lpa.gather"):
+        entry_labels = jnp.where(nbr_pos >= 0, label_table[safe], -1)
+        entry_weights = jnp.where(nbr_pos >= 0, edge_w, 0.0)
     # the fold loops below consume these in place round by round; the
     # rescan second pass re-reads round 0, so keep the originals
     entry_labels0, entry_weights0 = entry_labels, entry_weights
@@ -492,7 +495,8 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
                                 step_dmax=dm[0], n_entries_in=0,
                                 window_entries=g.shape[-1],
                                 aligned=is_aligned)
-            s_k, s_v = stream_fold_round(rnd, el, ew, k=k, chunk=chunk)
+            s_k, s_v = stream_fold_round(rnd, el, ew, k=k, chunk=chunk,
+                                         index=r)
             entry_labels, entry_weights = s_k.reshape(-1), s_v.reshape(-1)
         # window-slot row order: scatter below via the streaming slot map
         final_row_vertex = stream_frv[0]
@@ -505,7 +509,7 @@ def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
                              step_dmax=dm[0],
                              n_entries_in=fused_entries[r])
             s_k, s_v = fused_fold_round(rnd, entry_labels, entry_weights,
-                                        k=k, chunk=chunk)
+                                        k=k, chunk=chunk, index=r)
             entry_labels, entry_weights = s_k.reshape(-1), s_v.reshape(-1)
         s_k = s_k[:final_row_vertex.shape[0]]  # drop tile-padding rows
         s_v = s_v[:final_row_vertex.shape[0]]
@@ -582,13 +586,16 @@ def _move_epilogue(want, labels, pick_less, axis_names, frontier=None):
     the wanted labels (pad slots excluded) and psum the global ΔN. One
     copy for every method — the MG and BM paths must never drift.
     ``frontier`` ([V_pad] bool) additionally masks off-frontier moves."""
-    allowed = jnp.where(pick_less, want < labels, want != labels)
-    if frontier is not None:
-        allowed = allowed & frontier
-    is_real = labels >= 0
-    new_labels = jnp.where(allowed & is_real, want, labels)
-    changed = (new_labels != labels) & is_real
-    delta = jax.lax.psum(jnp.sum(changed.astype(jnp.int32)), axis_names)
+    with jax.named_scope("lpa.apply"):
+        allowed = jnp.where(pick_less, want < labels, want != labels)
+        if frontier is not None:
+            allowed = allowed & frontier
+        is_real = labels >= 0
+        new_labels = jnp.where(allowed & is_real, want, labels)
+        changed = (new_labels != labels) & is_real
+        moved = jnp.sum(changed.astype(jnp.int32))
+    with jax.named_scope("lpa.exchange"):
+        delta = jax.lax.psum(moved, axis_names)
     return new_labels, changed, delta
 
 
@@ -769,21 +776,29 @@ def dist_lpa(mesh, ws: DistLPAWorkspace, rho: int = 8, tau: float = 0.05,
     frontier = jax.device_put(jnp.ones(labels.shape, dtype=jnp.bool_),
                               shards)
     it = 0
-    for it in range(max_iters):
-        pl_on = (it % rho) == 0
-        if frontier_gate:
-            labels, delta, marked = step(
-                ws.nbr_pos, ws.weights, ws.round_gathers,
-                ws.final_row_vertex, labels, jnp.asarray(pl_on),
-                jnp.int32(it + 1), frontier=frontier, ws_arrays=ws)
-            frontier = (frontier | marked) if pl_on else marked
-        else:
-            labels, delta = step(ws.nbr_pos, ws.weights, ws.round_gathers,
-                                 ws.final_row_vertex, labels,
-                                 jnp.asarray(pl_on), jnp.int32(it + 1),
-                                 ws_arrays=ws)
-        if not pl_on and int(delta) / max(n, 1) < tau:
-            break
+    with obs.span("lpa.solve"):
+        for it in range(max_iters):
+            with obs.span("lpa.iteration", it=it):
+                pl_on = (it % rho) == 0
+                with obs.span("lpa.dispatch.move"):
+                    if frontier_gate:
+                        labels, delta, marked = step(
+                            ws.nbr_pos, ws.weights, ws.round_gathers,
+                            ws.final_row_vertex, labels, jnp.asarray(pl_on),
+                            jnp.int32(it + 1), frontier=frontier,
+                            ws_arrays=ws)
+                        frontier = (frontier | marked) if pl_on else marked
+                    else:
+                        labels, delta = step(
+                            ws.nbr_pos, ws.weights, ws.round_gathers,
+                            ws.final_row_vertex, labels, jnp.asarray(pl_on),
+                            jnp.int32(it + 1), ws_arrays=ws)
+                if pl_on:  # a Pick-Less pass never converges: no read
+                    continue
+                with obs.span("lpa.sync.delta"):
+                    moved = int(delta)
+                if moved / max(n, 1) < tau:
+                    break
     flat = np.asarray(labels).reshape(-1)
     slots = np.asarray(ws.init_labels).reshape(-1)
     out = np.empty(n, dtype=np.int32)

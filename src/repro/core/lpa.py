@@ -53,6 +53,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.exact import exact_choose
 from repro.core.fold_engine import get_engine
 from repro.core.fold_program import FoldRequest
@@ -187,18 +188,20 @@ def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
                         checked=False)
 
     aux = bundle.aux_for(engine)
-    if engine.uses_stream_plan and aux is not None and aux.aligned:
-        # window-aligned layout (DESIGN.md §13): ONE O(window slots) gather
-        # straight into window-slot order replaces labels[graph.indices]
-        # AND the engine's per-iteration windowed re-layout gather; the
-        # appended -1 slot absorbs the plan's n_nodes pad sentinel.
-        labels_ext = jnp.concatenate([labels,
-                                      jnp.full((1,), -1, labels.dtype)])
-        nbr_labels = labels_ext[aux.aligned_entry_vertex]
-        nbr_weights = aux.aligned_entry_weights
-    else:
-        nbr_labels = labels[graph.indices]
-        nbr_weights = graph.weights
+    with jax.named_scope("lpa.gather"):
+        if engine.uses_stream_plan and aux is not None and aux.aligned:
+            # window-aligned layout (DESIGN.md §13): ONE O(window slots)
+            # gather straight into window-slot order replaces
+            # labels[graph.indices] AND the engine's per-iteration windowed
+            # re-layout gather; the appended -1 slot absorbs the plan's
+            # n_nodes pad sentinel.
+            labels_ext = jnp.concatenate([labels,
+                                          jnp.full((1,), -1, labels.dtype)])
+            nbr_labels = labels_ext[aux.aligned_entry_vertex]
+            nbr_weights = aux.aligned_entry_weights
+        else:
+            nbr_labels = labels[graph.indices]
+            nbr_weights = graph.weights
     if config.method == "exact":
         want = exact_choose(ws.edge_src, labels[graph.indices],
                             graph.weights, graph.n_nodes, labels, seed)
@@ -223,12 +226,21 @@ def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
     else:
         raise ValueError(f"unknown method {config.method!r}")
 
-    allowed = jnp.where(pick_less, want < labels, want != labels)
-    if frontier is not None:
-        allowed = allowed & frontier
-    new_labels = jnp.where(allowed, want, labels)
-    changed = new_labels != labels
+    with jax.named_scope("lpa.apply"):
+        allowed = jnp.where(pick_less, want < labels, want != labels)
+        if frontier is not None:
+            allowed = allowed & frontier
+        new_labels = jnp.where(allowed, want, labels)
+        changed = new_labels != labels
     return new_labels, changed
+
+
+def mover(config: LPAConfig, cap_rows: int) -> Callable:
+    """``lpa_move`` bound to ``config`` and ``cap_rows`` as ``lpa()`` calls
+    it; jitted (``sparse`` static), its XLA module is ``jit_lpa_move``."""
+    move = functools.partial(lpa_move, config=config, cap_rows=cap_rows)
+    move.__name__ = "lpa_move"
+    return move
 
 
 def mark_frontier(ws: LPAWorkspace, changed: jnp.ndarray) -> jnp.ndarray:
@@ -258,11 +270,19 @@ class LPAResult:
     #: active rows (fused) or rows in active windows (streamed) — the
     #: skipped-row savings are visible as the gap to the dense entries.
     work_rows_history: list = dataclasses.field(default_factory=list)
+    #: device-to-host reads the host loop made (``obs.sync`` blocks): one
+    #: per iteration for the convergence delta, one more each for the
+    #: frontier fraction (track_frontier) and the sparse fit check
+    host_syncs: int = 0
 
 
 def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
         ws: Optional[LPAWorkspace] = None, jit: bool = True) -> LPAResult:
-    """Run LPA to convergence (host loop; jitted move step)."""
+    """Run LPA to convergence (host loop; jitted move step).
+
+    The loop's host spans and the mover's device scopes are named in
+    :mod:`repro.core.obs`; they record only under ``jax.profiler.trace``.
+    """
     config = config if config is not None else LPAConfig()
     if config.frontier_sparse:
         if not config.frontier_gate:
@@ -274,7 +294,7 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
                              "method (no fold plan to compact)")
     ws = ws if ws is not None else build_workspace(graph, config)
     cap_rows = ws.bundle.cap_rows()
-    move = functools.partial(lpa_move, config=config, cap_rows=cap_rows)
+    move = mover(config, cap_rows)
     frontier_fn = mark_frontier
     if jit:
         # ONE mover; the dense/sparse choice is a static argument decided
@@ -285,46 +305,59 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
         move = jax.jit(move, static_argnames=("sparse",))
         frontier_fn = jax.jit(mark_frontier)
     n = graph.n_nodes
-    labels = jnp.arange(n, dtype=jnp.int32)
-    frontier = jnp.ones((n,), dtype=jnp.bool_)  # every vertex starts queued
     need_marks = config.frontier_gate or config.track_frontier
     history = []
     frontier_history = []
     work_rows_history = []
     dense_rows = ws.bundle.dense_work_rows()
+    count = obs.SyncCount()
     converged = False
     it = 0
-    for it in range(config.max_iters):
-        pl = (it % config.rho) == 0
-        seed = jnp.int32(it + 1)
-        gate = frontier if config.frontier_gate else None
-        sparse = False
-        work = dense_rows
-        if config.frontier_sparse:
-            fits, sparse_work = ws.bundle.sparse_fit(np.asarray(frontier),
-                                                     cap_rows)
-            if fits:
-                sparse, work = True, sparse_work
-        labels, changed = move(ws, labels, jnp.asarray(pl), seed,
-                               frontier=gate, sparse=sparse)
-        work_rows_history.append(work)
-        if need_marks:
-            if config.track_frontier:
-                frontier_history.append(float(jnp.mean(frontier)))
-            marked = frontier_fn(ws, changed)
-            # A Pick-Less round blocks legal moves (want > label), so its
-            # unchanged vertices are deferred, not settled — keep them
-            # queued instead of letting the gate freeze them (§8.5).
-            frontier = (frontier | marked) if pl else marked
-        delta = int(jnp.sum(changed))
-        history.append(delta)
-        if not pl and delta / max(n, 1) < config.tau:
-            converged = True
-            break
+    with obs.span("lpa.solve"):
+        labels = jnp.arange(n, dtype=jnp.int32)
+        frontier = jnp.ones((n,), dtype=jnp.bool_)  # every vertex queued
+        for it in range(config.max_iters):
+            with obs.span("lpa.iteration", it=it):
+                pl = (it % config.rho) == 0
+                gate = frontier if config.frontier_gate else None
+                sparse = False
+                work = dense_rows
+                if config.frontier_sparse:
+                    with obs.sync("sparse_fit", count):
+                        active = np.asarray(frontier)
+                    fits, sparse_work = ws.bundle.sparse_fit(active,
+                                                             cap_rows)
+                    if fits:
+                        sparse, work = True, sparse_work
+                with obs.span("lpa.dispatch.move"):
+                    seed = jnp.int32(it + 1)
+                    labels, changed = move(ws, labels, jnp.asarray(pl),
+                                           seed, frontier=gate,
+                                           sparse=sparse)
+                work_rows_history.append(work)
+                if need_marks:
+                    if config.track_frontier:
+                        with obs.sync("frontier_mean", count):
+                            frontier_history.append(
+                                float(jnp.mean(frontier)))
+                    with obs.span("lpa.dispatch.frontier"):
+                        marked = frontier_fn(ws, changed)
+                        # A Pick-Less round blocks legal moves (want >
+                        # label), so its unchanged vertices are deferred,
+                        # not settled — keep them queued instead of
+                        # letting the gate freeze them (§8.5).
+                        frontier = (frontier | marked) if pl else marked
+                with obs.sync("delta", count):
+                    delta = int(jnp.sum(changed))
+                history.append(delta)
+                if not pl and delta / max(n, 1) < config.tau:
+                    converged = True
+                    break
     return LPAResult(labels=labels, iterations=it + 1,
                      changed_history=history, converged=converged,
                      frontier_history=frontier_history,
-                     work_rows_history=work_rows_history)
+                     work_rows_history=work_rows_history,
+                     host_syncs=count.host_syncs)
 
 
 def lpa_step_fn(config: LPAConfig) -> Callable:

@@ -312,6 +312,18 @@ def _rescan_kernel(dmax_ref, start_ref, count_ref, cand_ref, lab_ref,
                                cand_ref[...], dmax_ref[0, 0])
 
 
+#: Stable kernel names (``pallas_call(name=...)``): the TPU custom call
+#: and its profiler events carry them.
+KERNEL_NAMES = {_fold_kernel: "lpa_fold", _select_kernel: "lpa_select",
+                _bm_kernel: "lpa_bm_fold", _rescan_kernel: "lpa_rescan"}
+
+
+def fold_scope(index: int):
+    """Device scope (``op_name``) of fold round ``index``'s kernel launch;
+    a sibling of the ``lpa.relayout`` scope of the round's entries."""
+    return jax.named_scope(f"lpa.fold.r{index}")
+
+
 def slot_major(rows_k: jnp.ndarray, tile_r: int) -> jnp.ndarray:
     """[n_steps * tile_r, k] per-row slots -> the kernels' lane-dense
     [n_steps, k, tile_r] step blocks (slot on sublanes, row on lanes)."""
@@ -371,6 +383,7 @@ def _round_call(kernel, rnd, extras, entries, entries_spec, outs, *,
                         pltpu.VMEM((width, tile_r), jnp.int32),
                         pltpu.VMEM((width, tile_r), jnp.float32)],
         interpret=_interpret_default() if interpret is None else interpret,
+        name=KERNEL_NAMES[getattr(kernel, "func", kernel)],
     )(*args)
 
 
@@ -386,23 +399,26 @@ def _pad_entry_rows(x: jnp.ndarray, length: int, chunk: int, fill
 
 
 def _fused_round(kernel, rnd: FusedRound, extras, entry_labels,
-                 entry_weights, outs, *, chunk: int, interpret):
+                 entry_weights, outs, *, chunk: int, interpret, index: int):
     """One fused dispatch: the round's whole entry arrays stay resident in
     VMEM (a whole-array block, copied in once) for every grid step."""
-    el = _pad_entry_rows(entry_labels.astype(jnp.int32), rnd.n_entries_in,
-                         chunk, -1)
-    ew = _pad_entry_rows(entry_weights.astype(jnp.float32),
-                         rnd.n_entries_in, chunk, 0.0)
-    return _round_call(kernel, rnd, extras, (el, ew),
-                       pl.BlockSpec(memory_space=pltpu.VMEM), outs,
-                       chunk=chunk, interpret=interpret)
+    with jax.named_scope("lpa.relayout"):
+        el = _pad_entry_rows(entry_labels.astype(jnp.int32),
+                             rnd.n_entries_in, chunk, -1)
+        ew = _pad_entry_rows(entry_weights.astype(jnp.float32),
+                             rnd.n_entries_in, chunk, 0.0)
+    with fold_scope(index):
+        return _round_call(kernel, rnd, extras, (el, ew),
+                           pl.BlockSpec(memory_space=pltpu.VMEM), outs,
+                           chunk=chunk, interpret=interpret)
 
 
 def fused_fold_round(rnd: FusedRound, entry_labels: jnp.ndarray,
                      entry_weights: jnp.ndarray, *, k: int, chunk: int,
-                     interpret: bool | None = None
+                     interpret: bool | None = None, index: int = 0
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One dispatch covering every width bucket of the round.
+    """One dispatch covering every width bucket of the round (the plan's
+    round ``index``, which names its device scope).
 
     Returns padded ([n_steps*tile_r, k], [n_steps*tile_r, k]) sketches in
     fused row order (pad rows fold to empty sketches).
@@ -411,22 +427,25 @@ def fused_fold_round(rnd: FusedRound, entry_labels: jnp.ndarray,
     s_k, s_v = _fused_round(
         functools.partial(_fold_kernel, k=k), rnd, (), entry_labels,
         entry_weights, [(jnp.int32, block), (jnp.float32, block)],
-        chunk=chunk, interpret=interpret)
-    return row_major(s_k), row_major(s_v)
+        chunk=chunk, interpret=interpret, index=index)
+    with fold_scope(index):
+        return row_major(s_k), row_major(s_v)
 
 
 def fused_select_round(rnd: FusedRound, entry_labels: jnp.ndarray,
                        entry_weights: jnp.ndarray, incumbents: jnp.ndarray,
                        seed: jnp.ndarray, *, k: int, chunk: int,
-                       interpret: bool | None = None) -> jnp.ndarray:
-    """Final-round dispatch: fold + per-row winning label [n_steps*tile_r]."""
+                       interpret: bool | None = None,
+                       index: int = 0) -> jnp.ndarray:
+    """Final-round dispatch (round ``index``): fold + per-row winning
+    label [n_steps*tile_r]."""
     tile_r = rnd.tile_r
     (out,) = _fused_round(
         functools.partial(_select_kernel, k=k), rnd,
         [(incumbents, (1, tile_r)),
          (seed.astype(jnp.int32).reshape(1, 1), None)],
         entry_labels, entry_weights, [(jnp.int32, (1, tile_r))],
-        chunk=chunk, interpret=interpret)
+        chunk=chunk, interpret=interpret, index=index)
     return out.reshape(-1)
 
 
@@ -444,18 +463,18 @@ def run_mg_plan_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
     """
     labels, weights = entry_labels, entry_weights
     if selection is None:
-        for rnd in plan.rounds:
+        for i, rnd in enumerate(plan.rounds):
             s_k, s_v = fused_fold_round(rnd, labels, weights, k=plan.k,
                                         chunk=plan.chunk,
-                                        interpret=interpret)
+                                        interpret=interpret, index=i)
             labels, weights = s_k.reshape(-1), s_v.reshape(-1)
     else:
-        for rnd in plan.rounds:
+        for i, rnd in enumerate(plan.rounds):
             sub, idx, _ = _sparse_fused_round(rnd, selection.frontier,
                                               selection.cap_rows)
             c_k, c_v = fused_fold_round(sub, labels, weights, k=plan.k,
                                         chunk=plan.chunk,
-                                        interpret=interpret)
+                                        interpret=interpret, index=i)
             rows = rnd.row_vertex.shape[0]
             s_k = _scatter_sparse_rows(idx, c_k, rows, jnp.int32(-1))
             s_v = _scatter_sparse_rows(idx, c_v, rows, jnp.float32(0.0))
@@ -481,19 +500,19 @@ def select_best_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
         return labels
     el, ew = entry_labels, entry_weights
     if selection is None:
-        for rnd in plan.rounds[:-1]:
+        for i, rnd in enumerate(plan.rounds[:-1]):
             s_k, s_v = fused_fold_round(rnd, el, ew, k=plan.k,
                                         chunk=plan.chunk,
-                                        interpret=interpret)
+                                        interpret=interpret, index=i)
             el, ew = s_k.reshape(-1), s_v.reshape(-1)
         last, rv = plan.rounds[-1], plan.row_to_vertex
     else:
-        for rnd in plan.rounds[:-1]:
+        for i, rnd in enumerate(plan.rounds[:-1]):
             sub, idx, _ = _sparse_fused_round(rnd, selection.frontier,
                                               selection.cap_rows)
             c_k, c_v = fused_fold_round(sub, el, ew, k=plan.k,
                                         chunk=plan.chunk,
-                                        interpret=interpret)
+                                        interpret=interpret, index=i)
             rows = rnd.row_vertex.shape[0]
             el = _scatter_sparse_rows(idx, c_k, rows,
                                       jnp.int32(-1)).reshape(-1)
@@ -504,18 +523,21 @@ def select_best_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
                                           selection.cap_rows)
     n = plan.n_nodes
     real = rv >= 0
-    incumbents = jnp.where(real, labels[jnp.maximum(rv, 0)], -1)
+    with jax.named_scope("lpa.apply"):
+        incumbents = jnp.where(real, labels[jnp.maximum(rv, 0)], -1)
     choice = fused_select_round(last, el, ew, incumbents, seed,
                                 k=plan.k, chunk=plan.chunk,
-                                interpret=interpret)
+                                interpret=interpret,
+                                index=len(plan.rounds) - 1)
     # [N] scatter of per-row winners (pad/sentinel rows land in the dump
     # slot); vertices with no fold rows — degree 0, or off-frontier under a
     # selection — keep their label, identical to choose_from_candidates
     # with an empty candidate set.
-    buf = jnp.concatenate([labels, jnp.zeros((1,), labels.dtype)])
-    buf = buf.at[jnp.where(real, rv, n)].set(
-        jnp.where(real, choice, -1))
-    return buf[:n]
+    with jax.named_scope("lpa.apply"):
+        buf = jnp.concatenate([labels, jnp.zeros((1,), labels.dtype)])
+        buf = buf.at[jnp.where(real, rv, n)].set(
+            jnp.where(real, choice, -1))
+        return buf[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -539,7 +561,7 @@ def bm_fold_round_fused(rnd: FusedRound, entry_labels: jnp.ndarray,
     ck, wk = _fused_round(_bm_kernel, rnd, [(init_labels, row)],
                           entry_labels, entry_weights,
                           [(jnp.int32, row), (jnp.float32, row)],
-                          chunk=chunk, interpret=interpret)
+                          chunk=chunk, interpret=interpret, index=0)
     return ck.reshape(-1), wk.reshape(-1)
 
 
@@ -562,10 +584,12 @@ def run_bm_plan_generic(plan, entry_labels: jnp.ndarray,
     if n == 0:
         return (jnp.full((0,), -1, jnp.int32), jnp.zeros((0,), jnp.float32))
     rtv0 = plan.row_to_vertex0
-    init = bm_init_rows(rtv0, cur_labels)
+    with jax.named_scope("lpa.apply"):
+        init = bm_init_rows(rtv0, cur_labels)
     ck, wk = fold_round_fn(plan.rounds[0], entry_labels, entry_weights,
                            init, chunk=plan.chunk, interpret=interpret)
-    return bm_merge_rows(n, cur_labels, rtv0, ck, wk)
+    with jax.named_scope("lpa.apply"):
+        return bm_merge_rows(n, cur_labels, rtv0, ck, wk)
 
 
 def run_bm_plan_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
@@ -622,8 +646,9 @@ def rescan_round_fused(rnd: FusedRound, entry_labels: jnp.ndarray,
                           [(slot_major(cand_rows, rnd.tile_r), block)],
                           entry_labels, entry_weights,
                           [(jnp.float32, block)], chunk=chunk,
-                          interpret=interpret)
-    return row_major(out)
+                          interpret=interpret, index=0)
+    with fold_scope(0):
+        return row_major(out)
 
 
 def rescan_select_generic(plan, entry_labels: jnp.ndarray,
@@ -647,18 +672,20 @@ def rescan_select_generic(plan, entry_labels: jnp.ndarray,
     s_k, _ = run_plan_fn(plan, entry_labels, entry_weights,
                          interpret=interpret)
     rtv = plan.row_to_vertex
-    cand = jnp.full((n + 1, k), -1, jnp.int32).at[
-        jnp.where(rtv >= 0, rtv, n)].set(s_k)[:n]
     rtv0 = plan.row_to_vertex0
-    cand_ext = jnp.concatenate([cand, jnp.full((1, k), -1, jnp.int32)])
-    cand_rows = cand_ext[jnp.where(rtv0 >= 0, rtv0, n)]
+    with jax.named_scope("lpa.apply"):
+        cand = jnp.full((n + 1, k), -1, jnp.int32).at[
+            jnp.where(rtv >= 0, rtv, n)].set(s_k)[:n]
+        cand_ext = jnp.concatenate([cand, jnp.full((1, k), -1, jnp.int32)])
+        cand_rows = cand_ext[jnp.where(rtv0 >= 0, rtv0, n)]
     parts = rescan_round_fn(plan.rounds[0], entry_labels, entry_weights,
                             cand_rows, k=k, chunk=plan.chunk,
                             interpret=interpret)
-    acc = merge_rescan_partials(n, k, plan.max_rows0, rtv0,
-                                plan.row_rank0, parts)
-    return choose_from_candidates(jnp.where(acc > 0, cand, -1), acc,
-                                  labels, seed)
+    with jax.named_scope("lpa.apply"):
+        acc = merge_rescan_partials(n, k, plan.max_rows0, rtv0,
+                                    plan.row_rank0, parts)
+        return choose_from_candidates(jnp.where(acc > 0, cand, -1), acc,
+                                      labels, seed)
 
 
 def rescan_select_fused(plan: FusedFoldPlan, entry_labels: jnp.ndarray,
@@ -739,20 +766,21 @@ def _sparse_fused_round(rnd: FusedRound, frontier: jnp.ndarray,
     n = frontier.shape[0]
     rv = rnd.row_vertex
     real = rv >= 0
-    front_ext = jnp.concatenate([frontier.astype(jnp.bool_),
-                                 jnp.zeros((1,), jnp.bool_)])
-    active = real & front_ext[jnp.where(real, rv, n)]
     cap_steps = min(-(-cap_rows // tile_r), n_steps)
-    idx = compact_active_rows(active, cap_steps * tile_r)
-    zero_row = jnp.zeros((1,), jnp.int32)
-    rs = jnp.concatenate([rnd.row_start.reshape(-1), zero_row])[idx]
-    rc = jnp.concatenate([rnd.row_count.reshape(-1), zero_row])[idx]
-    rv_c = jnp.concatenate([rv, jnp.full((1,), -1, jnp.int32)])[idx]
-    rs2 = rs.reshape(cap_steps, tile_r)
-    rc2 = rc.reshape(cap_steps, tile_r)
-    sub = FusedRound(row_start=rs2, row_count=rc2,
-                     step_dmax=jnp.max(rc2, axis=1, keepdims=True),
-                     n_entries_in=rnd.n_entries_in)
+    with jax.named_scope("lpa.relayout"):
+        front_ext = jnp.concatenate([frontier.astype(jnp.bool_),
+                                     jnp.zeros((1,), jnp.bool_)])
+        active = real & front_ext[jnp.where(real, rv, n)]
+        idx = compact_active_rows(active, cap_steps * tile_r)
+        zero_row = jnp.zeros((1,), jnp.int32)
+        rs = jnp.concatenate([rnd.row_start.reshape(-1), zero_row])[idx]
+        rc = jnp.concatenate([rnd.row_count.reshape(-1), zero_row])[idx]
+        rv_c = jnp.concatenate([rv, jnp.full((1,), -1, jnp.int32)])[idx]
+        rs2 = rs.reshape(cap_steps, tile_r)
+        rc2 = rc.reshape(cap_steps, tile_r)
+        sub = FusedRound(row_start=rs2, row_count=rc2,
+                         step_dmax=jnp.max(rc2, axis=1, keepdims=True),
+                         n_entries_in=rnd.n_entries_in)
     return sub, idx, rv_c
 
 
@@ -764,5 +792,6 @@ def _scatter_sparse_rows(idx: jnp.ndarray, values: jnp.ndarray, rows: int,
     rows keep ``fill`` (the empty-sketch value, so later rounds read
     exact no-op entries for inactive vertices).
     """
-    buf = jnp.full((rows + 1,) + values.shape[1:], fill, values.dtype)
-    return buf.at[idx].set(values)[:rows]
+    with jax.named_scope("lpa.relayout"):
+        buf = jnp.full((rows + 1,) + values.shape[1:], fill, values.dtype)
+        return buf.at[idx].set(values)[:rows]

@@ -44,6 +44,7 @@ from __future__ import annotations
 import functools
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
@@ -52,8 +53,9 @@ from repro.graphs.csr import (StreamedFoldPlan, StreamedRound,
 from repro.kernels.mg_sketch.fused import (LANES, _bm_kernel, _fold_kernel,
                                            _lane_groups, _rescan_kernel,
                                            _round_call, _select_kernel,
-                                           rescan_select_generic, row_major,
-                                           run_bm_plan_generic, slot_major)
+                                           fold_scope, rescan_select_generic,
+                                           row_major, run_bm_plan_generic,
+                                           slot_major)
 
 
 def windowed_entries(gather: jnp.ndarray, entry_labels: jnp.ndarray,
@@ -88,35 +90,41 @@ def _aligned_window_entries(entry_labels: jnp.ndarray,
 
 
 def _stream_round(kernel, rnd: StreamedRound, extras, entry_labels,
-                  entry_weights, outs, *, chunk: int, interpret):
+                  entry_weights, outs, *, chunk: int, interpret,
+                  index: int):
     """One streamed dispatch: grid over windows, one W-entry window of
     [W/128, 128] lane rows resident per step (the kernels are the fused
-    ones; ``row_start`` holds window-relative offsets)."""
-    if rnd.aligned:  # entries pre-materialized window-aligned at build time
-        wl, ww = _aligned_window_entries(entry_labels, entry_weights)
-    else:
-        wl, ww = windowed_entries(rnd.entry_gather, entry_labels,
-                                  entry_weights)
+    ones; ``row_start`` holds window-relative offsets). The re-layout of
+    the entries into windows is device scope ``lpa.relayout``, the launch
+    ``lpa.fold.r<index>``."""
     rows = rnd.window_entries // LANES
-    wl = wl.reshape(rnd.n_windows, rows, LANES)
-    ww = ww.reshape(rnd.n_windows, rows, LANES)
     need = _lane_groups(chunk) + 1
-    if rows < need:  # a window narrower than one row gather's load
-        pad = ((0, 0), (0, need - rows), (0, 0))
-        wl = jnp.pad(wl, pad, constant_values=-1)
-        ww = jnp.pad(ww, pad, constant_values=0.0)
-        rows = need
+    with jax.named_scope("lpa.relayout"):
+        if rnd.aligned:  # entries pre-materialized window-aligned
+            wl, ww = _aligned_window_entries(entry_labels, entry_weights)
+        else:
+            wl, ww = windowed_entries(rnd.entry_gather, entry_labels,
+                                      entry_weights)
+        wl = wl.reshape(rnd.n_windows, rows, LANES)
+        ww = ww.reshape(rnd.n_windows, rows, LANES)
+        if rows < need:  # a window narrower than one row gather's load
+            pad = ((0, 0), (0, need - rows), (0, 0))
+            wl = jnp.pad(wl, pad, constant_values=-1)
+            ww = jnp.pad(ww, pad, constant_values=0.0)
+            rows = need
     window = pl.BlockSpec((None, rows, LANES), lambda i: (i, 0, 0))
-    return _round_call(kernel, rnd, extras, (wl, ww), window, outs,
-                       chunk=chunk, interpret=interpret)
+    with fold_scope(index):
+        return _round_call(kernel, rnd, extras, (wl, ww), window, outs,
+                           chunk=chunk, interpret=interpret)
 
 
 def stream_fold_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
                       entry_weights: jnp.ndarray, *, k: int, chunk: int,
-                      interpret: bool | None = None
+                      interpret: bool | None = None, index: int = 0
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One streamed dispatch: grid over windows, one W-entry window resident
-    per step.
+    per step. ``index`` is the plan's round number, which names the
+    launch's device scope.
 
     ``entry_labels``/``entry_weights`` are the round's flat source arrays
     (round 0: CSR-order neighbor labels/edge weights — or, on aligned
@@ -130,15 +138,18 @@ def stream_fold_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
     s_k, s_v = _stream_round(
         functools.partial(_fold_kernel, k=k), rnd, (), entry_labels,
         entry_weights, [(jnp.int32, block), (jnp.float32, block)],
-        chunk=chunk, interpret=interpret)
-    return row_major(s_k), row_major(s_v)
+        chunk=chunk, interpret=interpret, index=index)
+    with fold_scope(index):
+        return row_major(s_k), row_major(s_v)
 
 
 def stream_select_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
                         entry_weights: jnp.ndarray, incumbents: jnp.ndarray,
                         seed: jnp.ndarray, *, k: int, chunk: int,
-                        interpret: bool | None = None) -> jnp.ndarray:
-    """Final-round streamed dispatch: fold + per-row winning label.
+                        interpret: bool | None = None,
+                        index: int = 0) -> jnp.ndarray:
+    """Final-round (round ``index``) streamed dispatch: fold + per-row
+    winning label.
 
     ``incumbents`` [n_windows * tile_r] int32 carries each row slot's
     current vertex label (-1 on pad slots). Returns the chosen label per
@@ -149,7 +160,7 @@ def stream_select_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
         functools.partial(_select_kernel, k=k), rnd,
         [(incumbents, row), (seed.astype(jnp.int32).reshape(1, 1), None)],
         entry_labels, entry_weights, [(jnp.int32, row)], chunk=chunk,
-        interpret=interpret)
+        interpret=interpret, index=index)
     return out.reshape(-1)
 
 
@@ -173,18 +184,18 @@ def run_mg_plan_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
     """
     labels, weights = entry_labels, entry_weights
     if selection is None:
-        for rnd in plan.rounds:
+        for i, rnd in enumerate(plan.rounds):
             s_k, s_v = stream_fold_round(rnd, labels, weights, k=plan.k,
                                          chunk=plan.chunk,
-                                         interpret=interpret)
+                                         interpret=interpret, index=i)
             labels, weights = s_k.reshape(-1), s_v.reshape(-1)
     else:
-        for rnd in plan.rounds:
+        for i, rnd in enumerate(plan.rounds):
             sub, widx, _ = _sparse_stream_round(rnd, selection.frontier,
                                                 selection.cap_rows)
             c_k, c_v = stream_fold_round(sub, labels, weights, k=plan.k,
                                          chunk=plan.chunk,
-                                         interpret=interpret)
+                                         interpret=interpret, index=i)
             s_k = _scatter_sparse_windows(widx, c_k, rnd.n_windows,
                                           rnd.tile_r, jnp.int32(-1))
             s_v = _scatter_sparse_windows(widx, c_v, rnd.n_windows,
@@ -214,19 +225,19 @@ def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
         return labels
     el, ew = entry_labels, entry_weights
     if selection is None:
-        for rnd in plan.rounds[:-1]:
+        for i, rnd in enumerate(plan.rounds[:-1]):
             s_k, s_v = stream_fold_round(rnd, el, ew, k=plan.k,
                                          chunk=plan.chunk,
-                                         interpret=interpret)
+                                         interpret=interpret, index=i)
             el, ew = s_k.reshape(-1), s_v.reshape(-1)
         last, rv = plan.rounds[-1], plan.row_to_vertex
     else:
-        for rnd in plan.rounds[:-1]:
+        for i, rnd in enumerate(plan.rounds[:-1]):
             sub, widx, _ = _sparse_stream_round(rnd, selection.frontier,
                                                 selection.cap_rows)
             c_k, c_v = stream_fold_round(sub, el, ew, k=plan.k,
                                          chunk=plan.chunk,
-                                         interpret=interpret)
+                                         interpret=interpret, index=i)
             el = _scatter_sparse_windows(widx, c_k, rnd.n_windows,
                                          rnd.tile_r,
                                          jnp.int32(-1)).reshape(-1)
@@ -238,17 +249,20 @@ def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
                                            selection.cap_rows)
     n = plan.n_nodes
     real = rv >= 0
-    incumbents = jnp.where(real, labels[jnp.maximum(rv, 0)], -1)
+    with jax.named_scope("lpa.apply"):
+        incumbents = jnp.where(real, labels[jnp.maximum(rv, 0)], -1)
     choice = stream_select_round(last, el, ew, incumbents, seed,
                                  k=plan.k, chunk=plan.chunk,
-                                 interpret=interpret)
+                                 interpret=interpret,
+                                 index=len(plan.rounds) - 1)
     # [N] scatter of per-row winners (pad/sentinel rows land in the dump
     # slot); degree-0 (or off-frontier) vertices keep their label —
     # identical to choose_from_candidates with an empty candidate set.
-    buf = jnp.concatenate([labels, jnp.zeros((1,), labels.dtype)])
-    buf = buf.at[jnp.where(real, rv, n)].set(
-        jnp.where(real, choice, -1))
-    return buf[:n]
+    with jax.named_scope("lpa.apply"):
+        buf = jnp.concatenate([labels, jnp.zeros((1,), labels.dtype)])
+        buf = buf.at[jnp.where(real, rv, n)].set(
+            jnp.where(real, choice, -1))
+        return buf[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -272,7 +286,7 @@ def bm_fold_round_stream(rnd: StreamedRound, entry_labels: jnp.ndarray,
     ck, wk = _stream_round(_bm_kernel, rnd, [(init_labels, row)],
                            entry_labels, entry_weights,
                            [(jnp.int32, row), (jnp.float32, row)],
-                           chunk=chunk, interpret=interpret)
+                           chunk=chunk, interpret=interpret, index=0)
     return ck.reshape(-1), wk.reshape(-1)
 
 
@@ -323,8 +337,9 @@ def rescan_round_stream(rnd: StreamedRound, entry_labels: jnp.ndarray,
                            [(slot_major(cand_rows, rnd.tile_r), block)],
                            entry_labels, entry_weights,
                            [(jnp.float32, block)], chunk=chunk,
-                           interpret=interpret)
-    return row_major(out)
+                           interpret=interpret, index=0)
+    with fold_scope(0):
+        return row_major(out)
 
 
 def rescan_select_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
@@ -410,25 +425,27 @@ def _sparse_stream_round(rnd: StreamedRound, frontier: jnp.ndarray,
     n = frontier.shape[0]
     rv = rnd.row_vertex
     real = rv >= 0
-    front_ext = jnp.concatenate([frontier.astype(jnp.bool_),
-                                 jnp.zeros((1,), jnp.bool_)])
-    active = real & front_ext[jnp.where(real, rv, n)]
-    win_active = active.reshape(n_win, tile_r).any(axis=1)
     cap_w = min(cap_rows, n_win)
-    widx = compact_active_rows(win_active, cap_w)
-    eg_ext = jnp.concatenate([rnd.entry_gather.reshape(n_win, w),
-                              jnp.full((1, w), -1, jnp.int32)])
-    zero_tile = jnp.zeros((1, tile_r), jnp.int32)
-    rs_ext = jnp.concatenate([rnd.row_start, zero_tile])
-    rc_ext = jnp.concatenate([rnd.row_count, zero_tile])
-    dm_ext = jnp.concatenate([rnd.step_dmax, jnp.zeros((1, 1), jnp.int32)])
-    rv_ext = jnp.concatenate([rv.reshape(n_win, tile_r),
-                              jnp.full((1, tile_r), -1, jnp.int32)])
-    sub = StreamedRound(entry_gather=eg_ext[widx].reshape(-1),
-                        row_start=rs_ext[widx], row_count=rc_ext[widx],
-                        step_dmax=dm_ext[widx],
-                        n_entries_in=rnd.n_entries_in, window_entries=w)
-    return sub, widx, rv_ext[widx].reshape(-1)
+    with jax.named_scope("lpa.relayout"):
+        front_ext = jnp.concatenate([frontier.astype(jnp.bool_),
+                                     jnp.zeros((1,), jnp.bool_)])
+        active = real & front_ext[jnp.where(real, rv, n)]
+        win_active = active.reshape(n_win, tile_r).any(axis=1)
+        widx = compact_active_rows(win_active, cap_w)
+        eg_ext = jnp.concatenate([rnd.entry_gather.reshape(n_win, w),
+                                  jnp.full((1, w), -1, jnp.int32)])
+        zero_tile = jnp.zeros((1, tile_r), jnp.int32)
+        rs_ext = jnp.concatenate([rnd.row_start, zero_tile])
+        rc_ext = jnp.concatenate([rnd.row_count, zero_tile])
+        dm_ext = jnp.concatenate([rnd.step_dmax,
+                                  jnp.zeros((1, 1), jnp.int32)])
+        rv_ext = jnp.concatenate([rv.reshape(n_win, tile_r),
+                                  jnp.full((1, tile_r), -1, jnp.int32)])
+        sub = StreamedRound(entry_gather=eg_ext[widx].reshape(-1),
+                            row_start=rs_ext[widx], row_count=rc_ext[widx],
+                            step_dmax=dm_ext[widx],
+                            n_entries_in=rnd.n_entries_in, window_entries=w)
+        return sub, widx, rv_ext[widx].reshape(-1)
 
 
 def _scatter_sparse_windows(widx: jnp.ndarray, values: jnp.ndarray,
@@ -436,8 +453,10 @@ def _scatter_sparse_windows(widx: jnp.ndarray, values: jnp.ndarray,
     """Scatter compacted per-row-slot results back to dense slot positions
     (whole windows at a time; sentinel windows land in a sliced-off dump
     window; unwritten dense slots keep the empty-sketch ``fill``)."""
-    targets = (widx[:, None].astype(jnp.int32) * tile_r
-               + jnp.arange(tile_r, dtype=jnp.int32)[None, :]).reshape(-1)
-    buf = jnp.full(((n_win + 1) * tile_r,) + values.shape[1:], fill,
-                   values.dtype)
-    return buf.at[targets].set(values)[:n_win * tile_r]
+    with jax.named_scope("lpa.relayout"):
+        targets = (widx[:, None].astype(jnp.int32) * tile_r
+                   + jnp.arange(tile_r, dtype=jnp.int32)[None, :]
+                   ).reshape(-1)
+        buf = jnp.full(((n_win + 1) * tile_r,) + values.shape[1:], fill,
+                       values.dtype)
+        return buf.at[targets].set(values)[:n_win * tile_r]

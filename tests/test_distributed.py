@@ -356,3 +356,34 @@ def test_dist_frontier_gate_matches_single_host():
         bm_sh = lpa(g, LPAConfig(method="bm", rho=2, frontier_gate=True))
         assert (np.asarray(bm) == np.asarray(bm_sh.labels)).all()
     """, devices=4)
+
+
+@pytest.mark.slow  # spawns a multi-device subprocess
+@pytest.mark.parametrize("halo", [False, True], ids=["all-gather", "halo"])
+def test_dist_collectives_carry_the_exchange_scope(halo):
+    """Every collective of the four-shard step (the label all-gather, or
+    the hub all-gather and halo all-to-all, and the delta psum) lies in
+    device scope ``lpa.exchange``."""
+    out = _run(f"""
+        import re, jax, jax.numpy as jnp
+        from repro.core.distributed import build_dist_workspace, dist_lpa_step
+        from repro.graphs.generators import powerlaw_communities
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("shard",))
+        g, _ = powerlaw_communities(768, p_in=0.5, mix=0.02, seed=7)
+        ws = build_dist_workspace(g, 4, halo={halo})
+        step = dist_lpa_step(mesh, ws)
+        extra = (ws.send_idx, ws.hub_idx) if {halo} else ()
+        text = jax.jit(step).lower(
+            ws.nbr_pos, ws.weights, ws.round_gathers, ws.final_row_vertex,
+            ws.init_labels, jnp.asarray(True), jnp.int32(1),
+            *extra).compile().as_text()
+        lines = [ln for ln in text.splitlines()
+                 if re.search(r" (all-gather|all-to-all|all-reduce)"
+                              r"(-start)?\\(", ln)]
+        assert lines, "no collective in the step"
+        for ln in lines:
+            assert "lpa.exchange" in ln, ln
+        print("collectives", len(lines))
+    """, devices=4)
+    assert "collectives" in out

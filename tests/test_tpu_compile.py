@@ -148,3 +148,34 @@ def test_dist_stream_step_compiles_on_four_chips(topo, halo, monkeypatch):
              jax.ShapeDtypeStruct((), jnp.bool_, sharding=rep),
              jax.ShapeDtypeStruct((), jnp.int32, sharding=rep), ws)
 
+
+
+@pytest.mark.parametrize("aligned", [True, False],
+                         ids=["aligned", "regathered"])
+def test_mover_names_its_module_kernels_and_scopes(one_chip, aligned,
+                                                   monkeypatch):
+    """The whole streamed mover compiles as ``jit_lpa_move``, its Pallas
+    kernels as custom calls named ``lpa_fold``/``lpa_select``, and every
+    scope in its ops' ``op_name``."""
+    import re
+    from repro.core.lpa import LPAConfig, build_workspace, mover
+    from repro.graphs.generators import rmat
+    monkeypatch.setattr(fused, "_interpret_default", lambda: False)
+    g = rmat(12, 16, seed=1)
+    cfg = LPAConfig(fold_backend="pallas_stream", aligned_layout=aligned)
+    ws = build_workspace(g, cfg)
+    rounds = len(ws.stream_plan.rounds)
+    assert rounds >= 2
+    s = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
+                                       sharding=one_chip)
+    text = jax.jit(mover(cfg, 0), static_argnames=("sparse",)).lower(
+        jax.tree.map(s, ws), s(jnp.zeros(g.n_nodes, jnp.int32)),
+        s(jnp.zeros((), jnp.bool_)), s(jnp.zeros((), jnp.int32)),
+        frontier=None, sparse=False).compile().as_text()
+    assert text.startswith("HloModule jit_lpa_move")
+    kernels = re.findall(r"%(lpa_\w+)(?:\.\d+)? = .*tpu_custom_call", text)
+    assert sorted(set(kernels)) == ["lpa_fold", "lpa_select"]
+    assert len(kernels) == rounds
+    scopes = set(re.findall(r"(lpa\.[\w.]+)/", text))
+    assert scopes >= {"lpa.gather", "lpa.relayout", "lpa.apply"} | {
+        f"lpa.fold.r{i}" for i in range(rounds)}

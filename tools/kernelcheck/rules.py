@@ -633,9 +633,14 @@ class _DispatchCounter:
 
     @staticmethod
     def _classify_iter(stmt: ast.For, env) -> Tuple[str, Optional[str]]:
-        it = stmt.iter
-        loopvar = stmt.target.id if isinstance(stmt.target, ast.Name) \
-            else None
+        it, target = stmt.iter, stmt.target
+        if isinstance(it, ast.Call) and isinstance(it.func, ast.Name) \
+                and it.func.id == "enumerate" and len(it.args) == 1:
+            # enumerate(plan.rounds) walks the same rounds
+            it = it.args[0]
+            if isinstance(target, ast.Tuple) and len(target.elts) == 2:
+                target = target.elts[1]
+        loopvar = target.id if isinstance(target, ast.Name) else None
         if isinstance(it, ast.Attribute) and it.attr == "rounds":
             return "R", loopvar
         if isinstance(it, ast.Subscript) \
