@@ -75,8 +75,10 @@ class LPAConfig:
     # "jnp" | "pallas" | "pallas_fused" | "pallas_stream" | "auto"
     fold_backend: str = "jnp"
     mg_variant: str = "paper"  # "paper" | "exact_weighted" (DESIGN.md §8.4)
-    # pallas_stream: max entries per streamed window (bytes resident per
-    # step ~= 2 * window * 8); also the "auto" policy's stream granularity
+    # pallas_stream: cap on the entries of a streamed window (bytes resident
+    # per step <= ~2 * window * 8); each fold round packs its windows at the
+    # cap under it that its rows make cheapest (csr.build_streamed_rounds).
+    # Also the "auto" policy's stream granularity
     stream_window: int = 8192
     # pallas_stream: materialize the round-0 CSR entries window-aligned at
     # plan build time (DESIGN.md §13). The driver then gathers neighbor

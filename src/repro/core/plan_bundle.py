@@ -75,8 +75,8 @@ class PlanSpec:
     # pallas_stream: pre-materialize round 0's entries window-aligned at
     # plan build time (DESIGN.md §13); other backends ignore it
     aligned: bool = False
-    # pallas_stream: max entries per streamed window (also the "auto"
-    # policy's stream granularity)
+    # pallas_stream: cap on the entries per streamed window; each round
+    # picks its stride under it (also the "auto" policy's granularity)
     stream_window: int = 8192
     # "auto" resolution budget in bytes (None = the fold_engine default)
     vmem_budget_bytes: Optional[int] = None
@@ -348,7 +348,7 @@ def _build_shard_bundle(shard: ShardSlice, spec: PlanSpec
         rounds_np, rtv = build_streamed_rounds(
             counts0, starts0, shard.n_entries, k=k, chunk=chunk,
             tile_r=spec.tile_r, window_cap=spec.stream_window,
-            min_rounds=shard.n_rounds)
+            min_rounds=shard.n_rounds, aligned=spec.aligned)
         stream_rounds, stream_final_rtv = tuple(rounds_np), rtv
     return ShardPlanBundle(spec=spec, n_rounds=shard.n_rounds,
                            n_entries=shard.n_entries,

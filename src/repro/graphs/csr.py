@@ -18,6 +18,7 @@ structure, never on labels), so the whole multi-round fold jits cleanly.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
@@ -436,10 +437,22 @@ def build_fused_fold_plan(degrees: np.ndarray, k: int = 8, chunk: int = 128,
 # entry block HBM -> VMEM (double-buffered across grid steps) while the
 # previous window folds, so per-step residency is O(window_entries), not
 # O(|E|). Windows are closed greedily on whichever cap hits first (rows ==
-# tile_r or entries past the slice-safe limit), and the materialized window
-# stride is shrunk to the widest window actually produced (lane-aligned).
+# tile_r or entries past the slice-safe packing cap), and the materialized
+# window stride is shrunk to the widest window actually produced
+# (lane-aligned). ``window_entries`` is only the upper bound on that cap:
+# each round picks its own cap from its rows (_choose_stream_windows), so a
+# round of short rows is not laid out at the stride its hub rows need.
 
 _STREAM_ALIGN = 128  # lane-align the materialized window stride
+
+# A streamed window's cost in gathered window slots, the exchange rate of
+# the per-round stride choice (_choose_stream_windows). On a TPU v5e a
+# fold or select grid step costs about 13 us whatever its stride, and
+# every window slot a round gathers (the aligned round-0 label gather, the
+# merge rounds' label and weight re-layouts) about 8.6 ns, pad or real:
+# the mover's device scopes over the graph500 scale-19 and 2^20-vertex
+# k-mer cells' window and slot counts (PERF.md §5). 13 us / 8.6 ns ~ 1,500.
+WINDOW_COST_SLOTS = 1500
 
 
 @jax.tree_util.register_pytree_node_class
@@ -546,6 +559,48 @@ class StreamedFoldPlan:
         return self.aligned_entry_vertex is not None
 
 
+def _lane_align(n: int) -> int:
+    return -(-n // _STREAM_ALIGN) * _STREAM_ALIGN
+
+
+def _min_stream_stride(chunk: int) -> int:
+    """Narrowest stride the streamed kernels take without padding: one
+    row's ``chunk`` lane groups plus one (``streaming._stream_round``)."""
+    return _STREAM_ALIGN * (-(-chunk // _STREAM_ALIGN) + 1)
+
+
+def _window_firsts(cum: Sequence[int], chunk: int, tile_r: int,
+                   window_cap: int) -> List[int]:
+    """First row of each window of the greedy packing (``cum``: the rows'
+    entry-count prefix sum, a memoryview so the walk reads Python ints)."""
+    n_rows = len(cum) - 1
+    firsts = []
+    p = 0
+    while p < n_rows:
+        # the window ends before the first row q (p < q <= p + tile_r)
+        # whose rel_start = cum[q] - cum[p] passes cap - chunk
+        firsts.append(p)
+        p = bisect.bisect_right(cum, cum[p] + window_cap - chunk, p + 1,
+                                min(p + tile_r, n_rows))
+    return firsts
+
+
+def _window_need(cum: np.ndarray, firsts: np.ndarray, chunk: int) -> int:
+    """A packing's widest ``rel_start + chunk`` (its last row's, in the
+    window that reaches furthest)."""
+    lasts = np.append(firsts[1:], len(cum) - 1) - 1
+    return int((cum[lasts] - cum[firsts]).max()) + chunk
+
+
+def _window_stride(need: int, chunk: int, window_cap: int) -> int:
+    """Lane-aligned stride for a packing's ``need``, raised to the kernels'
+    narrowest stride when the cap allows it (so they never pad a window at
+    run time)."""
+    floor = _min_stream_stride(chunk)
+    w = _lane_align(need)
+    return max(w, floor) if window_cap >= floor else w
+
+
 def _pack_stream_windows(row_count: np.ndarray, chunk: int, tile_r: int,
                          window_cap: int) -> dict:
     """Greedily assign rows (kept in order) to slice-safe entry windows.
@@ -554,45 +609,116 @@ def _pack_stream_windows(row_count: np.ndarray, chunk: int, tile_r: int,
     counts of the rows before it in the same window. A window closes when it
     holds ``tile_r`` rows or when the next row's ``rel_start + chunk`` would
     exceed ``window_cap`` (so the kernel's full-chunk slice never crosses the
-    window edge — "no row straddles a window unsafely").
+    window edge — "no row straddles a window unsafely"). ``window_cap`` is
+    the packing cap :func:`_choose_stream_windows` picked for the round.
 
     Returns numpy arrays: ``win_of_row``/``rel_start``/``slot_of_row`` per
     row, plus ``n_windows`` and the lane-aligned ``window_entries`` stride
-    actually needed (<= aligned ``window_cap``; >= ``chunk``).
+    actually needed (<= aligned ``window_cap``; >= ``chunk``, and >= the
+    kernels' narrowest stride whenever the cap is).
     """
     if window_cap < chunk:
         raise ValueError(f"window_cap ({window_cap}) must be >= chunk "
                          f"({chunk}) for slice-safe rows")
     n_rows = len(row_count)
     if n_rows == 0:
-        w = -(-chunk // _STREAM_ALIGN) * _STREAM_ALIGN
         return {"win_of_row": np.zeros(0, np.int64),
                 "rel_start": np.zeros(0, np.int64),
                 "slot_of_row": np.zeros(0, np.int64),
-                "n_windows": 1, "window_entries": w}
+                "n_windows": 1, "window_entries": _lane_align(chunk)}
     cum = np.zeros(n_rows + 1, dtype=np.int64)
     np.cumsum(row_count, out=cum[1:])
-    firsts = []
-    p = 0
-    while p < n_rows:
-        # last includable row q has rel_start = cum[q]-cum[p] <= cap - chunk
-        q = int(np.searchsorted(cum, cum[p] + window_cap - chunk,
-                                side="right"))
-        q = max(min(q, p + tile_r, n_rows), p + 1)
-        firsts.append(p)
-        p = q
-    firsts_arr = np.asarray(firsts, dtype=np.int64)
-    n_windows = len(firsts)
+    firsts_arr = np.asarray(_window_firsts(memoryview(cum), chunk, tile_r,
+                                           window_cap), dtype=np.int64)
+    n_windows = len(firsts_arr)
     rows_per_win = np.diff(np.concatenate([firsts_arr, [n_rows]]))
     win_of_row = np.repeat(np.arange(n_windows, dtype=np.int64), rows_per_win)
     rel_start = cum[:-1] - cum[firsts_arr[win_of_row]]
     slot_of_row = win_of_row * tile_r + (np.arange(n_rows) -
                                          firsts_arr[win_of_row])
-    need = int((rel_start + chunk).max())
-    w = -(-max(need, chunk) // _STREAM_ALIGN) * _STREAM_ALIGN
     return {"win_of_row": win_of_row, "rel_start": rel_start,
             "slot_of_row": slot_of_row, "n_windows": n_windows,
-            "window_entries": w}
+            "window_entries": _window_stride(
+                _window_need(cum, firsts_arr, chunk), chunk, window_cap)}
+
+
+def stream_round_cost(n_windows: int, window_entries: int,
+                      gathers: int) -> int:
+    """A streamed round's device cost in gathered-slot units: ``gathers``
+    arrays gathered over every window slot, plus each window's kernel
+    step at :data:`WINDOW_COST_SLOTS`."""
+    return (gathers * n_windows * window_entries
+            + WINDOW_COST_SLOTS * n_windows)
+
+
+def _stream_cap_ladder(floor: int, top: int) -> List[int]:
+    """Lane-aligned packing caps from ``floor`` to ``top``, each at most a
+    quarter wider than the one before (every lane count to 8, then steps
+    of 2, 4, 8, ... lanes)."""
+    caps, lanes = [], floor // _STREAM_ALIGN
+    while lanes * _STREAM_ALIGN <= top:
+        caps.append(lanes * _STREAM_ALIGN)
+        lanes += 1 << max(lanes.bit_length() - 3, 0)
+    return caps
+
+
+def _choose_stream_windows(row_count: np.ndarray, chunk: int, tile_r: int,
+                           window_cap: int, gathers: int) -> dict:
+    """Pack one round at the cap (<= ``window_cap``) of least
+    :func:`stream_round_cost`.
+
+    ``gathers`` is the number of arrays the round gathers per window slot:
+    1 on a window-aligned round 0 (its weights are laid out at build
+    time), 2 on a re-gathered round (labels and weights). Only where the
+    windows are cut changes: every row folds the same entries in the same
+    order at any cap, so the fold is bit-identical at every choice.
+
+    The candidates are ``window_cap`` and a ladder of lane-aligned caps
+    below it (:func:`_stream_cap_ladder`), down to the kernels' narrowest
+    stride; a ``window_cap`` below that stride is kept as it is. Packing
+    walks the windows in Python, so candidates are tried cheapest lower
+    bound first and the search stops at the first bound that reaches the
+    best cost found. The bound: at least ``max(rows / tile_r, entries /
+    cap)`` windows and every real entry gathered; and below ``need`` (the
+    widest window of tile_r rows), some window closes on the cap, so the
+    stride exceeds ``cap - chunk``. At or above ``need`` every cap packs
+    the same tile_r-row windows, which cost nothing to count.
+    """
+    floor = _min_stream_stride(chunk)
+    n_rows = len(row_count)
+    if window_cap < floor or n_rows == 0:
+        return _pack_stream_windows(row_count, chunk, tile_r, window_cap)
+    cum = np.zeros(n_rows + 1, dtype=np.int64)
+    np.cumsum(row_count, out=cum[1:])
+    real = int(cum[-1])
+    by_rows = np.arange(0, n_rows, tile_r, dtype=np.int64)
+    need = _window_need(cum, by_rows, chunk)
+    if need <= window_cap:  # the tile_r-row packing, counted directly
+        best_cap = window_cap
+        best = stream_round_cost(
+            len(by_rows), _window_stride(need, chunk, window_cap), gathers)
+        caps = [c for c in _stream_cap_ladder(floor, window_cap) if c < need]
+    else:
+        best_cap = best = None
+        caps = sorted(set(_stream_cap_ladder(floor, window_cap))
+                      | {window_cap})
+
+    def bound(cap):
+        n_lb = max(len(by_rows), -(-real // cap))
+        return (gathers * max(real, n_lb * _lane_align(cap - chunk + 1))
+                + WINDOW_COST_SLOTS * n_lb)
+
+    cum_view = memoryview(cum)
+    for cap in sorted(caps, key=bound):
+        if best is not None and bound(cap) >= best:
+            break
+        firsts = np.asarray(_window_firsts(cum_view, chunk, tile_r, cap),
+                            dtype=np.int64)
+        cost = stream_round_cost(len(firsts), _window_stride(
+            _window_need(cum, firsts, chunk), chunk, cap), gathers)
+        if best is None or cost < best or (cost == best and cap > best_cap):
+            best, best_cap = cost, cap
+    return _pack_stream_windows(row_count, chunk, tile_r, best_cap)
 
 
 def _materialize_stream_round(row_vstart: np.ndarray, row_count: np.ndarray,
@@ -635,7 +761,8 @@ def _materialize_stream_round(row_vstart: np.ndarray, row_count: np.ndarray,
 
 def build_streamed_rounds(counts: np.ndarray, starts: np.ndarray,
                           n_entries: int, *, k: int, chunk: int, tile_r: int,
-                          window_cap: int, min_rounds: int = 1
+                          window_cap: int, min_rounds: int = 1,
+                          aligned: bool = False
                           ) -> Tuple[List[dict], np.ndarray]:
     """Host-side core of the streamed plan (shared with the distributed
     workspace builder).
@@ -647,6 +774,12 @@ def build_streamed_rounds(counts: np.ndarray, starts: np.ndarray,
     sort), so per-vertex results are bit-identical to the reference; only
     the window re-layout differs. ``min_rounds`` forces extra merge rounds
     (the distributed builder pads all shards to a common round count).
+
+    ``window_cap`` bounds every window (and so the kernels' VMEM
+    residency); each round packs at the cap :func:`_choose_stream_windows`
+    finds cheapest for its own rows, so the rounds' strides differ.
+    ``aligned`` says round 0 will be stored window-aligned, which makes its
+    windows cheaper to gather (one array per slot, not two).
 
     Returns (one numpy dict per round with the ``StreamedRound`` fields,
     final ``row_to_vertex`` [last n_windows * tile_r], -1 on pad slots).
@@ -667,7 +800,8 @@ def build_streamed_rounds(counts: np.ndarray, starts: np.ndarray,
             np.cumsum(nc_ord) - nc_ord, nc_ord)
         row_vstart = starts[row_vertex] + row_rank * chunk
         row_count = np.minimum(counts[row_vertex] - row_rank * chunk, chunk)
-        pack = _pack_stream_windows(row_count, chunk, tile_r, window_cap)
+        pack = _choose_stream_windows(row_count, chunk, tile_r, window_cap,
+                                      1 if aligned and r == 0 else 2)
         rnd = _materialize_stream_round(row_vstart, row_count, pack,
                                         pos_table, tile_r)
         rnd.update(n_entries_in=int(n_entries),
@@ -710,11 +844,12 @@ def build_streamed_fold_plan(degrees: np.ndarray, k: int = 8,
     """Construct the HBM-streaming windowed plan from the degree sequence.
 
     ``window_entries`` caps the entry slots per window (units: entries; the
-    per-step VMEM residency is ~``2 * window_entries * 8`` bytes for the
-    double-buffered label+weight window). Folds the identical entry
-    sequences as ``build_fold_plan``/``build_fused_fold_plan``, so
-    per-vertex results are bit-identical; only the windowed layout and the
-    per-window grid differ.
+    per-step VMEM residency is at most ~``2 * window_entries * 8`` bytes
+    for the double-buffered label+weight window); each round's stride is
+    chosen under it from the round's rows (``build_streamed_rounds``).
+    Folds the identical entry sequences as ``build_fold_plan``/
+    ``build_fused_fold_plan``, so per-vertex results are bit-identical;
+    only the windowed layout and the per-window grid differ.
 
     ``aligned=True`` (requires the CSR ``indices``/``weights``) stores the
     round-0 entry arrays window-aligned at build time: the plan carries
@@ -738,7 +873,7 @@ def build_streamed_fold_plan(degrees: np.ndarray, k: int = 8,
     np.cumsum(degrees, out=offsets[1:])
     rounds_np, rtv = build_streamed_rounds(
         degrees, offsets[:-1], int(degrees.sum()), k=k, chunk=chunk,
-        tile_r=tile_r, window_cap=window_entries)
+        tile_r=tile_r, window_cap=window_entries, aligned=aligned)
     aev = aew = None
     rounds = []
     for ri, r in enumerate(rounds_np):

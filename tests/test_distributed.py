@@ -268,6 +268,53 @@ def test_dist_aligned_layout_matches_unaligned():
 
 
 @pytest.mark.slow  # spawns a multi-device subprocess
+def test_dist_stream_shards_with_different_strides_match_single_host():
+    """A shard of hub rows and a shard of short rows pick different
+    window strides per round; the stacked workspace pads them to one
+    stride per round, and the streamed dist_lpa (plain and aligned) still
+    gives single-host lpa()'s labels."""
+    _run("""
+        import numpy as np
+        from repro.core.distributed import (_edge_balanced_ranges,
+                                            build_dist_workspace, dist_lpa)
+        from repro.core.lpa import lpa, LPAConfig
+        from repro.core.plan_bundle import (PlanSpec, ShardSlice,
+                                            build_plan_bundle,
+                                            uniform_round_count)
+        from repro.graphs.csr import build_csr
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("shard",))
+        n, n_hubs, deg = 600, 4, 600
+        chain = np.stack([np.arange(n_hubs, n - 1),
+                          np.arange(n_hubs + 1, n)], 1)
+        hubs = np.stack([np.repeat(np.arange(n_hubs), deg),
+                         n_hubs + np.arange(n_hubs * deg) * 7 % (n - n_hubs)],
+                        1)
+        g = build_csr(np.concatenate([chain, hubs]), n)
+        kw = dict(k=4, chunk=16, tile_r=32)
+        degrees = np.asarray(g.degrees)
+        bounds = _edge_balanced_ranges(degrees, 2)
+        counts = [degrees[bounds[p]:bounds[p + 1]] for p in range(2)]
+        n_rounds = uniform_round_count(counts, k=4, chunk=16)
+        spec = PlanSpec(backend="pallas_stream", stream_window=512, **kw)
+        strides = [build_plan_bundle(ShardSlice(
+            counts=c, n_entries=int(max(x.sum() for x in counts)),
+            n_rounds=n_rounds), spec).stream_rounds[0]["window_entries"]
+            for c in counts]
+        assert strides[0] != strides[1], strides
+        ref = lpa(g, LPAConfig(method="mg", rho=2, k=4, chunk=16))
+        for aligned in (False, True):
+            ws = build_dist_workspace(g, 2, stream=True, aligned=aligned,
+                                      window_entries=512, **kw)
+            assert ws.stream_gathers[0].shape[-1] == max(strides)
+            got, it = dist_lpa(mesh, ws, rho=2, engine="pallas_stream")
+            assert it == ref.iterations, aligned
+            assert (np.asarray(got) == np.asarray(ref.labels)).all(), aligned
+        print("different strides dist parity ok")
+    """, devices=2)
+
+
+@pytest.mark.slow  # spawns a multi-device subprocess
 def test_dist_rescan_matches_single_host():
     """dist_lpa(rescan=True) routes the MG double-scan (§4.4) through the
     same FoldRequest the single-host mover keys on (DESIGN.md §14); the

@@ -295,3 +295,41 @@ def test_shard_bundle_auto_resolves_like_the_graph_path():
         _spec("auto", vmem_budget_bytes=64))
     assert b.spec.backend == resolve_auto(m_pad, 64) == "pallas_stream"
     assert b.stream_rounds is not None and b.stream_final_rtv is not None
+
+
+def test_shards_with_different_strides_stack_to_one_stride_per_round():
+    """Each shard picks its rounds' strides from its own rows: a shard of
+    hub rows keeps wide windows, a shard of short rows takes the narrowest.
+    Stacking pads every round to one stride (the widest) and one window
+    count without moving a real slot."""
+    spec = PlanSpec(backend="pallas_stream", k=4, chunk=16, tile_r=32,
+                    stream_window=512)
+    counts = [np.asarray([600] * 4 + [6] * 100),
+              np.asarray([2] * 300 + [6] * 200)]
+    m_pad = int(max(c.sum() for c in counts))
+    n_rounds = uniform_round_count(counts, k=4, chunk=16)
+    bundles = [build_plan_bundle(
+        ShardSlice(counts=c, n_entries=m_pad, n_rounds=n_rounds), spec)
+        for c in counts]
+    strides = [[r["window_entries"] for r in b.stream_rounds]
+               for b in bundles]
+    assert strides[0][0] != strides[1][0], strides
+    plans = stack_shard_bundles(bundles)
+    assert len(plans.stream_gathers) == n_rounds
+    for r in range(n_rounds):
+        n_win = max(b.stream_rounds[r]["row_start"].shape[0]
+                    for b in bundles)
+        w_max = max(s[r] for s in strides)
+        stacked = np.asarray(plans.stream_gathers[r])
+        assert stacked.shape == (len(bundles), n_win, w_max)
+        for p, b in enumerate(bundles):
+            rr = b.stream_rounds[r]
+            nw, w_s = rr["row_start"].shape[0], rr["window_entries"]
+            np.testing.assert_array_equal(
+                stacked[p, :nw, :w_s], rr["entry_gather"].reshape(nw, w_s))
+            assert (stacked[p, :nw, w_s:] == -1).all()
+            assert (stacked[p, nw:] == -1).all()
+            np.testing.assert_array_equal(
+                np.asarray(plans.stream_starts[r])[p, :nw], rr["row_start"])
+            np.testing.assert_array_equal(
+                np.asarray(plans.stream_counts[r])[p, :nw], rr["row_count"])

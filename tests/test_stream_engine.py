@@ -21,9 +21,11 @@ from repro.core.fold_engine import (DEFAULT_VMEM_BUDGET_BYTES, get_engine,
                                     resolve_auto)
 from repro.core.lpa import LPAConfig, build_workspace, lpa
 from repro.core.sketch import run_mg_plan, scatter_rows, select_best
-from repro.graphs.csr import (build_csr, build_fold_plan,
+from repro.graphs.csr import (_min_stream_stride, _pack_stream_windows,
+                              build_csr, build_fold_plan,
                               build_streamed_fold_plan, fused_hbm_entries,
-                              build_fused_fold_plan, streamed_dispatches,
+                              build_fused_fold_plan, stream_round_cost,
+                              streamed_dispatches,
                               streamed_gather_slots, streamed_hbm_entries,
                               streamed_peak_window_bytes,
                               streamed_window_slots)
@@ -167,6 +169,87 @@ def test_exact_window_fill_keeps_single_window():
     assert splan.rounds[0].n_windows == 1
     rc = np.asarray(splan.rounds[0].row_count)
     np.testing.assert_array_equal(rc[rc > 0], [8, 8])
+
+
+def _round_rows(rnd):
+    """A round's rows in packing order: the real row slots, window by
+    window (every row holds at least one entry)."""
+    rc = np.asarray(rnd.row_count).reshape(-1)
+    return rc[rc > 0]
+
+
+# 1,000 rows of 8 entries and two hubs of 64 full rows each: at a fixed cap
+# the hub windows set an 8,192-slot stride for every window of the round
+HUB_AND_SHORT_ROWS = np.asarray([8] * 1000 + [8192] * 2)
+
+
+@pytest.mark.parametrize("aligned", [False, True],
+                         ids=["regathered", "aligned"])
+@pytest.mark.parametrize("name", ["powerlaw", "star_hub", "road_deg2"])
+@pytest.mark.parametrize("chunk,tile_r,cap", [(128, 128, 8192),
+                                              (16, 8, 1024),
+                                              (16, 8, 64)])
+def test_round_stride_chosen_from_its_rows(name, aligned, chunk, tile_r, cap):
+    """Each round's stride lies between the kernels' narrowest stride and
+    the cap (or is the cap's own stride where the cap is narrower), and its
+    cost is no more than packing the same rows at the cap."""
+    g = FIXTURES[name]()
+    splan = build_streamed_fold_plan(
+        np.asarray(g.degrees), k=4, chunk=chunk, tile_r=tile_r,
+        window_entries=cap, indices=np.asarray(g.indices),
+        weights=np.asarray(g.weights), aligned=aligned)
+    assert splan.aligned == aligned
+    floor = _min_stream_stride(chunk)
+    for rnd in splan.rounds:
+        rows = _round_rows(rnd)
+        at_cap = _pack_stream_windows(rows, chunk, tile_r, cap)
+        if cap >= floor:
+            assert floor <= rnd.window_entries <= cap
+        else:
+            assert rnd.window_entries == at_cap["window_entries"]
+            assert rnd.n_windows == at_cap["n_windows"]
+        gathers = 1 if rnd.aligned else 2
+        assert (stream_round_cost(rnd.n_windows, rnd.window_entries, gathers)
+                <= stream_round_cost(at_cap["n_windows"],
+                                     at_cap["window_entries"], gathers))
+        # slice safety holds at the chosen stride
+        rs, rc = np.asarray(rnd.row_start), np.asarray(rnd.row_count)
+        assert ((rs + chunk) * (rc > 0) <= rnd.window_entries).all()
+
+
+def test_hub_rows_no_longer_set_every_windows_stride():
+    """On hub rows plus short rows the chosen strides gather at least 2x
+    fewer window slots than packing every round at the 8,192-entry cap,
+    and the fold and the selection through them stay bit-identical to the
+    reference."""
+    degrees = HUB_AND_SHORT_ROWS
+    splan = build_streamed_fold_plan(degrees, k=8, chunk=128, tile_r=128,
+                                     window_entries=8192)
+    assert splan.n_rounds > 1
+    at_cap = sum(p["n_windows"] * p["window_entries"] for p in (
+        _pack_stream_windows(_round_rows(r), 128, 128, 8192)
+        for r in splan.rounds))
+    assert 2 * streamed_window_slots(splan) <= at_cap
+    assert splan.rounds[0].window_entries < 8192
+    rng = np.random.default_rng(3)
+    n, m = len(degrees), int(degrees.sum())
+    el = jnp.asarray(rng.integers(0, n, m).astype(np.int32))
+    ew = jnp.asarray((rng.random(m) * 3 + 0.25).astype(np.float32))
+    plan = build_fold_plan(degrees, k=8, chunk=128)
+    s_k, s_v = run_mg_plan(plan, el, ew)
+    cand_c, cand_w = scatter_rows(plan, s_k, s_v)
+    fs_k, fs_v = run_mg_plan_stream(splan, el, ew)
+    rtv = np.asarray(splan.row_to_vertex)
+    real = rtv >= 0
+    np.testing.assert_array_equal(np.asarray(fs_k)[real],
+                                  np.asarray(cand_c)[rtv[real]])
+    np.testing.assert_array_equal(np.asarray(fs_v)[real],
+                                  np.asarray(cand_w)[rtv[real]])
+    labels = jnp.asarray(rng.integers(0, n, n).astype(np.int32))
+    for seed in (1, 7):
+        ref = select_best(plan, s_k, s_v, labels, jnp.int32(seed))
+        got = select_best_stream(splan, el, ew, labels, jnp.int32(seed))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 def test_auto_policy_resolution():

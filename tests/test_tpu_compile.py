@@ -43,18 +43,19 @@ def _compile(fn, *args):
     return compiled
 
 
-def _streamed(one_chip, aligned):
-    """Round-0-sized streamed metadata + entry shapes on one chip."""
+def _streamed(one_chip, aligned, w=W):
+    """Round-0-sized streamed metadata + entry shapes on one chip, at
+    window stride ``w``."""
     s = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    meta = (s((N_STEPS * W,)), s((N_STEPS, TILE_R)), s((N_STEPS, TILE_R)),
+    meta = (s((N_STEPS * w,)), s((N_STEPS, TILE_R)), s((N_STEPS, TILE_R)),
             s((N_STEPS, 1)))
-    entries = (s((N_STEPS * W,)), s((N_STEPS * W,), jnp.float32))
+    entries = (s((N_STEPS * w,)), s((N_STEPS * w,), jnp.float32))
 
     def rnd(gather, starts, counts, dmax):
         return StreamedRound(entry_gather=gather, row_start=starts,
                              row_count=counts, step_dmax=dmax,
-                             n_entries_in=N_STEPS * W, window_entries=W,
+                             n_entries_in=N_STEPS * w, window_entries=w,
                              aligned=aligned)
     return s, meta, entries, rnd
 
@@ -75,6 +76,28 @@ def test_stream_select_compiles(one_chip):
                  rnd(g, rs, rc, dm), el, ew, inc, seed, k=K, chunk=CHUNK,
                  interpret=False),
              *meta, *entries, s((N_STEPS * TILE_R,)), s(()))
+
+
+@pytest.mark.parametrize("w", [384, 256])
+@pytest.mark.parametrize("kernel", ["fold-aligned", "fold-regathered",
+                                    "select"])
+def test_stream_kernels_compile_at_narrow_strides(one_chip, kernel, w):
+    """The plan picks each round's stride from its rows
+    (``csr.build_streamed_rounds``), so short rows get strides far under
+    the 8,192 cap, down to the kernels' narrowest: one 128-entry row's
+    lane rows plus one (3 and 2 lane rows a window here)."""
+    s, meta, entries, rnd = _streamed(one_chip, kernel != "fold-regathered",
+                                      w)
+    if kernel == "select":
+        _compile(lambda g, rs, rc, dm, el, ew, inc, seed:
+                 streaming.stream_select_round(
+                     rnd(g, rs, rc, dm), el, ew, inc, seed, k=K,
+                     chunk=CHUNK, interpret=False),
+                 *meta, *entries, s((N_STEPS * TILE_R,)), s(()))
+    else:
+        _compile(lambda g, rs, rc, dm, el, ew: streaming.stream_fold_round(
+            rnd(g, rs, rc, dm), el, ew, k=K, chunk=CHUNK, interpret=False),
+            *meta, *entries)
 
 
 def test_stream_bm_compiles(one_chip):
