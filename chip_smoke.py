@@ -1,7 +1,7 @@
 """Chip smoke test: sketch LPA end to end on a TPU, through the public API.
 
     python chip_smoke.py                 # one chip
-    python chip_smoke.py --four-chips    # one four-chip host: dist_lpa only
+    python chip_smoke.py --four-chips    # one four-chip host: shards=4 only
 
 One chip (the default). Generates LDBC Graphalytics ``graph500-22``
 (Graph500 Kronecker scale 22, edge factor 16, A/B/C = 0.57/0.19/0.19) from
@@ -15,10 +15,13 @@ layout to convergence, and checks that
     chip, and its modularity is at least 0.9 x the jnp run's.
 
 ``--four-chips`` runs only the four-chip path and what it is compared
-with: ``dist_lpa`` over a (4,) mesh with ``engine="pallas_stream"`` on the
-window-aligned workspace, with the all-gather and the halo label exchange,
-each bit-identical to single-chip ``lpa()`` on the same graph (Graph500
-scale 20 unless ``--scale`` says otherwise).
+with: ``lpa()`` with ``LPAConfig(shards=4)`` (the distributed loop over a
+(4,) mesh with the halo label exchange, ``fold_backend="auto"`` resolved
+to ``pallas_stream`` per shard, window-aligned), bit-identical to
+single-chip ``lpa()`` on the same graph in labels, iterations and moved
+counts; then ``dist_lpa`` with the all-gather exchange, bit-identical in
+labels and iterations (Graph500 scale 20 unless ``--scale`` says
+otherwise).
 
 The lines before the last are readings; times are one smoke run each, not
 benchmarks. The last line is one JSON object,
@@ -126,48 +129,70 @@ def one_chip(scale: int, seed: int) -> None:
 def four_chips(scale: int, seed: int) -> None:
     from repro.core.distributed import (build_dist_workspace, dist_lpa,
                                         dist_lpa_step)
-    from repro.launch.mesh import make_mesh
     if len(jax.devices()) != 4:
         raise AssertionError(f"--four-chips needs 4 devices, found "
                              f"{len(jax.devices())}")
-    mesh = make_mesh((4,), ("shard",))
     g = generate(scale, seed)
+    base = dict(method="mg", fold_backend="auto", aligned_layout=True)
     t0 = time.perf_counter()
-    ref = lpa(g, LPAConfig(method="mg", fold_backend="auto",
-                           aligned_layout=True))
+    ref = lpa(g, LPAConfig(**base))
     reading("single-chip e2e_s (smoke reading, not a benchmark)",
             time.perf_counter() - t0)
     reading("single-chip iterations", ref.iterations)
-    for halo in (False, True):
-        tag = "halo" if halo else "all-gather"
-        t0 = time.perf_counter()
-        ws = build_dist_workspace(g, 4, halo=halo, stream=True, aligned=True)
-        reading(f"{tag} plan_build_s", time.perf_counter() - t0)
-        step = jax.jit(dist_lpa_step(mesh, ws, engine="pallas_stream"))
-        hlo = step.lower(ws.nbr_pos, ws.weights, ws.round_gathers,
-                         ws.final_row_vertex, ws.init_labels,
-                         jnp.asarray(True), jnp.int32(1),
-                         ws_arrays=ws).as_text()
-        if "tpu_custom_call" not in hlo:
-            raise AssertionError(f"{tag} step holds no compiled kernel")
-        t0 = time.perf_counter()
-        labels, iters = dist_lpa(mesh, ws, engine="pallas_stream")
-        labels = np.asarray(labels)
-        reading(f"{tag} e2e_s (smoke reading, not a benchmark)",
-                time.perf_counter() - t0)
-        reading(f"{tag} iterations", iters)
-        if iters != ref.iterations:
-            raise AssertionError(f"{tag}: {iters} iterations, single-chip "
-                                 f"{ref.iterations}")
-        require_labels_match(labels, ref.labels, f"{tag} vs single-chip")
-        reading(f"{tag} modularity", float(modularity(g, labels)))
-        del ws
+
+    cfg = LPAConfig(**base, track_frontier=False, shards=4)
+    t0 = time.perf_counter()
+    ws = jax.block_until_ready(build_workspace(g, cfg))
+    reading("halo plan_build_s", time.perf_counter() - t0)
+    backend = ws.bundle.spec.backend
+    reading("halo auto resolved to", backend)
+    d = ws.dist
+    step = jax.jit(dist_lpa_step(ws.mesh, d, engine=backend))
+    hlo = step.lower(d.nbr_pos, d.weights, d.round_gathers,
+                     d.final_row_vertex, d.init_labels, jnp.asarray(True),
+                     jnp.int32(1), ws_arrays=d).as_text()
+    if "tpu_custom_call" not in hlo:
+        raise AssertionError("halo step holds no compiled kernel")
+    t0 = time.perf_counter()
+    res = lpa(g, cfg, ws=ws)
+    reading("halo e2e_s (smoke reading, not a benchmark)",
+            time.perf_counter() - t0)
+    reading("halo iterations", res.iterations)
+    reading("halo host_syncs", res.host_syncs)
+    if res.iterations != ref.iterations:
+        raise AssertionError(f"halo: {res.iterations} iterations, "
+                             f"single-chip {ref.iterations}")
+    if res.changed_history != ref.changed_history:
+        raise AssertionError(f"halo: moved counts {res.changed_history}, "
+                             f"single-chip {ref.changed_history}")
+    require_labels_match(np.asarray(res.labels), ref.labels,
+                         "halo vs single-chip")
+    reading("halo modularity", float(modularity(g, res.labels)))
+    mesh = ws.mesh
+    del ws, d, res
+
+    # the all-gather exchange, which only dist_lpa's callers build
+    t0 = time.perf_counter()
+    stream = backend == "pallas_stream"
+    dw = build_dist_workspace(g, 4, halo=False, stream=stream,
+                              fused=backend == "pallas_fused", aligned=stream)
+    reading("all-gather plan_build_s", time.perf_counter() - t0)
+    t0 = time.perf_counter()
+    labels, iters = dist_lpa(mesh, dw, engine=backend)
+    reading("all-gather e2e_s (smoke reading, not a benchmark)",
+            time.perf_counter() - t0)
+    reading("all-gather iterations", iters)
+    if iters != ref.iterations:
+        raise AssertionError(f"all-gather: {iters} iterations, single-chip "
+                             f"{ref.iterations}")
+    require_labels_match(np.asarray(labels), ref.labels,
+                         "all-gather vs single-chip")
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only dist_lpa on a (4,) mesh vs single-chip")
+                    help="run only lpa(shards=4) vs single-chip")
     ap.add_argument("--scale", type=int, default=None,
                     help="Graph500 Kronecker scale (default 22 on one chip, "
                          "20 with --four-chips)")

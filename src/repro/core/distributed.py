@@ -23,20 +23,24 @@ empty sketches (weight 0 entries are no-ops by construction).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import obs
 from repro.core import sketch as sketch_lib
+from repro.core.fold_engine import resolve_auto
 from repro.core.fold_program import FoldRequest
+from repro.core.lpa import LPAResult
 from repro.core.plan_bundle import (PlanSpec, ShardSlice, build_plan_bundle,
-                                    stack_aligned_windows,
+                                    spec_for, stack_aligned_windows,
                                     stack_shard_bundles,
                                     uniform_round_count)
+from repro.launch.mesh import make_mesh
 
 PAD = -1
 INT_MAX = jnp.iinfo(jnp.int32).max
@@ -58,8 +62,12 @@ class DistLPAWorkspace:
 
     nbr_pos: jnp.ndarray       # [P, M_pad] int32 — label positions (see above)
     weights: jnp.ndarray       # [P, M_pad] float32
-    round_gathers: Tuple[jnp.ndarray, ...]  # per round: [P, R_pad_r, chunk] int32
-    final_row_vertex: jnp.ndarray  # [P, R_last] int32 — local vertex per final row (-1 pad)
+    # per round: [P, R_pad_r, chunk] int32 (R_pad_r, and R_pad_0 of the
+    # bucketed round-0 maps below, 0 where the fold never reads them)
+    round_gathers: Tuple[jnp.ndarray, ...]
+    # [P, R_last] int32 — local vertex per final row (-1 pad; R_last 0 when
+    # streamed)
+    final_row_vertex: jnp.ndarray
     init_labels: jnp.ndarray   # [P, V_pad] int32 — real global ids (-1 on pad slots)
     n_nodes: int               # |V| — real (unpadded) global vertex count
     v_pad: int                 # per-shard label-slot count (max shard size)
@@ -153,12 +161,22 @@ def _edge_balanced_ranges(degrees: np.ndarray, p: int) -> np.ndarray:
     return np.concatenate([[0], bounds, [len(degrees)]]).astype(np.int64)
 
 
+def shard_entry_pad(degrees: np.ndarray, n_shards: int) -> int:
+    """Directed edge slots of the widest of the ``n_shards`` edge-balanced
+    shards: the padded entry length every shard's plans index."""
+    if not len(degrees):
+        return 1
+    cum = np.concatenate([[0], np.cumsum(degrees)])
+    return int(np.diff(cum[_edge_balanced_ranges(degrees, n_shards)]).max())
+
+
 def build_dist_workspace(graph, n_shards: int, k: int = 8, chunk: int = 128,
                          order: np.ndarray | None = None,
                          halo: bool = False, fused: bool = False,
                          tile_r: int = 128, stream: bool = False,
                          window_entries: int = 8192,
-                         aligned: bool = False) -> DistLPAWorkspace:
+                         aligned: bool = False,
+                         sharding=None) -> DistLPAWorkspace:
     """Host-side construction of the stacked distributed workspace.
 
     ``order`` optionally renumbers vertices first (e.g. the LPA-community
@@ -176,6 +194,9 @@ def build_dist_workspace(graph, n_shards: int, k: int = 8, chunk: int = 128,
     then gathers labels straight into window order and skips the
     per-iteration round-0 re-layout gather, bit-identically — the
     distributed analogue of ``LPAConfig(aligned_layout=True)``.
+    ``sharding`` places every stacked array straight from the host (shard
+    p of each [P, ...] array on mesh device p), so no device ever holds
+    all shards; None puts them on the default device.
     """
     if aligned and not stream:
         raise ValueError("aligned=True requires stream=True (the aligned "
@@ -210,8 +231,7 @@ def build_dist_workspace(graph, n_shards: int, k: int = 8, chunk: int = 128,
     local_slot = np.arange(n) - bounds[shard_of]
     padded_pos = shard_of * v_pad + local_slot
 
-    m_pad = int(max(offsets[bounds[p + 1]] - offsets[bounds[p]]
-                    for p in range(n_shards))) if n else 1
+    m_pad = shard_entry_pad(degrees, n_shards)
 
     # ONE declarative plan build per shard (DESIGN.md §15): the spec names
     # the fold backend the caller's requests will run on, and every
@@ -318,15 +338,27 @@ def build_dist_workspace(graph, n_shards: int, k: int = 8, chunk: int = 128,
         stream_apos, stream_aw = stack_aligned_windows(bundles, nbr_pos,
                                                        wgts)
 
-    return DistLPAWorkspace(
-        nbr_pos=jnp.asarray(nbr_pos), weights=jnp.asarray(wgts),
-        round_gathers=plans.round_gathers,
-        final_row_vertex=plans.final_row_vertex,
-        init_labels=jnp.asarray(init_labels),
+    round_gathers = plans.round_gathers
+    final_row_vertex = plans.final_row_vertex
+    row_vertex0, bucket_rank0 = plans.row_vertex0, plans.bucket_rank0
+    if fused or stream:
+        # the fused and streamed folds never read the bucketed round
+        # gathers, and the streamed one none of the bucketed row maps
+        # either: empty [P, 0, ...] stand-ins keep the step's operands
+        # without placing what no fold reads (at Graph500 scale 21 they
+        # were about 0.7 GB a chip)
+        round_gathers = tuple(g[:, :0] for g in round_gathers)
+        if stream:
+            final_row_vertex, row_vertex0, bucket_rank0 = (
+                a[:, :0] for a in (final_row_vertex, row_vertex0,
+                                   bucket_rank0))
+    ws = DistLPAWorkspace(
+        nbr_pos=nbr_pos, weights=wgts,
+        round_gathers=round_gathers,
+        final_row_vertex=final_row_vertex,
+        init_labels=init_labels,
         n_nodes=int(n), v_pad=int(v_pad), k=int(k), chunk=int(chunk),
-        send_idx=None if send_idx is None else jnp.asarray(send_idx),
-        h_pad=int(h_pad),
-        hub_idx=None if hub_idx_arr is None else jnp.asarray(hub_idx_arr),
+        send_idx=send_idx, h_pad=int(h_pad), hub_idx=hub_idx_arr,
         hub_pad=int(hub_pad),
         fused_starts=plans.fused_starts, fused_counts=plans.fused_counts,
         fused_dmax=plans.fused_dmax, fused_entries=plans.fused_entries,
@@ -334,11 +366,14 @@ def build_dist_workspace(graph, n_shards: int, k: int = 8, chunk: int = 128,
         stream_starts=plans.stream_starts,
         stream_counts=plans.stream_counts, stream_dmax=plans.stream_dmax,
         stream_final_rv=plans.stream_final_rv,
-        row_vertex0=plans.row_vertex0, fused_rv0=plans.fused_rv0,
-        stream_rv0=plans.stream_rv0, entry_vertex=jnp.asarray(entry_vertex),
+        row_vertex0=row_vertex0, fused_rv0=plans.fused_rv0,
+        stream_rv0=plans.stream_rv0, entry_vertex=entry_vertex,
         stream_aligned_pos=stream_apos, stream_aligned_w=stream_aw,
-        bucket_rank0=plans.bucket_rank0, fused_rank0=plans.fused_rank0,
+        bucket_rank0=bucket_rank0, fused_rank0=plans.fused_rank0,
         stream_rank0=plans.stream_rank0, max_rows0=plans.max_rows0)
+    if sharding is None:
+        return jax.tree_util.tree_map(jnp.asarray, ws)
+    return jax.device_put(ws, sharding)
 
 
 def _shard_move(nbr_pos, edge_w, round_gathers, final_row_vertex, labels,
@@ -762,46 +797,164 @@ def dist_lpa(mesh, ws: DistLPAWorkspace, rho: int = 8, tau: float = 0.05,
     ``frontier_gate`` turns on per-shard dense frontier gating (the
     distributed analogue of ``LPAConfig.frontier_gate``): settled vertices
     keep their label, and Pick-Less iterations union the previous frontier
-    into the marks so deferred vertices stay queued (§8.5)."""
-    step = jax.jit(dist_lpa_step(mesh, ws, engine=engine, method=method,
-                                 rescan=rescan,
+    into the marks so deferred vertices stay queued (§8.5).
+
+    ``lpa(graph, LPAConfig(shards=P))`` runs the same loop on a workspace
+    ``build_workspace`` placed once; this entry point places ``ws`` on
+    ``mesh`` on every call."""
+    ws = jax.device_put(ws, NamedSharding(mesh, P(tuple(mesh.axis_names))))
+    label_pos = jax.device_put(label_positions(ws.init_labels),
+                               NamedSharding(mesh, P()))
+    res = dist_solve(mesh, ws, label_pos, rho=rho, tau=tau,
+                     max_iters=max_iters, engine=engine, method=method,
+                     rescan=rescan, frontier_gate=frontier_gate)
+    return res.labels, res.iterations
+
+
+def label_positions(init_labels) -> np.ndarray:
+    """[N] int32: where vertex v's label lies in the flattened [P * V_pad]
+    stacked labels (``init_labels`` holds each slot's vertex id, -1 on
+    pads)."""
+    slots = np.asarray(init_labels).reshape(-1)
+    real = slots >= 0
+    pos = np.empty(int(real.sum()), dtype=np.int32)
+    pos[slots[real]] = np.nonzero(real)[0]
+    return pos
+
+
+@jax.jit
+def _labels_by_vertex(labels, label_pos):
+    """Stacked [P, V_pad] labels -> [N] labels in vertex order."""
+    return labels.reshape(-1)[label_pos]
+
+
+@functools.lru_cache(maxsize=32)
+def _jitted_step(mesh, treedef, engine, method, rescan, frontier_gate):
+    """One jitted step per mesh, workspace structure and routing key, so
+    repeated solves re-trace nothing. The step reads only the statics of
+    the workspace it is built from (its arrays come in as ``ws_arrays``),
+    so it is built from the structure alone and holds no array."""
+    skeleton = jax.tree_util.tree_unflatten(treedef,
+                                            [True] * treedef.num_leaves)
+    return jax.jit(dist_lpa_step(mesh, skeleton, engine=engine,
+                                 method=method, rescan=rescan,
                                  frontier_gate=frontier_gate))
-    # place the stacked workspace once — shard p of every [P, ...] array on
-    # mesh device p — and hand it to every step call as an argument, so no
-    # call reshards it and no array is compiled into the program
-    shards = NamedSharding(mesh, P(tuple(mesh.axis_names)))
-    ws = jax.device_put(ws, shards)
-    labels = ws.init_labels
+
+
+def dist_solve(mesh, ws: DistLPAWorkspace, label_pos, *, rho: int = 8,
+               tau: float = 0.05, max_iters: int = 20,
+               engine: str | None = None, method: str = "mg",
+               rescan: bool = False,
+               frontier_gate: bool = False) -> LPAResult:
+    """The distributed host loop over a workspace already placed on
+    ``mesh``; ``label_pos`` is ``label_positions(ws.init_labels)`` on the
+    mesh. Options as ``dist_lpa``'s. The result's labels are in vertex
+    order; it records no frontier or work-row history.
+
+    A Pick-Less iteration never converges, so the loop does not read its
+    moved count: the count stays on the device and is read with the next
+    iteration's, one host read per non-Pick-Less iteration (one more if
+    ``max_iters`` ends on a Pick-Less iteration)."""
+    step = _jitted_step(mesh, jax.tree_util.tree_structure(ws), engine,
+                        method, rescan, frontier_gate)
     n = ws.n_nodes
-    frontier = jax.device_put(jnp.ones(labels.shape, dtype=jnp.bool_),
-                              shards)
+    count = obs.SyncCount()
+    history, pending = [], []
+    converged = False
     it = 0
     with obs.span("lpa.solve"):
+        labels = ws.init_labels
+        if frontier_gate:
+            frontier = jax.device_put(
+                jnp.ones(labels.shape, dtype=jnp.bool_),
+                NamedSharding(mesh, P(tuple(mesh.axis_names))))
         for it in range(max_iters):
             with obs.span("lpa.iteration", it=it):
                 pl_on = (it % rho) == 0
                 with obs.span("lpa.dispatch.move"):
-                    if frontier_gate:
-                        labels, delta, marked = step(
-                            ws.nbr_pos, ws.weights, ws.round_gathers,
+                    args = (ws.nbr_pos, ws.weights, ws.round_gathers,
                             ws.final_row_vertex, labels, jnp.asarray(pl_on),
-                            jnp.int32(it + 1), frontier=frontier,
-                            ws_arrays=ws)
+                            jnp.int32(it + 1))
+                    if frontier_gate:
+                        labels, delta, marked = step(*args,
+                                                     frontier=frontier,
+                                                     ws_arrays=ws)
                         frontier = (frontier | marked) if pl_on else marked
                     else:
-                        labels, delta = step(
-                            ws.nbr_pos, ws.weights, ws.round_gathers,
-                            ws.final_row_vertex, labels, jnp.asarray(pl_on),
-                            jnp.int32(it + 1), ws_arrays=ws)
-                if pl_on:  # a Pick-Less pass never converges: no read
+                        labels, delta = step(*args, ws_arrays=ws)
+                pending.append(delta)
+                if pl_on:
                     continue
-                with obs.span("lpa.sync.delta"):
-                    moved = int(delta)
-                if moved / max(n, 1) < tau:
+                with obs.sync("delta", count):
+                    history += [int(d) for d in jax.device_get(pending)]
+                pending = []
+                if history[-1] / max(n, 1) < tau:
+                    converged = True
                     break
-    flat = np.asarray(labels).reshape(-1)
-    slots = np.asarray(ws.init_labels).reshape(-1)
-    out = np.empty(n, dtype=np.int32)
-    real = slots >= 0
-    out[slots[real]] = flat[real]
-    return jnp.asarray(out), it + 1
+        if pending:
+            with obs.sync("delta", count):
+                history += [int(d) for d in jax.device_get(pending)]
+        out = _labels_by_vertex(labels, label_pos)
+    return LPAResult(labels=out, iterations=it + 1, changed_history=history,
+                     converged=converged, host_syncs=count.host_syncs)
+
+
+@dataclasses.dataclass(frozen=True)
+class ShardBundle:
+    """The plan-level view a one-chip workspace's ``bundle`` gives: the
+    resolved per-shard spec (the stacked plans live in the
+    ``DistLPAWorkspace``)."""
+
+    spec: PlanSpec  # "auto" resolved against the widest shard
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class ShardedWorkspace:
+    """``build_workspace``'s workspace for ``LPAConfig(shards > 1)``: the
+    stacked shard workspace placed on a ``(shards,)`` mesh once, and where
+    each vertex's label lies in it. Answers ``bundle.spec`` and
+    ``stream_plan`` (None: the windowed plans are per shard) as a one-chip
+    ``LPAWorkspace`` does."""
+
+    dist: DistLPAWorkspace  # placed: shard p of each array on device p
+    label_pos: jnp.ndarray  # [N] int32 (label_positions), replicated
+    mesh: Mesh              # the (shards,) mesh ``dist`` lives on
+    bundle: ShardBundle     # the resolved per-shard spec
+
+    def tree_flatten(self):
+        return (self.dist, self.label_pos), (self.mesh, self.bundle)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+    @property
+    def stream_plan(self):
+        return None
+
+
+def build_sharded_workspace(graph, config) -> ShardedWorkspace:
+    """The ``LPAConfig(shards > 1)`` build (duck-typed on the config, as
+    ``spec_for``): edge-balanced shards, their plans and the halo
+    exchange tables (``build_dist_workspace(halo=True)``), placed on the
+    first ``shards`` devices. ``fold_backend="auto"`` resolves against the widest shard's
+    entry count by the one-chip rule (``resolve_auto``)."""
+    backend = config.fold_backend
+    if backend == "auto":
+        backend = resolve_auto(
+            shard_entry_pad(np.asarray(graph.degrees), config.shards),
+            config.vmem_budget_bytes)
+    stream = backend == "pallas_stream"
+    mesh = make_mesh((config.shards,), ("shard",))
+    dist = build_dist_workspace(
+        graph, config.shards, k=config.k, chunk=config.chunk,
+        halo=True, fused=backend == "pallas_fused", stream=stream,
+        window_entries=config.stream_window,
+        aligned=stream and config.aligned_layout,
+        sharding=NamedSharding(mesh, P("shard")))
+    label_pos = jax.device_put(label_positions(dist.init_labels),
+                               NamedSharding(mesh, P()))
+    spec = dataclasses.replace(spec_for(config), backend=backend)
+    return ShardedWorkspace(dist=dist, label_pos=label_pos, mesh=mesh,
+                            bundle=ShardBundle(spec=spec))

@@ -47,7 +47,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Literal, Optional
+from typing import TYPE_CHECKING, Callable, Literal, Optional
 
 import numpy as np
 import jax
@@ -59,6 +59,11 @@ from repro.core.fold_engine import get_engine
 from repro.core.fold_program import FoldRequest
 from repro.core.plan_bundle import PlanBundle, build_plan_bundle, spec_for
 from repro.graphs.csr import CSRGraph
+
+# core.distributed imports LPAResult from here, so the sharded path
+# imports it where it runs
+if TYPE_CHECKING:
+    from repro.core.distributed import ShardedWorkspace
 
 Method = Literal["exact", "mg", "bm"]
 
@@ -112,6 +117,15 @@ class LPAConfig:
     # frontier_gate and track_frontier off, mark_frontier is never called
     # and no segment_max is paid (asserted in tests/test_sparse_frontier).
     track_frontier: bool = True
+    # > 1: split the vertices into this many edge-balanced shards, one a
+    # device (core.distributed): build_workspace places them on a
+    # (shards,) mesh of the first `shards` devices and lpa() runs the
+    # distributed loop, bit-identical to shards=1, exchanging only the
+    # labels a shard's edges reference (hub all-gather + per-pair
+    # all_to_all). It carries neither the frontier history nor the sparse
+    # fold (track_frontier and frontier_sparse must be off), nor
+    # method="exact", nor mg_variant.
+    shards: int = 1
 
 
 @jax.tree_util.register_pytree_node_class
@@ -151,12 +165,36 @@ class LPAWorkspace:
         return self.bundle.stream_plan
 
 
-def build_workspace(graph: CSRGraph, config: LPAConfig) -> LPAWorkspace:
+def build_workspace(graph: CSRGraph, config: LPAConfig
+                    ) -> LPAWorkspace | ShardedWorkspace:
     """Thin wrapper over the bundle layer: spec the config, build the
-    bundle, attach the driver's edge-source expansion."""
+    bundle, attach the edge-source expansion ``lpa()`` reads. With
+    ``config.shards > 1``: the sharded workspace, placed on its mesh
+    (``core.distributed.build_sharded_workspace``)."""
+    if config.shards > 1:
+        from repro.core.distributed import build_sharded_workspace
+        _check_sharded(config)
+        return build_sharded_workspace(graph, config)
     return LPAWorkspace(graph=graph,
                         bundle=build_plan_bundle(graph, spec_for(config)),
                         edge_src=graph.sources())
+
+
+def _check_sharded(config: LPAConfig) -> None:
+    """Refuses what the distributed loop does not carry."""
+    refused = [
+        ("frontier_sparse", config.frontier_sparse),
+        ("track_frontier (no frontier history)", config.track_frontier),
+        ('method="exact"', config.method == "exact"),
+        (f"mg_variant={config.mg_variant!r}", config.mg_variant != "paper"),
+    ]
+    for what, on in refused:
+        if on:
+            raise ValueError(f"LPAConfig(shards={config.shards}) does not "
+                             f"carry {what}")
+    if config.shards > len(jax.devices()):
+        raise ValueError(f"LPAConfig(shards={config.shards}) needs as many "
+                         f"devices; JAX finds {len(jax.devices())}")
 
 
 def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
@@ -284,8 +322,13 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
 
     The loop's host spans and the mover's device scopes are named in
     :mod:`repro.core.obs`; they record only under ``jax.profiler.trace``.
+    ``config.shards > 1`` runs the distributed loop
+    (``core.distributed.dist_solve``, always jitted) on a workspace
+    ``build_workspace`` placed on its mesh.
     """
     config = config if config is not None else LPAConfig()
+    if config.shards > 1:
+        return _lpa_sharded(graph, config, ws)
     if config.frontier_sparse:
         if not config.frontier_gate:
             raise ValueError("frontier_sparse requires frontier_gate: the "
@@ -360,6 +403,21 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
                      frontier_history=frontier_history,
                      work_rows_history=work_rows_history,
                      host_syncs=count.host_syncs)
+
+
+def _lpa_sharded(graph: CSRGraph, config: LPAConfig,
+                 ws: Optional[ShardedWorkspace]) -> LPAResult:
+    from repro.core.distributed import ShardedWorkspace, dist_solve
+    _check_sharded(config)
+    ws = ws if ws is not None else build_workspace(graph, config)
+    if not isinstance(ws, ShardedWorkspace):
+        raise ValueError(f"LPAConfig(shards={config.shards}) needs the "
+                         "workspace build_workspace makes for it")
+    return dist_solve(ws.mesh, ws.dist, ws.label_pos, rho=config.rho,
+                      tau=config.tau, max_iters=config.max_iters,
+                      engine=ws.bundle.spec.backend, method=config.method,
+                      rescan=config.method == "mg" and config.rescan,
+                      frontier_gate=config.frontier_gate)
 
 
 def lpa_step_fn(config: LPAConfig) -> Callable:

@@ -5,8 +5,10 @@ when no profiler runs and records only while one does, on the same clock
 as the device's ops in the profiler's ``.xplane.pb``. There is no switch
 and no exporter of its own — the profiler is the exporter. Names:
 
-* host spans (``lpa()`` and ``dist_lpa()``): ``lpa.solve`` around a call,
-  ``lpa.iteration`` (``it=``) around each pass, ``lpa.dispatch.move`` and
+* host spans (``lpa()``, and the sharded loop ``dist_solve`` that
+  ``lpa(LPAConfig(shards > 1))`` and ``dist_lpa()`` run): ``lpa.solve``
+  around a call, ``lpa.iteration`` (``it=``) around each pass,
+  ``lpa.dispatch.move`` and
   ``lpa.dispatch.frontier`` around the jitted calls and the small
   programs that feed them, ``lpa.sync.<what>`` around each
   device-to-host read (:func:`sync`);

@@ -37,7 +37,6 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
 from repro.core.fold_engine import resolve_auto
 from repro.graphs.csr import (FoldPlan, FusedFoldPlan, StreamedFoldPlan,
@@ -216,7 +215,7 @@ def uniform_round_count(shard_counts: List[np.ndarray], *, k: int,
 
 @dataclasses.dataclass
 class ShardPlanBundle:
-    """One shard's host-side plans (numpy; stacked to device arrays by
+    """One shard's host-side plans (numpy; stacked by
     ``stack_shard_bundles``). The single-width (width = chunk) round
     encoding matches the legacy distributed builder row for row."""
 
@@ -359,48 +358,49 @@ def _build_shard_bundle(shard: ShardSlice, spec: PlanSpec
 
 @dataclasses.dataclass
 class StackedShardPlans:
-    """Per-shard bundles padded + stacked to the uniform [P, ...] device
+    """Per-shard bundles padded + stacked to the uniform [P, ...] host
     arrays ``DistLPAWorkspace`` carries (one field per engine encoding;
-    the workspace forwards them verbatim)."""
+    ``build_dist_workspace`` places them, so no device holds all shards
+    at once)."""
 
     # per round: [P, R_pad_r, chunk] int32 gather into the flat entries
-    round_gathers: Tuple[jnp.ndarray, ...]
+    round_gathers: Tuple[np.ndarray, ...]
     # [P, R_last] int32 — local vertex per final-round row (-1 pads)
-    final_row_vertex: jnp.ndarray
+    final_row_vertex: np.ndarray
     # [P, R_pad_0] int32 — round-0 row -> local vertex (-1 pads)
-    row_vertex0: jnp.ndarray
+    row_vertex0: np.ndarray
     # [P, R_pad_0] int32 — round-0 row -> chunk rank (0 on pads)
-    bucket_rank0: jnp.ndarray
+    bucket_rank0: np.ndarray
     # max round-0 chunk rows any vertex owns across shards (rescan depth)
     max_rows0: int
     # fused metadata (backend == "pallas_fused"), per round:
     # [P, S_r, tile_r] int32 row starts / counts, [P, S_r, 1] int32 dmax
-    fused_starts: Optional[Tuple[jnp.ndarray, ...]] = None
+    fused_starts: Optional[Tuple[np.ndarray, ...]] = None
     # per round [P, S_r, tile_r] int32 row entry counts (see fused_starts)
-    fused_counts: Optional[Tuple[jnp.ndarray, ...]] = None
+    fused_counts: Optional[Tuple[np.ndarray, ...]] = None
     # per round [P, S_r, 1] int32 max count per grid step
-    fused_dmax: Optional[Tuple[jnp.ndarray, ...]] = None
+    fused_dmax: Optional[Tuple[np.ndarray, ...]] = None
     # per round: flat entry-array length the fused kernel reads
     fused_entries: Tuple[int, ...] = ()
     # [P, S_0 * tile_r] int32 fused round-0 row -> local vertex (-1 pads)
-    fused_rv0: Optional[jnp.ndarray] = None
+    fused_rv0: Optional[np.ndarray] = None
     # [P, S_0 * tile_r] int32 fused round-0 row -> chunk rank (0 on pads)
-    fused_rank0: Optional[jnp.ndarray] = None
+    fused_rank0: Optional[np.ndarray] = None
     # streamed metadata (backend == "pallas_stream"), per round:
     # [P, n_win_r, W_r] int32 windowed entry gather (-1 pads)
-    stream_gathers: Optional[Tuple[jnp.ndarray, ...]] = None
+    stream_gathers: Optional[Tuple[np.ndarray, ...]] = None
     # per round [P, n_win_r, tile_r] int32 in-window row starts
-    stream_starts: Optional[Tuple[jnp.ndarray, ...]] = None
+    stream_starts: Optional[Tuple[np.ndarray, ...]] = None
     # per round [P, n_win_r, tile_r] int32 row entry counts
-    stream_counts: Optional[Tuple[jnp.ndarray, ...]] = None
+    stream_counts: Optional[Tuple[np.ndarray, ...]] = None
     # per round [P, n_win_r, 1] int32 max count per window step
-    stream_dmax: Optional[Tuple[jnp.ndarray, ...]] = None
+    stream_dmax: Optional[Tuple[np.ndarray, ...]] = None
     # [P, n_win_last * tile_r] int32 final window slot -> local vertex
-    stream_final_rv: Optional[jnp.ndarray] = None
+    stream_final_rv: Optional[np.ndarray] = None
     # [P, n_win_0 * tile_r] int32 round-0 window slot -> local vertex
-    stream_rv0: Optional[jnp.ndarray] = None
+    stream_rv0: Optional[np.ndarray] = None
     # [P, n_win_0 * tile_r] int32 round-0 window slot -> chunk rank
-    stream_rank0: Optional[jnp.ndarray] = None
+    stream_rank0: Optional[np.ndarray] = None
 
 
 def stack_shard_bundles(bundles: List[ShardPlanBundle]
@@ -438,7 +438,7 @@ def stack_shard_bundles(bundles: List[ShardPlanBundle]
                 bucket_rank0[p, :len(row_vertex)] = b.rounds[r][4]
             if r == n_rounds - 1:
                 final_row_vertex[p, :len(row_vertex)] = row_vertex
-        round_gathers.append(jnp.asarray(g))
+        round_gathers.append(g)
     max_rows0 = max(b.max_rows0 for b in bundles)
 
     fused_starts = fused_counts = fused_dmax = None
@@ -455,19 +455,19 @@ def stack_shard_bundles(bundles: List[ShardPlanBundle]
             if r == 0:  # fused round-0 rows share the bucketed row order
                 fv = np.full((n_shards, n_steps * tile_r), _PAD, np.int32)
                 fv[:, :row_vertex0.shape[1]] = row_vertex0
-                fused_rv0 = jnp.asarray(fv)
+                fused_rv0 = fv
                 fr = np.zeros((n_shards, n_steps * tile_r), np.int32)
                 fr[:, :bucket_rank0.shape[1]] = bucket_rank0
-                fused_rank0 = jnp.asarray(fr)
+                fused_rank0 = fr
             for p, b in enumerate(bundles):
                 row_start, row_count = b.rounds[r][2:4]
                 rs[p, :len(row_start)] = row_start
                 rc[p, :len(row_count)] = row_count
             rs = rs.reshape(n_shards, n_steps, tile_r)
             rc = rc.reshape(n_shards, n_steps, tile_r)
-            fused_starts.append(jnp.asarray(rs))
-            fused_counts.append(jnp.asarray(rc))
-            fused_dmax.append(jnp.asarray(rc.max(axis=2, keepdims=True)))
+            fused_starts.append(rs)
+            fused_counts.append(rc)
+            fused_dmax.append(rc.max(axis=2, keepdims=True))
             entries.append(n_entries)
             n_entries = n_steps * tile_r * k  # next round's flat source
         fused_starts = tuple(fused_starts)
@@ -498,17 +498,17 @@ def stack_shard_bundles(bundles: List[ShardPlanBundle]
                 rs[p, :nw] = rr["row_start"]
                 rc[p, :nw] = rr["row_count"]
                 dm[p, :nw] = rr["step_dmax"]
-            sg.append(jnp.asarray(g))
-            ss.append(jnp.asarray(rs))
-            sc.append(jnp.asarray(rc))
-            sd.append(jnp.asarray(dm))
+            sg.append(g)
+            ss.append(rs)
+            sc.append(rc)
+            sd.append(dm)
         stream_gathers, stream_starts = tuple(sg), tuple(ss)
         stream_counts, stream_dmax = tuple(sc), tuple(sd)
         n_slots_last = sg[-1].shape[1] * tile_r
         frv = np.full((n_shards, n_slots_last), _PAD, dtype=np.int32)
         for p, b in enumerate(bundles):
             frv[p, :len(b.stream_final_rtv)] = b.stream_final_rtv
-        stream_final_rv = jnp.asarray(frv)
+        stream_final_rv = frv
         # round-0 window slot -> local vertex + chunk rank (appending
         # all-pad windows never moves a real slot, so the per-shard slot
         # maps pad safely: vertex -1, rank 0)
@@ -520,14 +520,14 @@ def stack_shard_bundles(bundles: List[ShardPlanBundle]
             srv0[p, :len(rv)] = rv
             rk = b.stream_rounds[0]["row_rank"]
             srk0[p, :len(rk)] = rk
-        stream_rv0 = jnp.asarray(srv0)
-        stream_rank0 = jnp.asarray(srk0)
+        stream_rv0 = srv0
+        stream_rank0 = srk0
 
     return StackedShardPlans(
         round_gathers=tuple(round_gathers),
-        final_row_vertex=jnp.asarray(final_row_vertex),
-        row_vertex0=jnp.asarray(row_vertex0),
-        bucket_rank0=jnp.asarray(bucket_rank0), max_rows0=int(max_rows0),
+        final_row_vertex=final_row_vertex,
+        row_vertex0=row_vertex0,
+        bucket_rank0=bucket_rank0, max_rows0=int(max_rows0),
         fused_starts=fused_starts, fused_counts=fused_counts,
         fused_dmax=fused_dmax, fused_entries=fused_entries,
         fused_rv0=fused_rv0, fused_rank0=fused_rank0,
@@ -539,7 +539,7 @@ def stack_shard_bundles(bundles: List[ShardPlanBundle]
 
 def stack_aligned_windows(bundles: List[ShardPlanBundle],
                           tables: np.ndarray, weight_tables: np.ndarray
-                          ) -> tuple[jnp.ndarray, jnp.ndarray]:
+                          ) -> tuple[np.ndarray, np.ndarray]:
     """Apply every shard's ``remap_labels`` transform and stack the
     results to the [P, n_win_0 * W] aligned position/weight arrays.
 
@@ -558,5 +558,5 @@ def stack_aligned_windows(bundles: List[ShardPlanBundle],
         nw, w_s = pos.shape
         ap[p, :nw, :w_s] = pos
         aw[p, :nw, :w_s] = wts
-    return (jnp.asarray(ap.reshape(n_shards, -1)),
-            jnp.asarray(aw.reshape(n_shards, -1)))
+    return (ap.reshape(n_shards, -1),
+            aw.reshape(n_shards, -1))

@@ -6,6 +6,7 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
 import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -434,3 +435,144 @@ def test_dist_collectives_carry_the_exchange_scope(halo):
         print("collectives", len(lines))
     """, devices=4)
     assert "collectives" in out
+
+
+# a seeded weighted Graph500-style R-MAT: chunk 16 and k 4 give its hub
+# rows (degree up to about 200) three fold rounds; a 1 KiB VMEM budget
+# makes fold_backend="auto" resolve to the streamed engine on each shard
+_WEIGHTED_RMAT = """
+    import numpy as np, jax
+    from repro.core import LPAConfig, build_workspace, lpa
+    from repro.core.distributed import ShardedWorkspace
+    from repro.graphs.csr import build_csr
+    from repro.graphs.generators import rmat
+    g0 = rmat(9, edge_factor=8, seed=3)
+    src, dst = np.asarray(g0.sources()), np.asarray(g0.indices)
+    e = np.stack([src[src < dst], dst[src < dst]], 1)
+    g = build_csr(e, g0.n_nodes, weights=np.random.default_rng(3).random(
+        len(e)).astype(np.float32))
+    BASE = dict(method="mg", k=4, chunk=16, rho=3, max_iters=20,
+                fold_backend="auto", vmem_budget_bytes=1024,
+                aligned_layout=True, stream_window=512,
+                track_frontier=False)
+"""
+
+
+@pytest.mark.slow  # spawns a multi-device subprocess
+@pytest.mark.parametrize("routed, gate", [(True, False), (False, False),
+                                          (True, True)],
+                         ids=["halo", "all-gather", "halo-gated"])
+def test_lpa_shards_routes_to_the_distributed_solve(routed, gate):
+    """lpa(g, LPAConfig(shards=4)) on the workspace build_workspace places
+    runs the four-shard halo solve and gives single-host lpa()'s labels,
+    iteration count and every iteration's moved count, Pick-Less
+    iterations included, with one host read per non-Pick-Less
+    iteration. The all-gather exchange (build_dist_workspace(halo=False),
+    which dist_lpa's callers keep) runs the same loop, dist_solve, to the
+    same result."""
+    out = _run(_WEIGHTED_RMAT + f"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.distributed import (build_dist_workspace, dist_solve,
+                                        label_positions)
+    from repro.graphs.csr import build_fold_plan
+    from repro.launch.mesh import make_mesh
+    assert len(build_fold_plan(np.asarray(g.degrees), k=4,
+                               chunk=16).rounds) >= 2
+    gate = {gate}
+    ref = lpa(g, LPAConfig(**BASE, frontier_gate=gate))
+    cfg = LPAConfig(**BASE, shards=4, frontier_gate=gate)
+    if {routed}:
+        ws = jax.block_until_ready(build_workspace(g, cfg))
+        assert isinstance(ws, ShardedWorkspace)
+        assert ws.bundle.spec.backend == "pallas_stream"
+        assert ws.stream_plan is None
+        assert ws.dist.stream_aligned_pos is not None
+        assert ws.dist.send_idx is not None  # the halo tables
+        solve = lambda: lpa(g, cfg, ws=ws)
+    else:
+        mesh = make_mesh((4,), ("shard",))
+        d = build_dist_workspace(g, 4, k=4, chunk=16, halo=False,
+                                 stream=True, window_entries=512,
+                                 aligned=True,
+                                 sharding=NamedSharding(mesh, P("shard")))
+        assert d.send_idx is None and d.hub_idx is None
+        pos = jax.device_put(label_positions(d.init_labels),
+                             NamedSharding(mesh, P()))
+        solve = lambda: dist_solve(mesh, d, pos, rho=3, max_iters=20,
+                                   engine="pallas_stream",
+                                   frontier_gate=gate)
+    for _ in range(2):  # the placed workspace serves solve after solve
+        res = solve()
+        assert (np.asarray(res.labels) == np.asarray(ref.labels)).all()
+        assert res.iterations == ref.iterations
+        assert res.changed_history == ref.changed_history
+        assert res.converged == ref.converged
+        pick_less = sum(it % 3 == 0 for it in range(res.iterations))
+        assert pick_less >= 2
+        assert res.host_syncs == res.iterations - pick_less
+    print("routed", res.iterations, res.changed_history)
+    """, devices=4)
+    assert "routed" in out
+
+
+@pytest.mark.parametrize("refused", [
+    dict(frontier_gate=True, frontier_sparse=True),
+    dict(track_frontier=True),
+    dict(method="exact"),
+    dict(mg_variant="exact_weighted"),
+    dict(shards=64),
+], ids=["frontier_sparse", "track_frontier", "exact", "mg_variant",
+        "more_shards_than_devices"])
+def test_lpa_shards_refuses_what_the_distributed_loop_lacks(refused):
+    from repro.core import LPAConfig, build_workspace, lpa
+    from repro.graphs.generators import ring_of_cliques
+    g, _ = ring_of_cliques(4, 5)
+    kw = dict(shards=2, track_frontier=False)
+    kw.update(refused)
+    cfg = LPAConfig(**kw)
+    # this process holds one device, so any shards > 1 meets the device
+    # check last: each refusal must name its own option first
+    match = "devices" if "shards" in refused else "does not carry"
+    with pytest.raises(ValueError, match=match):
+        build_workspace(g, cfg)
+    with pytest.raises(ValueError, match=match):
+        lpa(g, cfg)
+
+
+def test_lpa_shards_one_is_the_single_host_path():
+    """shards=1, the default, written out or left out, changes nothing:
+    the same workspace type and the same result, read for read."""
+    from repro.core import LPAConfig, LPAWorkspace, build_workspace, lpa
+    from repro.graphs.generators import powerlaw_communities
+    g, _ = powerlaw_communities(512, p_in=0.5, mix=0.02, seed=2)
+    base = lpa(g, LPAConfig(rho=2))
+    for cfg in (LPAConfig(rho=2), LPAConfig(rho=2, shards=1)):
+        assert isinstance(build_workspace(g, cfg), LPAWorkspace)
+        res = lpa(g, cfg)
+        assert (np.asarray(res.labels) == np.asarray(base.labels)).all()
+        assert res.iterations == base.iterations
+        assert res.changed_history == base.changed_history
+        assert res.frontier_history == base.frontier_history
+        assert res.host_syncs == base.host_syncs
+
+
+@pytest.mark.parametrize("engine", ["pallas_fused", "pallas_stream"])
+def test_dist_workspace_places_no_bucketed_tables_its_fold_skips(engine):
+    """The fused and streamed shard folds never read the bucketed round
+    gathers (nor, streamed, the bucketed row maps): the workspace keeps
+    the step's operands as empty [P, 0, ...] stand-ins instead of
+    placing them."""
+    from repro.core.distributed import build_dist_workspace
+    from repro.graphs.generators import powerlaw_communities
+    g, _ = powerlaw_communities(512, p_in=0.5, mix=0.02, seed=2)
+    stream = engine == "pallas_stream"
+    ws = build_dist_workspace(g, 2, stream=stream, fused=not stream,
+                              tile_r=32, window_entries=512)
+    full = build_dist_workspace(g, 2)
+    assert len(ws.round_gathers) == len(full.round_gathers)
+    for g_r, f_r in zip(ws.round_gathers, full.round_gathers):
+        assert g_r.shape == (2, 0, f_r.shape[2]) and f_r.shape[1] > 0
+    kept = (ws.final_row_vertex, ws.row_vertex0, ws.bucket_rank0)
+    for a, f in zip(kept, (full.final_row_vertex, full.row_vertex0,
+                           full.bucket_rank0)):
+        assert a.shape == ((2, 0) if stream else f.shape)
