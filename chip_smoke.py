@@ -11,8 +11,11 @@ layout to convergence, and checks that
 
   * the lowered mover holds compiled Pallas kernels (``tpu_custom_call``),
     so a kernel in interpret mode cannot pass;
-  * its labels are bit-identical to ``fold_backend="jnp"`` on the same
-    chip, and its modularity is at least 0.9 x the jnp run's.
+  * the mover marks the frontier in its round-0 pass (``mover_marks`` is
+    ``iterations - 1``);
+  * its labels, moved counts and frontier history are bit-identical to
+    ``fold_backend="jnp"`` (whose frontier ``mark_frontier`` marks) on the
+    same chip, and its modularity is at least 0.9 x the jnp run's.
 
 ``--four-chips`` runs only the four-chip path and what it is compared
 with: ``lpa()`` with ``LPAConfig(shards=4)`` (the distributed loop over a
@@ -32,7 +35,6 @@ before any phase and prints no result.
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 import sys
@@ -45,8 +47,9 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro.core import LPAConfig, build_workspace, lpa, lpa_move  # noqa: E402
+from repro.core import LPAConfig, build_workspace, lpa  # noqa: E402
 from repro.core import modularity  # noqa: E402
+from repro.core.lpa import marks_in_mover, mover  # noqa: E402
 from repro.graphs.csr import streamed_window_slots  # noqa: E402
 from repro.graphs.generators import rmat  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
@@ -88,15 +91,18 @@ def one_chip(scale: int, seed: int) -> None:
         raise AssertionError(f"auto resolved to {backend}, not pallas_stream")
     reading("streamed_window_slots", streamed_window_slots(ws.stream_plan))
 
-    # the dense mover exactly as lpa() jits it; with the persistent cache
-    # on, lpa()'s own compile of the same program is a cache read
-    move = jax.jit(functools.partial(lpa_move, config=cfg,
-                                     cap_rows=ws.bundle.cap_rows()),
-                   static_argnames=("sparse",))
+    # the mover exactly as lpa() jits it (it marks the frontier, and takes
+    # the previous changed mask and frontier donated); with the persistent
+    # cache on, lpa()'s own compile of the same program is a cache read
+    if not marks_in_mover(ws, cfg):
+        raise AssertionError("the mover does not mark the frontier")
+    move = jax.jit(mover(cfg, ws.bundle.cap_rows(), marks=True),
+                   donate_argnames=("last",))
     labels0 = jnp.arange(g.n_nodes, dtype=jnp.int32)
+    mask = jnp.zeros((g.n_nodes,), jnp.bool_)
     t0 = time.perf_counter()
     lowered = move.lower(ws, labels0, jnp.asarray(True), jnp.int32(1),
-                         frontier=None, sparse=False)
+                         last=(mask, mask), last_pick_less=jnp.asarray(True))
     lowered.compile()
     reading("compile_s", time.perf_counter() - t0)
     if "tpu_custom_call" not in lowered.as_text():
@@ -109,9 +115,14 @@ def one_chip(scale: int, seed: int) -> None:
             time.perf_counter() - t0)
     q = float(modularity(g, res.labels))
     reading("iterations", res.iterations)
+    reading("mover_marks", res.mover_marks)
     reading("modularity", q)
     reading("peak_bytes_in_use",
             jax.devices()[0].memory_stats()["peak_bytes_in_use"])
+    if res.mover_marks != res.iterations - 1:
+        raise AssertionError(f"the mover marked {res.mover_marks} of "
+                             f"{res.iterations} iterations' frontiers")
+    moved, frontier = res.changed_history, res.frontier_history
     del ws, res
 
     ref = lpa(g, LPAConfig(method="mg", fold_backend="jnp"))
@@ -119,6 +130,12 @@ def one_chip(scale: int, seed: int) -> None:
     reading("jnp iterations", ref.iterations)
     reading("jnp modularity", q_ref)
     require_labels_match(labels, ref.labels, "auto vs jnp")
+    if moved != ref.changed_history:
+        raise AssertionError(f"moved counts {moved}, jnp "
+                             f"{ref.changed_history}")
+    if frontier != ref.frontier_history:
+        raise AssertionError(f"frontier history {frontier}, jnp "
+                             f"{ref.frontier_history}")
     # at least 0.9 x the jnp run's modularity; written as a 10% margin so
     # that it also holds for a (tiny) negative Q of one giant community
     if q < q_ref - 0.1 * abs(q_ref):

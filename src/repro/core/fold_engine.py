@@ -415,12 +415,25 @@ class PallasStreamEngine(FoldEngine):
         return _scatter_padded_rows(stream_plan.n_nodes, stream_plan.k,
                                     stream_plan.row_to_vertex, s_k, s_v)
 
+    def run(self, bundle, request, entry_labels, entry_weights, labels):
+        """:meth:`FoldEngine.run`; a ``marks`` request (packed round-0
+        labels of an aligned plan, DESIGN.md §8.5) also returns each
+        vertex's round-0 mark (``FoldOutcome.marked``)."""
+        if not request.marks:
+            return super().run(bundle, request, entry_labels, entry_weights,
+                               labels)
+        want, marked = self.mg_select(bundle.plan, bundle.aux_for(self),
+                                      entry_labels, entry_weights, labels,
+                                      request.seed, marks=True)
+        return FoldOutcome(want=want, marked=marked)
+
     def mg_select(self, plan, stream_plan, entry_labels, entry_weights,
-                  labels, seed, *, selection=None):
+                  labels, seed, *, selection=None, marks=False):
         from repro.kernels.mg_sketch.streaming import select_best_stream
         _require_plan(stream_plan, 'pallas_stream', 'StreamedFoldPlan')
         return select_best_stream(stream_plan, entry_labels, entry_weights,
-                                  labels, seed, selection=selection)
+                                  labels, seed, selection=selection,
+                                  marks=marks)
 
     def mg_rescan(self, plan, stream_plan, entry_labels, entry_weights,
                   labels, seed, *, selection=None):

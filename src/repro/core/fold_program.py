@@ -65,6 +65,9 @@ class FoldRequest:
     frontier: Optional[Any] = None
     cap_rows: int = 0  # sparse compaction capacity (static): max active
     # rows/windows the compacted fold may touch
+    marks: bool = False  # round-0 entry labels carry each neighbor's
+    # "changed" bit (fused.pack_marks): the round-0 kernel strips it and
+    # ORs it per row (FoldOutcome.marked; DESIGN.md §8.5)
 
     def __post_init__(self):
         if self.family not in FAMILIES:
@@ -83,6 +86,11 @@ class FoldRequest:
             raise ValueError(
                 "sparse mode needs a frontier (the compacted fold is "
                 "defined by the active vertex set)")
+        if self.marks and (self.family != "mg" or self.rescan
+                           or self.mode != "dense" or not self.aligned):
+            raise ValueError(
+                "marks=True is the dense MG fold of an aligned round 0 "
+                "(the only kernel that reads every row's packed labels)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,3 +124,6 @@ class FoldOutcome:
     bm_label: Optional[Any] = None
     # BM only: surviving candidate weight — [N] float32
     bm_weight: Optional[Any] = None
+    # marks requests only: some neighbor's packed "changed" bit was set
+    # — [N] bool
+    marked: Optional[Any] = None

@@ -59,6 +59,7 @@ from repro.core.fold_engine import get_engine
 from repro.core.fold_program import FoldRequest
 from repro.core.plan_bundle import PlanBundle, build_plan_bundle, spec_for
 from repro.graphs.csr import CSRGraph
+from repro.kernels.mg_sketch.fused import pack_marks
 
 # core.distributed imports LPAResult from here, so the sharded path
 # imports it where it runs
@@ -111,11 +112,13 @@ class LPAConfig:
     frontier_cap_rows: Optional[int] = None
     # frontier_history diagnostics (the per-iteration frontier fraction).
     # Deliberately decoupled from gating: frontier_gate computes the marks
-    # it needs (one O(|E|) segment_max per iteration) whether or not this
-    # is set, and track_frontier=False then only skips recording the
-    # history — it does NOT silently re-enable anything. With both
-    # frontier_gate and track_frontier off, mark_frontier is never called
-    # and no segment_max is paid (asserted in tests/test_sparse_frontier).
+    # it needs whether or not this is set, and track_frontier=False then
+    # only skips recording the history — it does NOT silently re-enable
+    # anything. The marks come from the mover's round-0 pass on an aligned
+    # streamed MG plan (marks_in_mover), else from one O(|E|) segment_max
+    # an iteration (mark_frontier). With both frontier_gate and
+    # track_frontier off nothing marks the frontier (asserted in
+    # tests/test_sparse_frontier).
     track_frontier: bool = True
     # > 1: split the vertices into this many edge-balanced shards, one a
     # device (core.distributed): build_workspace places them on a
@@ -197,10 +200,44 @@ def _check_sharded(config: LPAConfig) -> None:
                          f"devices; JAX finds {len(jax.devices())}")
 
 
+def marks_in_mover(ws: LPAWorkspace, config: LPAConfig) -> bool:
+    """Whether the mover marks the frontier itself (``lpa_move(marks=)``,
+    DESIGN.md §8.5): the dense MG fold of an aligned streamed plan, whose
+    round-0 kernel reads every vertex's whole row of gathered neighbour
+    labels. Elsewhere ``lpa()`` runs :func:`mark_frontier`; the sparse
+    fold needs the frontier on the host before it dispatches."""
+    engine = get_engine(ws.bundle.spec.backend, checked=False)
+    aux = ws.bundle.aux_for(engine)
+    return (config.method == "mg" and not config.rescan
+            and not config.frontier_sparse and engine.uses_stream_plan
+            and aux is not None and aux.aligned)
+
+
+@dataclasses.dataclass
+class MoverMarks:
+    """The frontier the mover marks itself (:func:`marks_in_mover`). In:
+    the previous move's ``changed`` mask, frontier and Pick-Less flag.
+    Out: ``entering``, the frontier entering this move, which
+    :func:`lpa_move` sets."""
+
+    changed: jnp.ndarray  # [N] bool: the previous move's changed mask
+    frontier: jnp.ndarray  # [N] bool: the frontier entering that move
+    pick_less: jnp.ndarray  # bool scalar: that move was a Pick-Less one
+    #: [N] bool: the frontier entering this move (lpa_move sets it)
+    entering: Optional[jnp.ndarray] = None
+
+    def enter(self, marked: jnp.ndarray) -> jnp.ndarray:
+        """The frontier entering this move from the neighbours of the
+        vertices that changed: after a Pick-Less move, which defers legal
+        moves, the previous frontier stays queued (§8.5)."""
+        return jnp.where(self.pick_less, self.frontier | marked, marked)
+
+
 def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
              seed: jnp.ndarray, config: LPAConfig,
              frontier: Optional[jnp.ndarray] = None, sparse: bool = False,
-             cap_rows: int = 0) -> tuple[jnp.ndarray, jnp.ndarray]:
+             cap_rows: int = 0, marks: Optional[MoverMarks] = None
+             ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One LPA iteration: returns (new_labels, changed_mask).
 
     ``pick_less`` and ``seed`` are traced so the jitted step is reused
@@ -215,6 +252,13 @@ def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
     dense on overflow). Sparse wanted labels are bit-identical to dense
     ones on frontier vertices and the gate masks the rest, so the two
     request modes commute.
+
+    ``marks`` makes the mover mark the frontier itself (where
+    :func:`marks_in_mover` holds, in place of ``frontier``): the round-0
+    gather reads each neighbour's ``changed`` bit with its label, the
+    round-0 kernel ORs it over each row, and scope ``lpa.marks`` ORs each
+    vertex's rows into ``marks.entering``, which gates the move under
+    ``config.frontier_gate``.
     """
     graph, bundle = ws.graph, ws.bundle
     if sparse and frontier is None:
@@ -237,6 +281,13 @@ def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
             # n_nodes pad sentinel.
             labels_ext = jnp.concatenate([labels,
                                           jnp.full((1,), -1, labels.dtype)])
+            if marks is not None:
+                # each neighbour's changed bit rides with its label; packed
+                # after the pad slot is appended, so XLA makes one n + 1
+                # buffer of both (packing first cost the 2^20-vertex k-mer
+                # mover 17 MB more HBM on v5e, compiled ahead of time)
+                labels_ext = pack_marks(labels_ext, jnp.concatenate(
+                    [marks.changed, jnp.zeros((1,), jnp.bool_)]))
             nbr_labels = labels_ext[aux.aligned_entry_vertex]
             nbr_weights = aux.aligned_entry_weights
         else:
@@ -260,11 +311,19 @@ def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
                          and aux.aligned),
             seed=seed,
             frontier=frontier if sparse else None,
-            cap_rows=cap_rows if sparse else 0)
-        want = engine.run(bundle, request, nbr_labels, nbr_weights,
-                          labels).want
+            cap_rows=cap_rows if sparse else 0,
+            marks=marks is not None)
+        outcome = engine.run(bundle, request, nbr_labels, nbr_weights,
+                             labels)
+        want = outcome.want
     else:
         raise ValueError(f"unknown method {config.method!r}")
+
+    if marks is not None:
+        with jax.named_scope("lpa.marks"):
+            marks.entering = marks.enter(outcome.marked)
+        if config.frontier_gate:
+            frontier = marks.entering
 
     with jax.named_scope("lpa.apply"):
         allowed = jnp.where(pick_less, want < labels, want != labels)
@@ -275,10 +334,31 @@ def lpa_move(ws: LPAWorkspace, labels: jnp.ndarray, pick_less: jnp.ndarray,
     return new_labels, changed
 
 
-def mover(config: LPAConfig, cap_rows: int) -> Callable:
+def mover(config: LPAConfig, cap_rows: int, marks: bool = False
+          ) -> Callable:
     """``lpa_move`` bound to ``config`` and ``cap_rows`` as ``lpa()`` calls
-    it; jitted (``sparse`` static), its XLA module is ``jit_lpa_move``."""
-    move = functools.partial(lpa_move, config=config, cap_rows=cap_rows)
+    it; jitted (``sparse`` static), its XLA module is ``jit_lpa_move``.
+
+    ``marks`` (where :func:`marks_in_mover` holds): the mover takes the
+    previous move's ``last`` = (changed, frontier) and ``last_pick_less``
+    in place of a frontier, and returns (new_labels, changed, frontier
+    entering the move); ``lpa()`` jits it with ``last`` donated. A
+    ``lpa_move`` that leaves the marks unset (one wrapped or replaced,
+    keeping only the (new_labels, changed) contract) gets
+    :func:`mark_frontier`'s marks in the same program.
+    """
+    if not marks:
+        move = functools.partial(lpa_move, config=config, cap_rows=cap_rows)
+    else:
+        def move(ws, labels, pick_less, seed, last, last_pick_less):
+            carry = MoverMarks(*last, last_pick_less)
+            new_labels, changed = lpa_move(ws, labels, pick_less, seed,
+                                           config, cap_rows=cap_rows,
+                                           marks=carry)
+            if carry.entering is None:
+                carry.entering = carry.enter(mark_frontier(ws,
+                                                           carry.changed))
+            return new_labels, changed, carry.entering
     move.__name__ = "lpa_move"
     return move
 
@@ -314,6 +394,10 @@ class LPAResult:
     #: per iteration for the convergence delta, one more each for the
     #: frontier fraction (track_frontier) and the sparse fit check
     host_syncs: int = 0
+    #: iterations whose frontier the mover marked from its round-0 gather
+    #: (``marks_in_mover``; every one after the first, whose frontier is
+    #: all ones); 0 where ``mark_frontier`` marks it or nothing does
+    mover_marks: int = 0
 
 
 def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
@@ -339,7 +423,9 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
                              "method (no fold plan to compact)")
     ws = ws if ws is not None else build_workspace(graph, config)
     cap_rows = ws.bundle.cap_rows()
-    move = mover(config, cap_rows)
+    need_marks = config.frontier_gate or config.track_frontier
+    in_mover = need_marks and marks_in_mover(ws, config)
+    move = mover(config, cap_rows, marks=in_mover)
     frontier_fn = mark_frontier
     if jit:
         # ONE mover; the dense/sparse choice is a static argument decided
@@ -347,10 +433,15 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
         # iterations), never a traced branch — the overflow fallback is a
         # request swap between two cached specializations of the same
         # artifact.
-        move = jax.jit(move, static_argnames=("sparse",))
+        # A mover that marks takes the previous changed mask and frontier
+        # (last=) and donates them: its new ones take their buffers.
+        move = (jax.jit(move, donate_argnames=("last",)) if in_mover
+                else jax.jit(move, static_argnames=("sparse",)))
         frontier_fn = jax.jit(mark_frontier)
     n = graph.n_nodes
-    need_marks = config.frontier_gate or config.track_frontier
+    marks_by = ("mover" if in_mover else
+                "segment_max" if need_marks else "none")
+    mover_marks = 0
     history = []
     frontier_history = []
     work_rows_history = []
@@ -361,9 +452,13 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
     with obs.span("lpa.solve"):
         labels = jnp.arange(n, dtype=jnp.int32)
         frontier = jnp.ones((n,), dtype=jnp.bool_)  # every vertex queued
+        # the mover marks iteration 0's frontier from no vertex changed and
+        # the all-ones frontier kept, as after a Pick-Less pass
+        changed = jnp.zeros((n,), dtype=jnp.bool_) if in_mover else None
+        pl = True
         for it in range(config.max_iters):
-            with obs.span("lpa.iteration", it=it):
-                pl = (it % config.rho) == 0
+            with obs.span("lpa.iteration", it=it, marks=marks_by):
+                last_pl, pl = pl, (it % config.rho) == 0
                 gate = frontier if config.frontier_gate else None
                 sparse = False
                 work = dense_rows
@@ -376,15 +471,21 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
                         sparse, work = True, sparse_work
                 with obs.span("lpa.dispatch.move"):
                     seed = jnp.int32(it + 1)
-                    labels, changed = move(ws, labels, jnp.asarray(pl),
-                                           seed, frontier=gate,
-                                           sparse=sparse)
+                    if in_mover:
+                        labels, changed, frontier = move(
+                            ws, labels, jnp.asarray(pl), seed,
+                            last=(changed, frontier),
+                            last_pick_less=jnp.asarray(last_pl))
+                        mover_marks += it > 0
+                    else:
+                        labels, changed = move(ws, labels, jnp.asarray(pl),
+                                               seed, frontier=gate,
+                                               sparse=sparse)
                 work_rows_history.append(work)
-                if need_marks:
-                    if config.track_frontier:
-                        with obs.sync("frontier_mean", count):
-                            frontier_history.append(
-                                float(jnp.mean(frontier)))
+                if config.track_frontier:
+                    with obs.sync("frontier_mean", count):
+                        frontier_history.append(float(jnp.mean(frontier)))
+                if need_marks and not in_mover:
                     with obs.span("lpa.dispatch.frontier"):
                         marked = frontier_fn(ws, changed)
                         # A Pick-Less round blocks legal moves (want >
@@ -402,7 +503,7 @@ def lpa(graph: CSRGraph, config: Optional[LPAConfig] = None,
                      changed_history=history, converged=converged,
                      frontier_history=frontier_history,
                      work_rows_history=work_rows_history,
-                     host_syncs=count.host_syncs)
+                     host_syncs=count.host_syncs, mover_marks=mover_marks)
 
 
 def _lpa_sharded(graph: CSRGraph, config: LPAConfig,

@@ -7,7 +7,9 @@ and no exporter of its own — the profiler is the exporter. Names:
 
 * host spans (``lpa()``, and the sharded loop ``dist_solve`` that
   ``lpa(LPAConfig(shards > 1))`` and ``dist_lpa()`` run): ``lpa.solve``
-  around a call, ``lpa.iteration`` (``it=``) around each pass,
+  around a call, ``lpa.iteration`` (``it=``, and ``marks=``: ``mover``
+  where the mover marks the frontier, ``segment_max`` where
+  ``mark_frontier`` does, ``none``) around each pass,
   ``lpa.dispatch.move`` and
   ``lpa.dispatch.frontier`` around the jitted calls and the small
   programs that feed them, ``lpa.sync.<what>`` around each
@@ -15,7 +17,8 @@ and no exporter of its own — the profiler is the exporter. Names:
 * device scopes (``jax.named_scope``, in the ops' ``op_name``):
   ``lpa.gather`` (round-0 neighbour labels), ``lpa.relayout`` (window
   re-layout between rounds), ``lpa.fold.r<i>`` (round ``i``'s kernel
-  launch), ``lpa.apply`` (the winner scatter and the move epilogue) and
+  launch), ``lpa.marks`` (the frontier from the round-0 kernel's marks),
+  ``lpa.apply`` (the winner scatter and the move epilogue) and
   ``lpa.exchange`` (``dist_lpa``'s collectives); the Pallas kernels are
   named ``lpa_fold``, ``lpa_select``, ``lpa_bm_fold`` and ``lpa_rescan``.
 """

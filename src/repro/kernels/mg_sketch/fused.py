@@ -75,6 +75,7 @@ _HASH_MUL = np.int32(2654435761 - 2**32)
 _HASH_SEED_MUL = np.int32(0x9E3779B9 - 2**32)
 _HASH_FIN_MUL = np.int32(0x85EBCA77 - 2**32)
 _SIGN_BIT = np.int32(-2**31)
+_LABEL_MASK = np.int32(2**31 - 1)  # clears the sign bit pack_marks sets
 
 
 def _interpret_default() -> bool:
@@ -145,6 +146,32 @@ def _column(tile_l_ref, tile_w_ref, counts, i):
     live = i < counts
     return (jnp.where(live, tile_l_ref[pl.ds(i, 1), :], -1),
             jnp.where(live, tile_w_ref[pl.ds(i, 1), :], 0.0))
+
+
+def pack_marks(labels: jnp.ndarray, changed: jnp.ndarray) -> jnp.ndarray:
+    """Labels with each vertex's ``changed`` bit in the sign bit (DESIGN.md
+    §8.5): what the aligned round-0 gather reads when the mover marks the
+    frontier. Labels lie in ``[0, n)`` with ``n < 2**31 - 1``, so a packed
+    label is never the pad -1; :func:`unpack_marks` decodes it."""
+    return jnp.where(changed, labels | _SIGN_BIT, labels)
+
+
+def unpack_marks(packed: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The inverse of :func:`pack_marks`: (labels, bits); a pad (-1) stays
+    -1 and carries no bit."""
+    pad = packed == -1
+    return (jnp.where(pad, packed, packed & _LABEL_MASK),
+            (packed < 0) & ~pad)
+
+
+def _strip_marks(tile_l_ref, counts):
+    """Each row's frontier mark: the OR of the packed ``changed`` bit
+    over the row's live entries ([1, tile_r] int32). Clears the bits in
+    the tile (:func:`unpack_marks`), so the fold reads the plain labels."""
+    tile, bit = unpack_marks(tile_l_ref[...])
+    tile_l_ref[...] = tile
+    live = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0) < counts
+    return jnp.max((live & bit).astype(jnp.int32), axis=0, keepdims=True)
 
 
 def _any(mask):
@@ -284,6 +311,17 @@ def _fold_kernel(dmax_ref, start_ref, count_ref, lab_ref, wgt_ref,
                                               dmax_ref[0, 0])
 
 
+def _fold_marks_kernel(dmax_ref, start_ref, count_ref, lab_ref, wgt_ref,
+                       out_k_ref, out_v_ref, out_m_ref, *scratch, k: int):
+    """:func:`_fold_kernel` on packed labels (an aligned round 0 that marks
+    the frontier): also writes each row's mark (:func:`_strip_marks`)."""
+    _gather_tile(start_ref, lab_ref, wgt_ref, *scratch)
+    out_m_ref[...] = _strip_marks(scratch[2], count_ref[...])
+    out_k_ref[...], out_v_ref[...] = _mg_fold(scratch[2], scratch[3],
+                                              count_ref[...], k,
+                                              dmax_ref[0, 0])
+
+
 def _select_kernel(dmax_ref, start_ref, count_ref, inc_ref, seed_ref,
                    lab_ref, wgt_ref, out_c_ref, *scratch, k: int):
     """Final-round fold + move selection in one step. The final round has
@@ -292,6 +330,20 @@ def _select_kernel(dmax_ref, start_ref, count_ref, inc_ref, seed_ref,
     s_k, s_v = _mg_fold(scratch[2], scratch[3], count_ref[...], k,
                         dmax_ref[0, 0])
     out_c_ref[...] = _select_rows(s_k, s_v, inc_ref[...], seed_ref[0, 0])
+
+
+def _select_marks_kernel(dmax_ref, start_ref, count_ref, inc_ref, seed_ref,
+                         lab_ref, wgt_ref, out_c_ref, *scratch, k: int):
+    """:func:`_select_kernel` on packed labels (a single-round aligned
+    plan that marks the frontier): each row's mark rides in the sign bit
+    of its choice (:func:`pack_marks`; a pad row's -1 has no mark), so the
+    marks cost no output of their own."""
+    _gather_tile(start_ref, lab_ref, wgt_ref, *scratch)
+    mark = _strip_marks(scratch[2], count_ref[...])
+    s_k, s_v = _mg_fold(scratch[2], scratch[3], count_ref[...], k,
+                        dmax_ref[0, 0])
+    choice = _select_rows(s_k, s_v, inc_ref[...], seed_ref[0, 0])
+    out_c_ref[...] = pack_marks(choice, mark > 0)
 
 
 def _bm_kernel(dmax_ref, start_ref, count_ref, init_ref, lab_ref, wgt_ref,
@@ -313,8 +365,11 @@ def _rescan_kernel(dmax_ref, start_ref, count_ref, cand_ref, lab_ref,
 
 
 #: Stable kernel names (``pallas_call(name=...)``): the TPU custom call
-#: and its profiler events carry them.
+#: and its profiler events carry them. A kernel that also marks the
+#: frontier keeps the name of the fold it runs.
 KERNEL_NAMES = {_fold_kernel: "lpa_fold", _select_kernel: "lpa_select",
+                _fold_marks_kernel: "lpa_fold",
+                _select_marks_kernel: "lpa_select",
                 _bm_kernel: "lpa_bm_fold", _rescan_kernel: "lpa_rescan"}
 
 

@@ -50,12 +50,11 @@ from jax.experimental import pallas as pl
 
 from repro.graphs.csr import (StreamedFoldPlan, StreamedRound,
                               compact_active_rows)
-from repro.kernels.mg_sketch.fused import (LANES, _bm_kernel, _fold_kernel,
-                                           _lane_groups, _rescan_kernel,
-                                           _round_call, _select_kernel,
-                                           fold_scope, rescan_select_generic,
-                                           row_major, run_bm_plan_generic,
-                                           slot_major)
+from repro.kernels.mg_sketch.fused import (
+    LANES, _bm_kernel, _fold_kernel, _fold_marks_kernel, _lane_groups,
+    _rescan_kernel, _round_call, _select_kernel, _select_marks_kernel,
+    fold_scope, rescan_select_generic, row_major, run_bm_plan_generic,
+    slot_major, unpack_marks)
 
 
 def windowed_entries(gather: jnp.ndarray, entry_labels: jnp.ndarray,
@@ -120,8 +119,8 @@ def _stream_round(kernel, rnd: StreamedRound, extras, entry_labels,
 
 def stream_fold_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
                       entry_weights: jnp.ndarray, *, k: int, chunk: int,
-                      interpret: bool | None = None, index: int = 0
-                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                      interpret: bool | None = None, index: int = 0,
+                      marks: bool = False):
     """One streamed dispatch: grid over windows, one W-entry window resident
     per step. ``index`` is the plan's round number, which names the
     launch's device scope.
@@ -133,31 +132,40 @@ def stream_fold_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
     flattened padded [n_windows * tile_r * k] sketches).
     Returns padded ([n_windows * tile_r, k] int32, [..., k] float32)
     sketches in window-slot order (pad rows fold to empty sketches).
+    ``marks`` (static): the labels are packed (``fused.pack_marks``), and
+    each row slot's frontier mark [n_windows * tile_r] int32 is returned
+    third.
     """
     block = (k, rnd.tile_r)
-    s_k, s_v = _stream_round(
-        functools.partial(_fold_kernel, k=k), rnd, (), entry_labels,
-        entry_weights, [(jnp.int32, block), (jnp.float32, block)],
-        chunk=chunk, interpret=interpret, index=index)
+    kernel = _fold_marks_kernel if marks else _fold_kernel
+    outs = [(jnp.int32, block), (jnp.float32, block)]
+    outs += [(jnp.int32, (1, rnd.tile_r))] if marks else []
+    s_k, s_v, *row_marks = _stream_round(
+        functools.partial(kernel, k=k), rnd, (), entry_labels,
+        entry_weights, outs, chunk=chunk, interpret=interpret, index=index)
     with fold_scope(index):
-        return row_major(s_k), row_major(s_v)
+        return (row_major(s_k), row_major(s_v),
+                *[m.reshape(-1) for m in row_marks])
 
 
 def stream_select_round(rnd: StreamedRound, entry_labels: jnp.ndarray,
                         entry_weights: jnp.ndarray, incumbents: jnp.ndarray,
                         seed: jnp.ndarray, *, k: int, chunk: int,
                         interpret: bool | None = None,
-                        index: int = 0) -> jnp.ndarray:
+                        index: int = 0, marks: bool = False):
     """Final-round (round ``index``) streamed dispatch: fold + per-row
     winning label.
 
     ``incumbents`` [n_windows * tile_r] int32 carries each row slot's
     current vertex label (-1 on pad slots). Returns the chosen label per
-    row slot [n_windows * tile_r] int32.
+    row slot [n_windows * tile_r] int32; with ``marks`` (packed labels, a
+    single-round plan), each row's frontier mark rides in its choice's
+    sign bit (``fused.pack_marks``).
     """
     row = (1, rnd.tile_r)
+    kernel = _select_marks_kernel if marks else _select_kernel
     (out,) = _stream_round(
-        functools.partial(_select_kernel, k=k), rnd,
+        functools.partial(kernel, k=k), rnd,
         [(incumbents, row), (seed.astype(jnp.int32).reshape(1, 1), None)],
         entry_labels, entry_weights, [(jnp.int32, row)], chunk=chunk,
         interpret=interpret, index=index)
@@ -207,7 +215,7 @@ def run_mg_plan_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
 def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
                        entry_weights: jnp.ndarray, labels: jnp.ndarray,
                        seed: jnp.ndarray, interpret: bool | None = None,
-                       *, selection=None) -> jnp.ndarray:
+                       *, selection=None, marks: bool = False):
     """Full streamed MG iteration: ``n_rounds`` dispatches, the last fused
     with move selection. Bit-identical to ``run_mg_plan`` + ``select_best``
     on the reference backend (and to ``fused.select_best_fused``).
@@ -220,16 +228,26 @@ def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
     wanted labels may differ (inactive rows sharing an active window
     compute, others carry through) — the frontier gate masks both, exactly
     as it masks the dense mover's off-frontier moves.
+
+    ``marks`` (dense; an aligned plan whose round-0 labels are packed by
+    ``fused.pack_marks``): the round-0 kernel strips the bits before it
+    folds and ORs them over each row; device scope ``lpa.marks`` ORs the
+    rows of each vertex, and the result is the pair (wanted labels, [N]
+    bool: some neighbour's bit was set). A single-round plan's rows are
+    its vertices, and their marks ride in the choices' sign bits.
     """
     if plan.n_nodes == 0:
-        return labels
+        return (labels, jnp.zeros((0,), jnp.bool_)) if marks else labels
     el, ew = entry_labels, entry_weights
+    row_marks = None
     if selection is None:
         for i, rnd in enumerate(plan.rounds[:-1]):
-            s_k, s_v = stream_fold_round(rnd, el, ew, k=plan.k,
-                                         chunk=plan.chunk,
-                                         interpret=interpret, index=i)
+            s_k, s_v, *m = stream_fold_round(rnd, el, ew, k=plan.k,
+                                             chunk=plan.chunk,
+                                             interpret=interpret, index=i,
+                                             marks=marks and i == 0)
             el, ew = s_k.reshape(-1), s_v.reshape(-1)
+            row_marks = m[0] if m else row_marks
         last, rv = plan.rounds[-1], plan.row_to_vertex
     else:
         for i, rnd in enumerate(plan.rounds[:-1]):
@@ -254,7 +272,8 @@ def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
     choice = stream_select_round(last, el, ew, incumbents, seed,
                                  k=plan.k, chunk=plan.chunk,
                                  interpret=interpret,
-                                 index=len(plan.rounds) - 1)
+                                 index=len(plan.rounds) - 1,
+                                 marks=marks and plan.n_rounds == 1)
     # [N] scatter of per-row winners (pad/sentinel rows land in the dump
     # slot); degree-0 (or off-frontier) vertices keep their label —
     # identical to choose_from_candidates with an empty candidate set.
@@ -262,7 +281,15 @@ def select_best_stream(plan: StreamedFoldPlan, entry_labels: jnp.ndarray,
         buf = jnp.concatenate([labels, jnp.zeros((1,), labels.dtype)])
         buf = buf.at[jnp.where(real, rv, n)].set(
             jnp.where(real, choice, -1))
-        return buf[:n]
+        want = buf[:n]
+    if not marks:
+        return want
+    with jax.named_scope("lpa.marks"):
+        if row_marks is None:  # one round: the marks are the sign bits
+            return unpack_marks(want)
+        marked = jnp.zeros((n,), jnp.bool_).at[plan.row_to_vertex0].max(
+            row_marks > 0, mode="drop", wrap_negative_indices=False)
+        return want, marked
 
 
 # ---------------------------------------------------------------------------

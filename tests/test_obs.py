@@ -78,6 +78,30 @@ def test_solve_spans_nest_on_the_host_plane(graph, backend, tmp_path):
             assert max(cover, key=lambda s: s[1])[0] == span, program
 
 
+def test_aligned_mover_marks_the_frontier_in_its_own_module(graph,
+                                                            tmp_path):
+    """On the aligned streamed path the mover marks the frontier: no
+    ``jit_mark_frontier`` module and no ``lpa.dispatch.frontier`` span
+    run, each ``lpa.iteration`` says ``marks=mover``, and the host still
+    reads the frontier fraction and the moved count once an iteration."""
+    cfg = LPAConfig(**STREAM, aligned_layout=True)
+    ws = build_workspace(graph, cfg)
+    lpa(graph, cfg, ws=ws)  # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        res = lpa(graph, cfg, ws=ws)
+    events = host_events(str(tmp_path))
+    names = [e.name for e in events]
+    modules = {v for e in events for k, v in e.stats if k == "hlo_module"}
+    assert "jit_lpa_move" in modules and "jit_mark_frontier" not in modules
+    assert "lpa.dispatch.frontier" not in names
+    iters = [e for e in events if e.name == "lpa.iteration"]
+    assert len(iters) == res.iterations > 1
+    assert all(("marks", "mover") in e.stats for e in iters)
+    assert names.count("lpa.sync.frontier_mean") == res.iterations
+    assert res.host_syncs == 2 * res.iterations
+    assert res.mover_marks == res.iterations - 1
+
+
 @pytest.mark.parametrize("cfg,per_iter", [
     ({}, 2),                                             # delta + frontier
     ({"track_frontier": False}, 1),                      # delta only

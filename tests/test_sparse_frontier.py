@@ -18,6 +18,12 @@ Contracts under test (DESIGN.md §8.5):
     changing a count (kernelcheck R3 verifies the same statically).
   * **decoupling** — with ``frontier_gate`` and ``track_frontier`` both
     off, ``mark_frontier`` (the O(|E|) segment_max) is never called.
+  * **marks from the mover** — on the dense MG fold of an aligned
+    streamed plan the mover marks the frontier from its round-0 gather
+    (``lpa_move(marks=)``): labels, moved counts and the frontier history
+    are identical to ``mark_frontier``'s, ``mark_frontier`` is never
+    called there, and every other path still calls it. ``lpa_move`` keeps
+    its (new_labels, changed) contract there, so a wrapper of it runs.
 """
 import importlib
 
@@ -37,7 +43,7 @@ from repro.graphs.csr import (CSRGraph, build_csr, compact_active_rows,
                               fused_active_rows, fused_dispatches,
                               plan_dispatches, plan_round0_dispatches,
                               streamed_dispatches)
-from repro.graphs.generators import sbm
+from repro.graphs.generators import chain_kmer, rmat, sbm
 
 BACKENDS = ("jnp", "pallas", "pallas_fused", "pallas_stream")
 SPARSE_BACKENDS = ("pallas_fused", "pallas_stream")  # the ones that skip rows
@@ -352,3 +358,150 @@ def test_mark_frontier_only_called_when_needed(monkeypatch):
                            track_frontier=True), jit=False)
     assert len(calls) > 0
     assert len(res.frontier_history) == res.iterations
+
+
+# ---------------------------------------------------------------------------
+# frontier marks from the mover's round-0 pass (DESIGN.md §8.5)
+# ---------------------------------------------------------------------------
+
+def _weighted_rmat():
+    """A weighted R-MAT whose hub rows span several 16-entry chunks: hub
+    vertices own several round-0 rows, and the plan has merge rounds."""
+    g0 = rmat(8, edge_factor=8, seed=5)
+    src, dst = np.asarray(g0.sources()), np.asarray(g0.indices)
+    e = np.stack([src[src < dst], dst[src < dst]], 1)
+    w = np.random.default_rng(5).random(len(e)).astype(np.float32)
+    return build_csr(e, g0.n_nodes, weights=w)
+
+
+MARK_GRAPHS = {"rmat_hubs": _weighted_rmat,
+               "kmer_chain": lambda: chain_kmer(600, branch_prob=0.05,
+                                                seed=3)}
+# rho=3 and tau=0: every run crosses the Pick-Less iterations 3 and 6
+MARK_BASE = dict(method="mg", k=4, chunk=16, rho=3, tau=0.0, max_iters=8,
+                 fold_backend="pallas_stream", stream_window=256,
+                 aligned_layout=True)
+
+
+def _counting_mark_frontier(monkeypatch):
+    calls = []
+
+    def counting(ws, changed):
+        calls.append(1)
+        return mark_frontier(ws, changed)
+
+    monkeypatch.setattr(lpa_mod, "mark_frontier", counting)
+    return calls
+
+
+@pytest.mark.parametrize("mode", [
+    {}, {"frontier_gate": True},
+    {"frontier_gate": True, "track_frontier": False}],
+    ids=["tracked", "gated", "gated-untracked"])
+@pytest.mark.parametrize("name", sorted(MARK_GRAPHS))
+def test_mover_marks_match_mark_frontier(monkeypatch, name, mode):
+    """The same aligned workspace solved twice: marks from the mover's
+    round-0 pass, then (``marks_in_mover`` patched off) from
+    ``mark_frontier``. Labels, moved counts, the frontier entering every
+    iteration and, under the gate, every gated move agree exactly."""
+    g = MARK_GRAPHS[name]()
+    cfg = LPAConfig(**MARK_BASE, **mode)
+    ws = build_workspace(g, cfg)
+    if name == "rmat_hubs":
+        rv0 = np.asarray(ws.stream_plan.row_to_vertex0)
+        assert np.bincount(rv0[rv0 >= 0]).max() > 1  # hub rows split
+        assert ws.stream_plan.n_rounds > 1
+    else:
+        assert ws.stream_plan.n_rounds == 1  # the fused selection marks
+    assert lpa_mod.marks_in_mover(ws, cfg)
+    calls = _counting_mark_frontier(monkeypatch)
+    got = lpa(g, cfg, ws=ws)
+    assert calls == []
+    monkeypatch.setattr(lpa_mod, "marks_in_mover", lambda ws, config: False)
+    ref = lpa(g, cfg, ws=ws)
+    assert calls
+    assert ref.iterations == got.iterations > 2 * cfg.rho
+    np.testing.assert_array_equal(np.asarray(got.labels),
+                                  np.asarray(ref.labels))
+    assert got.changed_history == ref.changed_history
+    assert got.frontier_history == ref.frontier_history
+    assert len(got.frontier_history) == (
+        got.iterations if cfg.track_frontier else 0)
+    if cfg.track_frontier:  # the marks thin the frontier, not all ones
+        assert min(got.frontier_history) < 1.0
+    assert got.mover_marks == got.iterations - 1
+    assert ref.mover_marks == 0
+    assert got.host_syncs == ref.host_syncs
+
+
+@pytest.mark.parametrize("change", [
+    {"fold_backend": "jnp"}, {"fold_backend": "pallas_fused"},
+    {"aligned_layout": False}, {"method": "bm"}, {"rescan": True},
+    {"frontier_gate": True, "frontier_sparse": True,
+     "frontier_cap_rows": 10**9}],
+    ids=["jnp", "pallas_fused", "unaligned", "bm", "rescan", "sparse"])
+def test_mover_marks_fallbacks_keep_mark_frontier(monkeypatch, change):
+    """Every path but the dense MG fold of an aligned streamed plan keeps
+    ``mark_frontier``, and counts no mover marks."""
+    g = _graph()
+    cfg = LPAConfig(**{**MARK_BASE, "max_iters": 4, **change})
+    calls = _counting_mark_frontier(monkeypatch)
+    res = lpa(g, cfg)
+    assert calls
+    assert res.mover_marks == 0
+    assert len(res.frontier_history) == res.iterations
+
+
+def _moves_nothing(orig):
+    def move(ws, labels, *a, **k):
+        return labels, jnp.zeros(labels.shape, bool)
+    return move
+
+
+def _passes_through(orig):
+    def move(ws, labels, *a, **k):
+        new, changed = orig(ws, labels, *a, **k)
+        return new, changed
+    return move
+
+
+@pytest.mark.parametrize("wrap", [_passes_through, _moves_nothing],
+                         ids=["passes-through", "moves-nothing"])
+def test_marking_mover_keeps_its_two_output_contract(monkeypatch, wrap):
+    """On the marking path ``lpa()`` still calls ``lpa_move`` for
+    (new_labels, changed), so a wrapper of it runs there: one that passes
+    the call on leaves the solve and its marks as they were; one that
+    marks nothing gets ``mark_frontier``'s marks in the same program."""
+    g = _graph()
+    cfg = LPAConfig(**{**MARK_BASE, "max_iters": 4})
+    ws = build_workspace(g, cfg)
+    assert lpa_mod.marks_in_mover(ws, cfg)
+    want = lpa(g, cfg, ws=ws)
+    calls = _counting_mark_frontier(monkeypatch)
+    monkeypatch.setattr(lpa_mod, "lpa_move", wrap(lpa_mod.lpa_move))
+    got = lpa(g, cfg, ws=ws)
+    if wrap is _passes_through:
+        assert calls == []
+        np.testing.assert_array_equal(np.asarray(got.labels),
+                                      np.asarray(want.labels))
+        assert got.changed_history == want.changed_history
+        assert got.frontier_history == want.frontier_history
+    else:
+        assert calls
+        np.testing.assert_array_equal(np.asarray(got.labels),
+                                      np.arange(g.n_nodes))
+        # nothing moves: the Pick-Less iteration 0 keeps the first two
+        # frontiers whole, then nothing is marked
+        assert got.changed_history == [0, 0, 0, 0]
+        assert got.frontier_history == [1.0, 1.0, 0.0, 0.0]
+
+
+def test_mover_marks_nothing_when_nothing_needs_marks(monkeypatch):
+    """Neither the gate nor the history: the aligned mover packs no bits
+    and nothing marks the frontier."""
+    g = _graph()
+    cfg = LPAConfig(**{**MARK_BASE, "max_iters": 4, "track_frontier": False})
+    calls = _counting_mark_frontier(monkeypatch)
+    res = lpa(g, cfg)
+    assert calls == []
+    assert res.mover_marks == 0 and res.frontier_history == []

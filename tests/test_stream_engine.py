@@ -14,6 +14,7 @@ VMEM-cap item demanded.
 import zlib
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -30,8 +31,10 @@ from repro.graphs.csr import (_min_stream_stride, _pack_stream_windows,
                               streamed_peak_window_bytes,
                               streamed_window_slots)
 from repro.graphs.generators import chain_kmer, powerlaw_communities
+from repro.kernels.mg_sketch import fused
 from repro.kernels.mg_sketch.streaming import (run_mg_plan_stream,
                                                select_best_stream,
+                                               stream_fold_round,
                                                windowed_entries)
 
 
@@ -404,6 +407,107 @@ def test_aligned_sketch_variants_bit_parity(method, rescan):
         assert ref.iterations == got.iterations, name
         np.testing.assert_array_equal(np.asarray(ref.labels),
                                       np.asarray(got.labels))
+
+
+def test_strip_marks_decodes_packed_labels():
+    """``pack_marks`` then the kernels' decode (``fused._strip_marks``)
+    round-trips label 0 and label n - 1 with the bit set, a label without
+    it and the pad -1, and ORs only each row's live entries."""
+    from jax.experimental import pallas as pl
+    n = 1000
+    labels = jnp.asarray([0, n - 1, 7, 0], jnp.int32)
+    packed = fused.pack_marks(labels, jnp.asarray([True, True, False,
+                                                   False]))
+    col = np.asarray(packed).tolist() + [-1]  # and a pad
+    assert col[:2] == [-2**31, (n - 1) - 2**31] and col[4] == -1
+    # column r of the [8, 128] tile is row r: entry 0 holds col[r % 5]
+    tile = np.full((8, 128), 5, np.int32)
+    tile[0] = np.resize(np.asarray(col, np.int32), 128)
+    tile[1] = -1  # a row's second entry is a pad
+    counts = np.resize(np.asarray([1, 2, 0], np.int32), 128)[None]
+
+    def kernel(tile_ref, count_ref, out_tile_ref, out_m_ref):
+        out_tile_ref[...] = tile_ref[...]
+        out_m_ref[...] = fused._strip_marks(out_tile_ref, count_ref[...])
+
+    out_tile, out_m = pl.pallas_call(
+        kernel, out_shape=[jax.ShapeDtypeStruct((8, 128), jnp.int32),
+                           jax.ShapeDtypeStruct((1, 128), jnp.int32)],
+        interpret=True)(jnp.asarray(tile), jnp.asarray(counts))
+    out_tile, out_m = np.asarray(out_tile), np.asarray(out_m)[0]
+    want_row0 = np.resize(np.asarray([0, n - 1, 7, 0, -1], np.int32), 128)
+    np.testing.assert_array_equal(out_tile[0], want_row0)
+    np.testing.assert_array_equal(out_tile[1:], tile[1:])
+    bits = np.resize(np.asarray([1, 1, 0, 0, 0], np.int32), 128)
+    np.testing.assert_array_equal(out_m, np.where(counts[0] > 0, bits, 0))
+
+
+def _row_marks_np(rnd, slot_bit):
+    """numpy OR of ``slot_bit`` over each row's live window slots."""
+    w = rnd.window_entries
+    starts, counts = np.asarray(rnd.row_start), np.asarray(rnd.row_count)
+    out = np.zeros(starts.shape, np.int32)
+    for win, r in zip(*np.nonzero(counts)):
+        s = win * w + starts[win, r]
+        out[win, r] = slot_bit[s:s + counts[win, r]].any()
+    return out.reshape(-1)
+
+
+@pytest.mark.parametrize("name", ["star_hub", "powerlaw", "road_deg2"])
+def test_round0_marks_are_each_rows_or(name):
+    """The aligned round-0 kernel's mark output is numpy's OR of the
+    packed bit over each row's slots — rows that end in a window's last
+    lane row (the ``late`` load), pad rows and hub rows split over several
+    round-0 rows included — and the fold beside it sees the plain labels:
+    sketches and selections are those of the unpacked gather. A vertex is
+    marked when one of its rows is."""
+    g = FIXTURES[name]()
+    n = g.n_nodes
+    _, aplan = _aligned_plans(g)
+    rnd = aplan.rounds[0]
+    aev = np.asarray(aplan.aligned_entry_vertex)
+    aew = aplan.aligned_entry_weights
+    rng = np.random.default_rng(zlib.crc32(name.encode()) + 3)
+    labels = rng.permutation(n).astype(np.int32)  # holds 0 and n - 1
+    changed = rng.random(n) < 0.3
+    changed[labels == 0] = changed[labels == n - 1] = True
+    pad = np.asarray([-1], np.int32)
+    plain = jnp.asarray(np.concatenate([labels, pad])[aev])
+    packed = jnp.concatenate([fused.pack_marks(jnp.asarray(labels),
+                                               jnp.asarray(changed)),
+                              jnp.asarray(pad)])[aev]
+    slot_bit = np.concatenate([changed, [False]])[aev]
+    want = _row_marks_np(rnd, slot_bit)
+    # coverage: late rows, pad rows, marked and unmarked rows
+    starts, counts = np.asarray(rnd.row_start), np.asarray(rnd.row_count)
+    last = rnd.window_entries // 128 - fused._lane_groups(aplan.chunk) - 1
+    assert ((starts // 128 > last) & (counts > 0)).any()
+    assert 0 < want.sum() < (counts > 0).sum()
+    rv0 = np.asarray(aplan.row_to_vertex0)
+    if name != "road_deg2":  # the chain fills every row slot
+        assert (counts == 0).any()
+        assert np.bincount(rv0[rv0 >= 0]).max() > 1  # hub rows split
+    kw = dict(k=aplan.k, chunk=aplan.chunk)
+    s_k, s_v, got = stream_fold_round(rnd, packed, aew, marks=True, **kw)
+    ref_k, ref_v = stream_fold_round(rnd, plain, aew, **kw)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(s_k), np.asarray(ref_k))
+    np.testing.assert_array_equal(np.asarray(s_v), np.asarray(ref_v))
+    # the whole iteration (single-round plans mark through the select
+    # kernel): each vertex's mark is the OR of its round-0 rows'
+    real = rv0 >= 0
+    want_v = np.zeros(n, bool)
+    np.logical_or.at(want_v, rv0[real], want[real] > 0)
+    lab, seed = jnp.asarray(labels), jnp.int32(4)
+    w_got, m_got = select_best_stream(aplan, packed, aew, lab, seed,
+                                      marks=True)
+    w_ref = select_best_stream(aplan, plain, aew, lab, seed)
+    np.testing.assert_array_equal(np.asarray(w_got), np.asarray(w_ref))
+    np.testing.assert_array_equal(np.asarray(m_got), want_v)
+    # a pad in a live slot never reads as a mark
+    quiet = jnp.where(jnp.asarray(slot_bit), packed, -1)
+    _, _, got = stream_fold_round(rnd, quiet, aew, marks=True, **kw)
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_aligned_gather_accounting():

@@ -100,6 +100,25 @@ def test_stream_kernels_compile_at_narrow_strides(one_chip, kernel, w):
             *meta, *entries)
 
 
+@pytest.mark.parametrize("w", [W, 384, 256])
+@pytest.mark.parametrize("kernel", ["fold", "select"])
+def test_stream_marks_kernels_compile(one_chip, kernel, w):
+    """The aligned round-0 kernels that also mark the frontier
+    (``marks=True``: strip the packed bit, OR it per row, one more
+    [1, tile_r] output), at the cap and at the narrowest strides."""
+    s, meta, entries, rnd = _streamed(one_chip, True, w)
+    if kernel == "select":
+        _compile(lambda g, rs, rc, dm, el, ew, inc, seed:
+                 streaming.stream_select_round(
+                     rnd(g, rs, rc, dm), el, ew, inc, seed, k=K,
+                     chunk=CHUNK, interpret=False, marks=True),
+                 *meta, *entries, s((N_STEPS * TILE_R,)), s(()))
+    else:
+        _compile(lambda g, rs, rc, dm, el, ew: streaming.stream_fold_round(
+            rnd(g, rs, rc, dm), el, ew, k=K, chunk=CHUNK, interpret=False,
+            marks=True), *meta, *entries)
+
+
 def test_stream_bm_compiles(one_chip):
     s, meta, entries, rnd = _streamed(one_chip, True)
     _compile(lambda g, rs, rc, dm, el, ew, init:
@@ -173,13 +192,15 @@ def test_dist_stream_step_compiles_on_four_chips(topo, halo, monkeypatch):
 
 
 
-@pytest.mark.parametrize("aligned", [True, False],
-                         ids=["aligned", "regathered"])
-def test_mover_names_its_module_kernels_and_scopes(one_chip, aligned,
+@pytest.mark.parametrize("aligned,marks", [(True, False), (False, False),
+                                           (True, True)],
+                         ids=["aligned", "regathered", "aligned-marks"])
+def test_mover_names_its_module_kernels_and_scopes(one_chip, aligned, marks,
                                                    monkeypatch):
     """The whole streamed mover compiles as ``jit_lpa_move``, its Pallas
     kernels as custom calls named ``lpa_fold``/``lpa_select``, and every
-    scope in its ops' ``op_name``."""
+    scope in its ops' ``op_name``; marking the frontier
+    (``mover(marks=True)``) adds the ``lpa.marks`` scope and no kernel."""
     import re
     from repro.core.lpa import LPAConfig, build_workspace, mover
     from repro.graphs.generators import rmat
@@ -191,10 +212,18 @@ def test_mover_names_its_module_kernels_and_scopes(one_chip, aligned,
     assert rounds >= 2
     s = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
                                        sharding=one_chip)
-    text = jax.jit(mover(cfg, 0), static_argnames=("sparse",)).lower(
-        jax.tree.map(s, ws), s(jnp.zeros(g.n_nodes, jnp.int32)),
-        s(jnp.zeros((), jnp.bool_)), s(jnp.zeros((), jnp.int32)),
-        frontier=None, sparse=False).compile().as_text()
+    flag = s(jnp.zeros((), jnp.bool_))
+    mask = s(jnp.zeros(g.n_nodes, jnp.bool_))
+    # as lpa() jits it
+    if marks:
+        move = jax.jit(mover(cfg, 0, marks=True), donate_argnames=("last",))
+        kw = dict(last=(mask, mask), last_pick_less=flag)
+    else:
+        move = jax.jit(mover(cfg, 0), static_argnames=("sparse",))
+        kw = dict(frontier=None, sparse=False)
+    text = move.lower(
+        jax.tree.map(s, ws), s(jnp.zeros(g.n_nodes, jnp.int32)), flag,
+        s(jnp.zeros((), jnp.int32)), **kw).compile().as_text()
     assert text.startswith("HloModule jit_lpa_move")
     kernels = re.findall(r"%(lpa_\w+)(?:\.\d+)? = .*tpu_custom_call", text)
     assert sorted(set(kernels)) == ["lpa_fold", "lpa_select"]
@@ -202,3 +231,4 @@ def test_mover_names_its_module_kernels_and_scopes(one_chip, aligned,
     scopes = set(re.findall(r"(lpa\.[\w.]+)/", text))
     assert scopes >= {"lpa.gather", "lpa.relayout", "lpa.apply"} | {
         f"lpa.fold.r{i}" for i in range(rounds)}
+    assert ("lpa.marks" in scopes) == marks
